@@ -277,9 +277,9 @@ def estimate_cost(closed_jaxpr, param_bytes: int = 0) -> CostEstimate:
 
 # Dense bf16 peak FLOP/s per chip. TPU numbers are the published per-chip
 # peaks (v4 275 TFLOP/s, v5e 197, v5p 459, v6e 918); GPU entries cover the
-# common single-host dev boxes; "cpu" is a nominal 100 GFLOP/s placeholder
-# so CPU smoke runs still produce a ratio (meaningful only relatively —
-# override with $KATIB_TPU_PEAK_FLOPS for calibrated numbers).
+# common single-host dev boxes. There is no "cpu" entry: a device that is
+# not in the table has no peak here and therefore no MFU
+# ($KATIB_TPU_PEAK_FLOPS states a calibrated peak for one).
 PEAK_FLOPS: Dict[str, float] = {
     "tpu v4": 275e12,
     "tpu v5 lite": 197e12,
@@ -289,7 +289,6 @@ PEAK_FLOPS: Dict[str, float] = {
     "tpu v6e": 918e12,
     "h100": 989e12,
     "a100": 312e12,
-    "cpu": 100e9,
 }
 
 ENV_PEAK_FLOPS = "KATIB_TPU_PEAK_FLOPS"

@@ -50,7 +50,6 @@ import hashlib
 import json
 import os
 import re
-import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
@@ -666,22 +665,16 @@ def dispatch_group_key_for_assignments(spec, assignments: Dict[str, str]):
 
 def device_capacity_bytes() -> Optional[int]:
     """Accelerator memory per device, when knowable without side effects:
-    only consulted if jax is already imported (same guard as telemetry.py)
-    and the backend reports bytes_limit. CPU backends return None."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return None
-    try:
-        from ..utils.backend import bounded_local_devices
+    only if a backend is already up in this process (same rule as
+    telemetry.py — the pre-flight never takes the chip) and it reports
+    bytes_limit. CPU backends return None."""
+    from ..utils.backend import initialized_local_devices
 
-        devices = bounded_local_devices()
-        if not devices:
-            return None
-        stats = devices[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        return int(limit) if limit else None
-    except Exception:
+    devices = initialized_local_devices()
+    if not devices:
         return None
+    limit = (devices[0].memory_stats() or {}).get("bytes_limit")
+    return int(limit) if limit else None
 
 
 # ---------------------------------------------------------------------------

@@ -62,14 +62,42 @@ import sys
 from typing import Optional
 
 
+def _local_accelerators():
+    """The host's real accelerator devices, or None on a CPU host.
+
+    Whether an accelerator is expected is read from config/env first, so a
+    CPU-held process never initializes a backend here. On a host that has a
+    chip the probe raises if the chip cannot be reached: pooling abstract
+    slots there would let every trial land on chip 0 unseen."""
+    from .utils.backend import bounded_local_devices
+    from .utils.compilation import accelerator_expected
+
+    if not accelerator_expected():
+        return None
+    devices = bounded_local_devices()
+    if not devices or devices[0].platform == "cpu":
+        return None
+    return list(devices)
+
+
 def _controller(
-    root: Optional[str], devices: Optional[int] = None, readonly: bool = False
+    root: Optional[str],
+    devices: Optional[int] = None,
+    readonly: bool = False,
+    in_process_trials: bool = False,
 ):
+    """``devices`` (the --devices count) sizes a pool of abstract slots.
+    Without it, a controller whose trials run in its own process pools the
+    host's real accelerator devices when it has any; a controller of
+    subprocess trials stays off the backend (its children need the chip)
+    and keeps the scheduler's default abstract slots."""
     from .controller.experiment import ExperimentController
 
     devs = None
     if devices:
         devs = list(range(devices))
+    elif in_process_trials and not readonly:
+        devs = _local_accelerators()
     config = None
     if readonly:
         # inspection commands must not contend the running controller's
@@ -98,7 +126,10 @@ def cmd_run(args) -> int:
             # still the friendly message + rc=2, not a traceback
             print(f"invalid experiment spec: {type(e).__name__}: {e}", file=sys.stderr)
             return 2
-    ctrl = _controller(args.root, args.devices)
+    ctrl = _controller(
+        args.root, args.devices,
+        in_process_trials=spec.trial_template.command is None,
+    )
     try:
         ctrl.create_experiment(spec)
     except (ValidationError, ValueError) as e:
@@ -114,7 +145,19 @@ def cmd_run(args) -> int:
 def cmd_resume(args) -> int:
     """Resume a persisted (FromVolume-style) experiment in a fresh process:
     restore state, requeue in-flight trials, drive to completion."""
-    ctrl = _controller(args.root, args.devices)
+    import os
+
+    from .db.state import ExperimentStateStore
+
+    # same pool as `run` would build: peek at the persisted template first
+    persisted = ExperimentStateStore(os.path.join(args.root, "state")).load(args.name)
+    ctrl = _controller(
+        args.root, args.devices,
+        in_process_trials=(
+            persisted is not None
+            and persisted.spec.trial_template.command is None
+        ),
+    )
     try:
         try:
             ctrl.load_experiment(args.name)
@@ -1231,7 +1274,7 @@ def main(argv=None) -> int:
     )
     run_p.add_argument("spec")
     run_p.add_argument("--timeout", type=float, default=None)
-    run_p.add_argument("--devices", type=int, default=None, help="abstract device slots (default: 8 slots; in-process JAX trials see the real devices regardless)")
+    run_p.add_argument("--devices", type=int, default=None, help="pool this many abstract device slots (default: the host's real accelerator devices for in-process trials on a TPU host, else 8 abstract slots)")
     run_p.set_defaults(fn=cmd_run)
 
     res_p = sub.add_parser(

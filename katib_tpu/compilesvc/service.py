@@ -429,6 +429,11 @@ class CompileService:
         trace on a cold cache."""
         from ..analysis import program as semantic
 
+        if spec.trial_template.resources.num_hosts > 1:
+            # a multi-host gang runs in worker processes that need the chip;
+            # compiling its program here would initialize the backend in the
+            # controller and take the chip from them
+            return None
         builder = semantic.probe_builder_for(spec.trial_template)
         if builder is None:
             return None
@@ -649,13 +654,9 @@ class CompileService:
         jitted = jax.jit(probe.fn, donate_argnums=probe.donate_argnums)
         with self._lock:
             self.trace_counter += 1
-        try:
-            traced = jitted.trace(*probe.args)
-            closed = traced.jaxpr
-            lower = traced.lower
-        except AttributeError:  # older jax without jit(...).trace
-            closed = semantic.trace_probe(probe)
-            lower = lambda: jitted.lower(*probe.args)  # noqa: E731
+        traced = jitted.trace(*probe.args)
+        closed = traced.jaxpr
+        lower = traced.lower
         fingerprint = semantic.fingerprint_jaxpr(closed, probe)
         with self._lock:
             twin = self._by_fingerprint.get(fingerprint)

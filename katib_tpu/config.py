@@ -59,7 +59,6 @@ class RuntimeConfig:
     telemetry_ring_samples: int = 720      # per-trial sample ring bound (~1h at 5s)
     stall_seconds: float = 120.0           # TrialStalled heartbeat threshold
     oom_risk_fraction: float = 0.9         # TrialOOMRisk host-memory fraction
-    xla_cache_dir: Optional[str] = None
     # persisted-entry threshold for the shared XLA cache
     # (utils/compilation.py): 0.0 persists every compile — jax's own 1.0s
     # default skipped sub-second programs and defeated warm-start for small
@@ -126,7 +125,7 @@ class RuntimeConfig:
     # device_plane=false / KATIB_TPU_DEVICE_PLANE=0 restores the legacy
     # free-list allocator byte-identically.
     device_plane: bool = True
-    # bounded backend health probe timeout (the BENCH_r01-r05 wedge class)
+    # timeout of the periodic re-probe of a backend that already came up
     device_probe_timeout_seconds: float = 15.0
     # periodic backend re-probe on the supervisor tick; 0 = off (probe
     # only at acquisition)
@@ -275,7 +274,6 @@ ENV_OVERRIDES: Dict[str, str] = {
     "telemetry_ring_samples": "KATIB_TPU_TELEMETRY_RING_SAMPLES",
     "stall_seconds": "KATIB_TPU_STALL_SECONDS",
     "oom_risk_fraction": "KATIB_TPU_OOM_RISK_FRACTION",
-    "xla_cache_dir": "KATIB_TPU_XLA_CACHE",  # historical spelling
     "xla_cache_min_compile_seconds": "KATIB_TPU_XLA_CACHE_MIN_COMPILE_SECONDS",
     "devices_per_host": "KATIB_TPU_DEVICES_PER_HOST",
     "metrics_poll_interval": "KATIB_TPU_METRICS_POLL_INTERVAL",

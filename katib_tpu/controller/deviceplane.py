@@ -26,10 +26,13 @@ band-aid into a plane that OWNS backend acquisition and device custody:
   checkpoint-preemption through the existing PR 2/9 freeze/resume
   machinery: observations flushed, trial requeued, resumed bit-identically
   on surviving devices when a checkpoint exists, clean re-run otherwise.
-- **Failover** — when the pool drains to nothing (whole backend dead) the
-  plane swaps in the next pool of the failover chain (accelerator →
-  synthetic CPU slots by default) and emits ``BackendFailedOver``: a sweep
-  degrades instead of dying.
+- **Failover** — when a pool of *abstract* slots drains to nothing the
+  plane swaps in the next pool of the failover chain (same-size synthetic
+  slots by default) and emits ``BackendFailedOver``. A pool adopted from
+  real devices has no default chain: a synthetic slot names no device, so a
+  trial placed on one would run untracked on the default backend (chip 0 of
+  a TPU host). Losing every real device parks pending work and says so
+  (``DevicePoolExhausted``).
 
 Gating: ``runtime.device_plane`` / ``KATIB_TPU_DEVICE_PLANE=0`` removes
 the plane entirely — the allocator then runs the legacy free-list path
@@ -47,7 +50,11 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..utils import chaos
-from ..utils.backend import bounded_local_devices, probe_verdict
+from ..utils.backend import (
+    PROBE_TIMEOUT_SECONDS,
+    bounded_local_devices,
+    probe_verdict,
+)
 
 log = logging.getLogger("katib_tpu.deviceplane")
 
@@ -73,6 +80,12 @@ BACKEND_ERROR_MARKERS = (
 )
 
 
+def is_abstract_pool(devices: Sequence[Any]) -> bool:
+    """Ints/strings standing for device slots, not devices: they name no
+    chip, do not die with the backend, and a number can size them."""
+    return all(isinstance(d, (int, str)) for d in devices)
+
+
 def is_backend_loss(message: Optional[str]) -> bool:
     """Does this executor failure message carry a backend-death signature?
     Conservative by design: only explicit runtime/transport markers match —
@@ -83,16 +96,17 @@ def is_backend_loss(message: Optional[str]) -> bool:
 
 
 def acquire_backend(
-    timeout_seconds: float = 15.0,
+    timeout_seconds: float = PROBE_TIMEOUT_SECONDS,
     retries: int = 2,
     events=None,
 ) -> Tuple[Optional[List[Any]], str]:
     """Health-probed backend acquisition with a hard timeout and cached
     verdict — the plane's front door, shared by the controller bootstrap,
     ``bench.py`` round acquisition, and the probe subprocess. Returns
-    ``(devices, diagnosis)``; devices is None when the backend is wedged or
-    dead (the verdict is cached, so every later call in this process is an
-    immediate None — a wedge can never cost a second timeout)."""
+    ``(devices, diagnosis)``; devices is None when the probe failed on a
+    host that was never going to use an accelerator (the verdict is cached,
+    so every later call in this process is an immediate None). On a host
+    that has a chip a failed probe raises instead."""
     devices = bounded_local_devices(
         timeout_seconds=timeout_seconds, retries=retries, events=events
     )
@@ -187,6 +201,7 @@ class DevicePlane:
         # the active pool drains to zero live devices. The default chain is
         # installed by adopt_pool; tests/bench may override.
         self._fallbacks: List[Tuple[str, Callable[[], List[Any]]]] = []
+        self._exhausted = False  # DevicePoolExhausted said once
 
     # -- pool bootstrap ------------------------------------------------------
 
@@ -199,11 +214,10 @@ class DevicePlane:
         with self._lock:
             self._free = list(devices)
             self._backend = backend
-            if not self._fallbacks:
-                # CPU↔TPU↔GPU failover order, degraded to what a single
-                # process can actually deliver: whatever backend the pool
-                # came from fails over to same-size synthetic CPU slots
-                # (in-process trials then run on the default CPU backend).
+            if not self._fallbacks and is_abstract_pool(self._free):
+                # An abstract pool (ints/strings standing for slots) fails
+                # over to same-size synthetic slots. Real devices get no
+                # such chain: see the module docstring.
                 n = max(len(self._free), 1)
                 self._fallbacks = [
                     ("cpu-fallback", lambda n=n: [f"cpu-slot-{i}" for i in range(n)])
@@ -446,26 +460,47 @@ class DevicePlane:
 
     def _maybe_failover(self) -> None:
         """When no live device remains (free or leased), swap in the next
-        pool of the failover chain so pending work degrades instead of
-        starving forever."""
+        pool of the failover chain; with no chain left (always the case for
+        a pool of real devices) say once that pending work is parked."""
         if not self.failover_enabled:
             return
         with self._lock:
             live = len(self._free) + sum(
                 1 for d, l in self._device_lease.items() if d not in l.lost
             )
-            if live > 0 or not self._fallbacks:
+            if live > 0:
                 return
-            name, factory = self._fallbacks.pop(0)
-            try:
-                fresh = list(factory())
-            except Exception:
-                log.exception("failover pool factory for %r failed", name)
-                return
-            old = self._backend
-            self._backend = name
-            self._free.extend(fresh)
-            self._failovers += 1
+            exhausted = not self._fallbacks
+            if exhausted:
+                if self._exhausted:
+                    return
+                self._exhausted = True
+                old = self._backend
+            else:
+                name, factory = self._fallbacks.pop(0)
+                try:
+                    fresh = list(factory())
+                except Exception:
+                    log.exception("failover pool factory for %r failed", name)
+                    return
+                old = self._backend
+                self._backend = name
+                self._free.extend(fresh)
+                self._failovers += 1
+        if exhausted:
+            log.error(
+                "backend %s lost every device and nothing can stand in for "
+                "them; pending trials stay queued", old,
+            )
+            if self.events is not None:
+                self.events.event(
+                    "", "Controller", "deviceplane", "DevicePoolExhausted",
+                    f"backend {old} lost every device and nothing can stand "
+                    "in for them; pending trials stay queued until the "
+                    "controller is restarted on healthy devices",
+                    warning=True,
+                )
+            return
         log.warning(
             "backend %s lost every device; failed over to %s (%d device(s))",
             old, name, len(fresh),
@@ -631,9 +666,13 @@ class DevicePlane:
         device is gone — lose them all (which triggers failover)."""
         if probe_verdict() is not True:
             return  # never probed / already known dead: nothing to re-check
-        devices, _diag = acquire_backend(
-            timeout_seconds=self.probe_timeout_seconds, events=self.events
-        )
+        try:
+            devices, _diag = acquire_backend(
+                timeout_seconds=self.probe_timeout_seconds, events=self.events
+            )
+        except Exception:
+            log.exception("backend re-probe failed")
+            devices = None
         if devices is not None:
             return
         with self._lock:
@@ -641,7 +680,7 @@ class DevicePlane:
                 d for d, l in self._device_lease.items() if d not in l.lost
             ]
         for d in pooled:
-            if not isinstance(d, (int, str)):  # abstract slots don't die with jax
+            if not is_abstract_pool([d]):  # abstract slots don't die with jax
                 self.lose_device(d, reason="backend re-probe failed")
 
     # -- observability -------------------------------------------------------

@@ -467,7 +467,8 @@ EVENT_CATALOG: Dict[str, str] = {
     # supervised device plane (ISSUE 12, controller/deviceplane.py)
     "DeviceLost": "A device left custody (probe failure, heartbeat miss, backend error, or chaos injection); the holding gang preempts.",
     "DeviceLeaseRevoked": "The plane voided a lease: an expired zombie hold was reclaimed into the pool, or a heartbeat-missed holder was cut off.",
-    "BackendFailedOver": "Every live device of the backend was lost; the fallback pool was swapped in so the sweep degrades instead of dying.",
+    "BackendFailedOver": "Every slot of an abstract pool was lost; the fallback pool was swapped in.",
+    "DevicePoolExhausted": "Every device of the pool was lost and nothing can stand in for them; pending trials stay queued.",
     # crash-tolerant controller (ISSUE 14, controller/recovery.py)
     "ControllerRecovered": "A restarted controller replayed the recovery journal and requeued in-flight trials with their checkpointed observation rows preserved.",
     "LeaseTakenOver": "This controller took over the state root's single-writer lease from an expired or dead previous holder (fence token incremented).",

@@ -97,10 +97,6 @@ class ExperimentController:
         # vector_suggest=false / KATIB_TPU_VECTOR_SUGGEST=0 restores the
         # legacy NumPy suggesters byte-identically
         vectorized_suggest.set_enabled(rt.vector_suggest)
-        if rt.xla_cache_dir:
-            # picked up by utils.compilation.enable_compilation_cache in
-            # whichever process first touches JAX
-            os.environ.setdefault("KATIB_TPU_XLA_CACHE", rt.xla_cache_dir)
         if rt.xla_cache_min_compile_seconds:
             from ..utils.compilation import ENV_MIN_COMPILE_SECS
 

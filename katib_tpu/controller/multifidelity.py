@@ -290,6 +290,10 @@ class _ExperimentRungs:
         self.brackets = [_BracketRungs(ladder) for ladder in ladders]
         self.maximize = maximize
         self.paused: Dict[str, Tuple[int, int]] = {}  # name -> (bracket, rung)
+        # promotions claimed (out of ``paused`` or the dwell buffer) whose
+        # trials are not resubmitted yet: to every other thread such a trial
+        # still reads EarlyStopped, i.e. terminal
+        self.submitting = 0
         self.done = False
 
 
@@ -322,6 +326,8 @@ class MultiFidelityEngine:
         self.journal = journal
         self.dwell_seconds = max(float(dwell_seconds or 0.0), 0.0)
         self._lock = threading.Lock()
+        # signalled when an experiment's ``submitting`` count returns to zero
+        self._settled = threading.Condition(self._lock)
         self._exps: Dict[str, _ExperimentRungs] = {}
         # dwell buffer: experiment -> [(enqueued_at, name, bracket, rung)]
         self._pending: Dict[str, List[Tuple[float, str, int, int]]] = {}
@@ -515,6 +521,8 @@ class MultiFidelityEngine:
             for name, b, k in candidates:
                 st.brackets[b].promoted[k].add(name)
                 st.paused.pop(name, None)
+            if self.dwell_seconds <= 0:
+                st.submitting += len(candidates)
         if not candidates:
             if self.dwell_seconds > 0:
                 return self._flush_if_due(exp, scheduler)
@@ -593,6 +601,8 @@ class MultiFidelityEngine:
             batch = self._pending.pop(exp.name, [])
             timer = self._timers.pop(exp.name, None)
             st = self._exps.get(exp.name)
+            if st is not None:
+                st.submitting += len(batch)
         if timer is not None:
             timer.cancel()
         if not batch or st is None:
@@ -620,26 +630,37 @@ class MultiFidelityEngine:
         scheduler,
         dwelled: bool,
     ) -> bool:
+        """Resubmit claimed promotions. The caller counted them into
+        ``st.submitting`` when it claimed them; they are counted out here,
+        whatever happens, once every one of them has been handed to the
+        scheduler (or given back)."""
         promoted_any = False
-        if self.journal is not None and candidates:
-            # intent before action: a crash inside the barrier below leaves
-            # the claimed candidates visible to `katib-tpu recover`, and the
-            # label rebuild re-derives their paused state on restart
-            self.journal.append(
-                "promote", exp.name,
-                trials=[name for name, _, _ in candidates],
-            )
-        with scheduler.dispatch_barrier():
-            for name, b, k in candidates:
-                try:
-                    if self._promote_one(
-                        exp, name, b, k, st.brackets[b].ladder, scheduler, st
-                    ):
-                        promoted_any = True
-                except Exception:
-                    log.warning(
-                        "promotion of trial %s failed", name, exc_info=True
-                    )
+        try:
+            if self.journal is not None and candidates:
+                # intent before action: a crash inside the barrier below
+                # leaves the claimed candidates visible to `katib-tpu
+                # recover`, and the label rebuild re-derives their paused
+                # state on restart
+                self.journal.append(
+                    "promote", exp.name,
+                    trials=[name for name, _, _ in candidates],
+                )
+            with scheduler.dispatch_barrier():
+                for name, b, k in candidates:
+                    try:
+                        if self._promote_one(
+                            exp, name, b, k, st.brackets[b].ladder, scheduler, st
+                        ):
+                            promoted_any = True
+                    except Exception:
+                        log.warning(
+                            "promotion of trial %s failed", name, exc_info=True
+                        )
+        finally:
+            with self._settled:
+                st.submitting -= len(candidates)
+                if st.submitting <= 0:
+                    self._settled.notify_all()
         return promoted_any or dwelled
 
     def _trial_checkpoint_dir(self, exp: Experiment, trial: Trial, scheduler) -> Optional[str]:
@@ -767,6 +788,17 @@ class MultiFidelityEngine:
                 return False
         if self._maybe_promote(exp, scheduler):
             return True
+        with self._settled:
+            if st.submitting > 0:
+                # Another thread (a rung boundary, the dwell timer) claimed
+                # promotions and is between the claim and the resubmission —
+                # it may be waiting for a checkpoint to be read. Their trials
+                # still read terminal in ``trials``, so neither the drain
+                # check below nor the caller's status aggregation may run on
+                # that list: wait the resubmission out and have the caller
+                # list the trials again.
+                self._settled.wait_for(lambda: st.submitting <= 0, timeout=60.0)
+                return True
         if any(not t.is_terminal for t in trials):
             return False
         with self._lock:

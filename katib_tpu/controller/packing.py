@@ -518,6 +518,12 @@ class FusedPopulationExecutor:
             # observation logs KEPT — the resumed sweep extends, never
             # re-reports, and the lineage stays bit-identical.
             if ckdir:
+                # the checkpoint declares every earlier chunk's rows
+                # reported: make them durable first, or a hard kill after
+                # this save loses the rows a write-behind store still held
+                store = ctx.reporters[0].store if ctx.reporters else None
+                if store is not None:
+                    ctx._flush_traced(store)
                 pop.save_sweep_checkpoint(ckdir, carry, done, ys_np, 0)
                 ctx.notify_checkpoint(done)
             self._demux(

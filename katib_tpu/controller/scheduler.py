@@ -161,8 +161,19 @@ class TrialScheduler:
         self._queue_spans: Dict[str, Any] = {}  # trial -> open queue_wait span
         if devices is None:
             devices = list(range(8))  # abstract slots when JAX not involved
-        if devices_per_host:
-            devices = list(devices)[:devices_per_host]
+        devices = list(devices)
+        if devices_per_host and devices_per_host != len(devices):
+            from .deviceplane import is_abstract_pool
+
+            if not is_abstract_pool(devices):
+                # real devices are what the host has: a cap would drop chips
+                # from the pool without a word, a larger number adds none
+                raise ValueError(
+                    f"devices_per_host={devices_per_host} does not match the "
+                    f"{len(devices)} real device(s) given; pass the devices "
+                    "to pool instead"
+                )
+            devices = devices[:devices_per_host]  # sizes an abstract pool
         # -- supervised device plane (controller/deviceplane.py) -------------
         # None = disabled: the allocator below runs the legacy free-list
         # path byte-identically and every consult is one `is None` check

@@ -75,7 +75,6 @@ class StepStatsPlane:
         self._rollups: Dict[str, _ExpRollup] = {}
         self._cost_cache: Dict[str, Any] = {}
         self._device_kind: Optional[str] = None
-        self._device_kind_probed = False
         if metrics is not None:
             metrics.add_collector(
                 self._collect,
@@ -322,14 +321,11 @@ class StepStatsPlane:
         return cost
 
     def _probe_device_kind(self) -> Optional[str]:
-        if self._device_kind_probed:
-            return self._device_kind
-        self._device_kind_probed = True
-        try:
-            from ..utils.backend import bounded_devices
+        if self._device_kind is None:
+            from ..utils.backend import initialized_local_devices
 
-            devs = bounded_devices()
+            # step rows come from in-process trials, so their backend is
+            # up; this reader never initializes one itself
+            devs = initialized_local_devices()
             self._device_kind = devs[0].device_kind if devs else None
-        except Exception:
-            self._device_kind = None
         return self._device_kind

@@ -1,9 +1,10 @@
 """Native (C++) components, consumed via ctypes.
 
-Build on demand with ``python -m katib_tpu.native.build`` (g++ -O2 -fPIC
--shared); every consumer falls back to the pure-Python implementation when
-the shared object is missing, so the framework has no hard toolchain
-dependency.
+Git commits no binary: the consumers build the shared objects from the
+``.cc`` files the first time they need them (``python -m
+katib_tpu.native.build`` does the same by hand; g++ -O2 -fPIC -shared).
+Where no C++ compiler is at hand they run the pure-Python implementation
+and say so.
 """
 
 from __future__ import annotations

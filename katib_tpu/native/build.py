@@ -19,12 +19,19 @@ def _build_one(gxx: str, src: str, out: str, force: bool) -> bool:
     if os.path.exists(out) and not force:
         if os.path.getmtime(out) >= os.path.getmtime(src):
             return True
-    cmd = [gxx, "-O2", "-fPIC", "-shared", "-std=c++17", "-o", out, src]
+    # built beside the target and renamed into place: another process that
+    # finds the shared object finds a whole one
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = [gxx, "-O2", "-fPIC", "-shared", "-std=c++17", "-o", tmp, src]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, out)
     except subprocess.CalledProcessError as e:
         print(f"native build failed for {src}:\n{e.stderr}", file=sys.stderr)
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return True
 
 

@@ -9,15 +9,19 @@ file-metrics-collector sidecar watch
 single orchestrator core, reading + regex-scanning in Python is measurable
 overhead; the native tailer does the read/split/parse in C++.
 
-``make_tailer`` picks the implementation: native when the shared object is
-built and the collector uses the default TEXT filter; Python otherwise
-(custom regex filters and JSON lines keep full generality).
+``make_tailer`` picks the implementation: native when the collector uses the
+default TEXT filter, building the shared object from ``metrics_tailer.cc``
+the first time (git commits no binary, so a fresh checkout has none); Python
+for custom regex filters and JSON lines, and — said once in the log — where
+no C++ compiler is at hand.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
+import threading
 from typing import List, Optional, Sequence, Tuple
 
 from . import METRICS_TAILER_SO, tailer_available
@@ -27,6 +31,28 @@ from . import METRICS_TAILER_SO, tailer_available
 Parsed = Tuple[str, str, int]
 
 _lib = None
+_build_lock = threading.Lock()
+_build_tried = False
+
+
+def _ensure_built() -> bool:
+    """The shared object is there, built now if it was not (once per
+    process; a failed build is reported and the Python tailer runs)."""
+    global _build_tried
+    if tailer_available():
+        return True
+    with _build_lock:
+        if not _build_tried:
+            _build_tried = True
+            from .build import build
+
+            build()
+            if not tailer_available():
+                logging.getLogger("katib_tpu.native").warning(
+                    "native metrics tailer could not be built; trial output "
+                    "is parsed by the Python tailer"
+                )
+    return tailer_available()
 
 
 def _load_lib():
@@ -159,7 +185,7 @@ def make_tailer(
     (custom filters or JSON lines). Non-ASCII lines are deferred by the
     kernel back to the Unicode-aware Python regex, so Unicode metric names
     and log content parse identically on both paths."""
-    if not json_format and not filters and tailer_available():
+    if not json_format and not filters and _ensure_built():
         try:
             return NativeTailer(path, metric_names)
         except OSError:

@@ -43,19 +43,14 @@ def _dot_precision(dtype):
     return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
+@functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        from ..utils.backend import bounded_devices
+    """Asked of the backend once, plainly. An error here is an error: a
+    caller handed ``False`` on a TPU host would run dense attention in the
+    kernel's place and nobody would see it."""
+    from ..utils.backend import require_devices
 
-        # bounded probe (KTI304): kernel-vs-interpret dispatch on a wedged
-        # backend degrades to the dense path instead of hanging
-        devices = bounded_devices()
-        if not devices:
-            return False
-        d = devices[0]
-        return "tpu" in d.platform.lower() or "TPU" in getattr(d, "device_kind", "")
-    except Exception:
-        return False
+    return require_devices()[0].platform == "tpu"
 
 
 def _use_kernel(interpret: Optional[bool]) -> bool:

@@ -26,21 +26,11 @@ AXIS_ORDER = ("pipe", "data", "fsdp", "expert", "seq", "model")
 
 
 def distributed_initialized() -> bool:
-    """Is the jax.distributed client up? ``jax.distributed.is_initialized``
-    only exists on newer jax; older versions keep the state object in
-    ``jax._src.distributed`` — probe both rather than crash on a version
-    mismatch. Inspects only the distributed client, never the XLA backend."""
+    """Is the jax.distributed client up? Inspects only the distributed
+    client, never the XLA backend."""
     import jax
 
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def initialize_distributed(

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import os
 import pickle
+import threading
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -24,6 +25,19 @@ import numpy as np
 from ..parallel.mesh import distributed_initialized as _dist_init
 
 MAX_TO_KEEP = 3
+
+# orbax keeps process-global state for the save in flight (its temporary
+# paths are registered under one operation id per process), so two
+# CheckpointManagers saving from two in-process trial threads — even into
+# different directories — create and remove each other's
+# ``<step>.orbax-checkpoint-tmp``. Every orbax call of this process goes
+# through this lock; a save already blocks its trial thread until committed.
+_ORBAX_LOCK = threading.RLock()
+
+
+class CheckpointError(RuntimeError):
+    """A save that did not commit. Raised on the trial's own thread so the
+    trial fails with the cause instead of resuming later from nothing."""
 
 
 def _pickle_steps(directory: str) -> List[int]:
@@ -109,9 +123,15 @@ class CheckpointStore:
                 lambda x: np.asarray(x) if isinstance(x, np.generic) else x,
                 state,
             )
-            with self._manager() as mngr:
+            with _ORBAX_LOCK, self._manager() as mngr:
                 mngr.save(step, args=ocp.args.StandardSave(state))
                 mngr.wait_until_finished()
+                latest = mngr.latest_step()
+            if latest is None or latest < step:
+                raise CheckpointError(
+                    f"checkpoint step {step} did not commit under "
+                    f"{self.directory} (latest committed: {latest})"
+                )
         else:
             host_state = jax.tree.map(np.asarray, state)
             path = os.path.join(self.directory, f"ckpt_{step}.pkl")
@@ -129,7 +149,7 @@ class CheckpointStore:
 
     def latest_step(self) -> Optional[int]:
         if self.use_orbax:
-            with self._manager() as mngr:
+            with _ORBAX_LOCK, self._manager() as mngr:
                 return mngr.latest_step()
         steps = _pickle_steps(self.directory)
         return max(steps) if steps else None
@@ -143,7 +163,7 @@ class CheckpointStore:
         if self.use_orbax:
             import orbax.checkpoint as ocp
 
-            with self._manager() as mngr:
+            with _ORBAX_LOCK, self._manager() as mngr:
                 if template is not None:
                     return mngr.restore(step, args=ocp.args.StandardRestore(template))
                 # template-less StandardRestore: newer orbax refuses a bare

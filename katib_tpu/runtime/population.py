@@ -501,12 +501,9 @@ def enas_program(
 
 def _emit_stream(sink, generation, best, median) -> None:
     """Per-generation host stream from inside the scan body (io_callback):
-    ordered so the live view advances monotonically. Degrades to a no-op on
-    jax builds without io_callback."""
-    try:
-        from jax.experimental import io_callback
-    except ImportError:  # pragma: no cover - old jax
-        return
+    ordered so the live view advances monotonically."""
+    from jax.experimental import io_callback
+
     io_callback(sink, None, generation, best, median, ordered=True)
 
 

@@ -29,15 +29,15 @@ CMA z) is made on the host with the SAME numpy Generator calls in the SAME
 order as the legacy loop, so the vectorized kernels reproduce the oracle's
 selections up to floating-point tolerance (tests/test_suggest_vectorized.py
 asserts this per algorithm). Kernels run in float64 via the
-``jax.experimental.enable_x64`` scope so that tolerance is ~1e-12, not
+``jax.enable_x64`` scope so that tolerance is ~1e-12, not
 float32 noise. Inputs are padded to power-of-two shape buckets so history
 growth retraces O(log n) times per experiment, not per call.
 
 Gating: ``runtime.vector_suggest`` / ``KATIB_TPU_VECTOR_SUGGEST`` (default
-on); a missing or broken JAX install degrades to the legacy path rather
-than failing suggestion. Each entry point returns ``None`` whenever the
-call falls outside its parity-exact fast path (cold history, degenerate
-good/bad split, restart strategies) and the caller runs the NumPy oracle.
+on); an install without JAX runs the legacy path. Each entry point returns
+``None`` whenever the call falls outside its parity-exact fast path (cold
+history, degenerate good/bad split, restart strategies) and the caller runs
+the NumPy oracle.
 """
 
 from __future__ import annotations
@@ -76,16 +76,19 @@ def enabled() -> bool:
 
 @functools.lru_cache(maxsize=1)
 def _jax():
-    """(jax, jnp) or None — a broken accelerator install must gate to the
-    legacy NumPy path, never fail suggestion (the bounded-probe lesson of
-    utils/backend.py)."""
+    """(jax, jnp) or None — an install without JAX gates to the legacy NumPy
+    path. The persistent compile cache is switched on here as well: JAX
+    decides at the process's first compile whether the cache is in use, and
+    with warm-start history that first compile can be a suggestion kernel."""
     try:
         import jax
         import jax.numpy as jnp
-
-        return jax, jnp
-    except Exception:
+    except ImportError:
         return None
+    from ..utils.compilation import enable_compilation_cache
+
+    enable_compilation_cache()
+    return jax, jnp
 
 
 def available() -> bool:
@@ -328,9 +331,7 @@ def tpe_batch(
     xs_pad = np.zeros((np_pad, d), dtype=np.float64)
     xs_pad[:n0] = xs
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with _jax()[0].enable_x64():
         us = _tpe_program(multivariate)(
             xs_pad, cands, good_mask, bad_mask, bw_good, bw_bad, n_good, n_bad
         )
@@ -461,9 +462,7 @@ def cma_replay(
             ys_gens[i, :n] = yg
         counts[i] = float(n)
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with _jax()[0].enable_x64():
         mean, sigma, C, p_sigma, p_c = _cma_program(dim, mu0)(
             np.asarray(mean0, dtype=np.float64),
             np.float64(sigma0),
@@ -563,9 +562,7 @@ def bo_mle(
     lengths = np.array([c[0] for c in combos], dtype=np.float64)
     noises = np.array([c[1] for c in combos], dtype=np.float64)
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with _jax()[0].enable_x64():
         lmls = np.asarray(
             _bo_mle_program()(
                 xs_pad, ys_pad, mask, np.float64(n), lengths, noises
@@ -737,9 +734,7 @@ def bo_batch(
     if member_idx is not None:
         midx[:batch] = member_idx
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with _jax()[0].enable_x64():
         us = _bo_acquire_program(acq)(
             xs_pad, ys_pad, mask, np.float64(n0), cands_pad, midx,
             np.float64(length), np.float64(noise),

@@ -16,7 +16,7 @@ programmatic :func:`install`; when neither is set every hook below is one
 - ``seed=N``        — deterministic device choice within a revoked lease
 - ``wedge_probe=N`` — the first N backend probe attempts wedge (hang past
                       the bounded timeout, surfacing the cached-verdict
-                      path exactly like a dead tunnel)
+                      path exactly like a runtime that never starts)
 - ``revoke=G[@H]``  — the G-th lease granted by the plane loses one device
                       after its H-th heartbeat (default H=1)
 - ``kill=G[@H]``    — the G-th lease's holder is hard-killed after its

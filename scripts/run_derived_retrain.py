@@ -32,9 +32,7 @@ def main() -> None:
     args = ap.parse_args()
 
     if not args.tpu:
-        from katib_tpu.utils.platform_force import ensure_cpu_process
-
-        ensure_cpu_process()
+        os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
     else:
         # SAME dataset knobs as the search record this reproduces — taken
         # from the RECORD's own provenance string, not the repo's current

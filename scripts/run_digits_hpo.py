@@ -147,9 +147,7 @@ def main() -> None:
     args = ap.parse_args()
 
     if not args.tpu:
-        from katib_tpu.utils.platform_force import ensure_cpu_process
-
-        ensure_cpu_process()
+        os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
     import jax
 
     if not args.tpu:

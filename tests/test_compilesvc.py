@@ -696,6 +696,66 @@ def test_bounded_backend_probe_success_path():
         backend.reset_probe_state()
 
 
+@pytest.mark.parametrize("expected", [True, False], ids=["chip-expected", "cpu-held"])
+def test_failed_probe_raises_where_a_chip_was_expected(monkeypatch, expected):
+    """A probe that fails on a host that has a chip raises; only a process
+    held to the CPU anyway gets None (and one warning event)."""
+    from katib_tpu.utils import backend, compilation
+
+    def _dead():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "local_devices", _dead)
+    monkeypatch.setattr(compilation, "accelerator_expected", lambda: expected)
+    backend.reset_probe_state()
+    try:
+        for _ in range(2):  # the cached verdict answers the same way
+            if expected:
+                with pytest.raises(backend.BackendUnavailable, match="refusing"):
+                    backend.bounded_local_devices(retries=1)
+            else:
+                assert backend.bounded_local_devices(retries=1) is None
+        with pytest.raises(backend.BackendUnavailable):
+            backend.require_devices(retries=1)
+    finally:
+        backend.reset_probe_state()
+
+
+def test_readers_never_initialize_a_backend(monkeypatch):
+    """Telemetry, the admission pre-flight and step statistics look only at
+    a backend that is already up: the process that initializes the TPU
+    backend owns the chip, and a controller of subprocess trials must not."""
+    from jax._src import xla_bridge
+
+    from katib_tpu.analysis.program import device_capacity_bytes
+    from katib_tpu.telemetry import read_device_memory
+    from katib_tpu.utils import backend
+
+    def _boom(*_a, **_k):
+        raise AssertionError("a reader initialized the backend")
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: False)
+    monkeypatch.setattr(jax, "local_devices", _boom)
+    monkeypatch.setattr(jax, "devices", _boom)
+    assert backend.initialized_local_devices() is None
+    assert backend.holds_accelerator() is False
+    assert read_device_memory() == []
+    assert device_capacity_bytes() is None
+
+
+def test_holds_accelerator_reads_the_platform(monkeypatch):
+    from katib_tpu.utils import backend
+
+    class Dev:
+        def __init__(self, platform):
+            self.platform = platform
+
+    monkeypatch.setattr(backend, "initialized_local_devices", lambda: [Dev("tpu")])
+    assert backend.holds_accelerator() is True
+    monkeypatch.setattr(backend, "initialized_local_devices", lambda: [Dev("cpu")])
+    assert backend.holds_accelerator() is False
+
+
 def test_xla_cache_min_compile_env_parsing(monkeypatch):
     from katib_tpu.utils.compilation import min_compile_seconds_from_env
 

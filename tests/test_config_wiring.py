@@ -347,3 +347,65 @@ def test_experiment_survives_suggester_restart(tmp_path):
             if p is not None:
                 p.terminate()
                 p.wait(timeout=10)
+
+
+class _FakeChip:
+    """Stands for a real device: anything that is not an int/str slot."""
+
+    platform = "tpu"
+
+    def __init__(self, i):
+        self.id = i
+
+
+def test_devices_per_host_refuses_to_drop_real_devices(tmp_path):
+    chips = [_FakeChip(i) for i in range(4)]
+    cfg = KatibConfig(runtime=RuntimeConfig(devices_per_host=2))
+    with pytest.raises(ValueError, match="devices_per_host=2"):
+        ExperimentController(root_dir=str(tmp_path), devices=chips, config=cfg)
+
+
+def test_devices_per_host_matching_real_pool_is_accepted(tmp_path):
+    chips = [_FakeChip(i) for i in range(2)]
+    cfg = KatibConfig(runtime=RuntimeConfig(devices_per_host=2))
+    c = ExperimentController(root_dir=str(tmp_path), devices=chips, config=cfg)
+    try:
+        assert c.scheduler.allocator.total == 2
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize(
+    "expected,probe,in_process,want",
+    [
+        # CPU-held process: never probes, abstract default pool
+        (False, None, True, None),
+        # TPU host, in-process trials: the real devices are the pool
+        (True, [_FakeChip(0), _FakeChip(1)], True, 2),
+        # TPU host, subprocess trials: the controller stays off the backend
+        (True, [_FakeChip(0)], False, None),
+    ],
+)
+def test_cli_pools_real_devices_on_accelerator_host(
+    tmp_path, monkeypatch, expected, probe, in_process, want
+):
+    from katib_tpu import cli
+    from katib_tpu.utils import backend, compilation
+
+    probed = []
+
+    def fake_probe(*_a, **_k):
+        probed.append(1)
+        return probe
+
+    monkeypatch.setattr(compilation, "accelerator_expected", lambda: expected)
+    monkeypatch.setattr(backend, "bounded_local_devices", fake_probe)
+    c = cli._controller(str(tmp_path), None, in_process_trials=in_process)
+    try:
+        if want is None:
+            assert not probed
+            assert c.scheduler.allocator.total == 8  # abstract slots, as before
+        else:
+            assert c.scheduler.allocator.total == want
+    finally:
+        c.close()

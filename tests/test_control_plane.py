@@ -529,7 +529,7 @@ class TestFailoverE2E:
         names = ["fo-a", "fo-b"]
 
         def drive(root, replicas, kill_after_place):
-            _write_trial_module(root, epochs=epochs, dwell=0.25)
+            _write_trial_module(root, epochs=epochs, dwell=0.6)
             env = _replica_env(root, replicas)
             procs, logs = [], []
             try:

@@ -214,6 +214,26 @@ class TestLeases:
             plane.lose_device(d, "fallback died too")
         assert plane.free_count == 0
 
+    def test_real_device_pool_gets_no_synthetic_chain(self):
+        """A slot named ``cpu-slot-i`` names no device: a trial placed on one
+        would run untracked on the default backend (chip 0 of a TPU host).
+        Losing every real device parks the work and says so, once."""
+
+        class Chip:
+            platform = "tpu"
+
+        events, metrics = EventRecorder(), MetricsRegistry()
+        plane = DevicePlane(events=events, metrics=metrics)
+        chips = [Chip(), Chip()]
+        plane.adopt_pool(chips, backend="tpu")
+        for chip in chips:
+            plane.lose_device(chip, "backend died")
+        assert plane.backend == "tpu" and plane.free_count == 0
+        reasons = [e.reason for e in events.list("")]
+        assert "BackendFailedOver" not in reasons
+        assert reasons.count("DevicePoolExhausted") == 1
+        assert "katib_backend_failover_total 1.0" not in metrics.render()
+
     def test_failover_disabled_leaves_pool_empty(self):
         plane, events, _ = self._plane(n=1, failover=False)
         plane.lose_device(0, "gone")

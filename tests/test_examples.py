@@ -91,7 +91,7 @@ def test_record_parses(path):
     "name", ["darts_hpo_50trials_cpu.json", "darts_hpo_50trials_tpu.json"]
 )
 def test_north_star_record_contract(name):
-    """scripts/capture_tpu_evidence.py gates the stage-2 derived retrain on
+    """The stage-2 derived retrain is gated on
     ``verification == 'ok' and optimal_assignments`` and bench.py attaches
     the record to its extras by these same fields — the contract the north
     star script promises (run_north_star.py 'stable contract' comment) must

@@ -217,7 +217,7 @@ def test_asha_e2e_ladder_structure_and_integrity(controller):
     # 2 pruned at rung 1, 2 promoted; both survivors succeed at the top
     assert budgets == {1: 4, 2: 2, 4: 2}, budgets
     conds = Counter((t.condition.value, t.current_reason) for t in trials)
-    assert conds[("Succeeded", "TrialSucceeded")] == 2
+    assert conds[("Succeeded", "TrialSucceeded")] == 2, (conds, [(t.name, t.message) for t in trials])
     assert conds[("EarlyStopped", "RungPruned")] == 6
 
     ev = Counter(e.reason for e in c.events.list("asha-e2e"))

@@ -240,3 +240,33 @@ def test_port_collision_relaunches_gang_without_restart(controller):
     assert float(trial.observation.metric("score").latest) == 1.0
     # no scheduler-level restart was consumed — the relaunch was internal
     assert not any(c.reason == "TrialRestarting" for c in trial.conditions)
+
+
+@pytest.mark.parametrize(
+    "env,n_hosts,holds,expect",
+    [
+        # CPU-held workers share nothing: never refused
+        ({"JAX_PLATFORMS": "cpu"}, 2, True, None),
+        # a controller that owns the chip cannot hand it to workers
+        ({"JAX_PLATFORMS": "tpu"}, 1, True, "owns the chip"),
+        # N workers on one TPU machine would each need all of its chips
+        ({"JAX_PLATFORMS": "tpu"}, 2, False, "per-worker chip partitioning"),
+        # a launcher that places workers on separate machines pins the coordinator
+        ({"JAX_PLATFORMS": "tpu", "KATIB_TPU_COORDINATOR": "10.0.0.1:1234"}, 2, False, None),
+        ({"JAX_PLATFORMS": "tpu"}, 1, False, None),
+    ],
+)
+def test_gang_that_cannot_have_the_chip_is_refused_at_once(
+    monkeypatch, env, n_hosts, holds, expect
+):
+    """One process for each chip: said before any worker starts, instead of
+    workers waiting for each other in jax.distributed.initialize."""
+    from katib_tpu.controller.executor import MultiHostExecutor
+    from katib_tpu.utils import backend
+
+    monkeypatch.setattr(backend, "holds_accelerator", lambda: holds)
+    refusal = MultiHostExecutor._chip_refusal(dict(env), n_hosts)
+    if expect is None:
+        assert refusal is None
+    else:
+        assert expect in refusal

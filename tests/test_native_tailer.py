@@ -166,3 +166,29 @@ class TestExecutorIntegration:
             assert exp.status.is_completed
         finally:
             ctrl.close()
+
+
+def test_tailer_builds_itself_on_first_use(tmp_path, monkeypatch):
+    """Git commits no binary: a fresh checkout has no shared object, and
+    make_tailer builds it from metrics_tailer.cc rather than behaving
+    differently from the tree it was cloned from."""
+    import os
+
+    from katib_tpu import native
+    from katib_tpu.native import tailer
+
+    target = str(tmp_path / "libmetricstailer.so")
+    monkeypatch.setattr(native, "METRICS_TAILER_SO", target)
+    monkeypatch.setattr(tailer, "METRICS_TAILER_SO", target)
+    monkeypatch.setattr(
+        "katib_tpu.native.build._TARGETS", (("metrics_tailer.cc", target),)
+    )
+    monkeypatch.setattr(tailer, "_build_tried", False)
+    monkeypatch.setattr(tailer, "_lib", None)
+    log = tmp_path / "out.log"
+    log.write_text("accuracy=0.5\n")
+    t = tailer.make_tailer(str(log), ["accuracy"])
+    assert os.path.exists(target)
+    assert isinstance(t, tailer.NativeTailer)
+    assert [(n, v) for n, v, _ in t.poll()] == [("accuracy", "0.5")]
+    t.close()

@@ -210,3 +210,26 @@ def test_ring_backward_kernel_path_matches_dense_grad():
                 np.asarray(gr), np.asarray(gd), atol=2e-4, rtol=2e-4,
                 err_msg=f"{name} causal={causal}",
             )
+
+
+def test_on_tpu_asks_the_backend_plainly(monkeypatch):
+    """An error while asking the backend is an error: answering False would
+    hand a TPU host dense attention in the kernel's place, unseen."""
+    from katib_tpu.ops import flash_attention as fa
+    from katib_tpu.utils import backend
+
+    def _dead(*_a, **_k):
+        raise backend.BackendUnavailable("no backend")
+
+    fa._on_tpu.cache_clear()
+    monkeypatch.setattr(backend, "require_devices", _dead)
+    try:
+        with pytest.raises(backend.BackendUnavailable):
+            fa._use_kernel(None)
+        assert fa._use_kernel(True) is True  # forced paths never ask
+        assert fa._use_kernel(False) is False
+    finally:
+        fa._on_tpu.cache_clear()
+    monkeypatch.undo()
+    assert fa._on_tpu() is False  # the CPU backend answers, plainly
+    fa._on_tpu.cache_clear()

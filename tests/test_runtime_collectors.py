@@ -200,6 +200,48 @@ class TestCheckpointStore:
         old = store.restore(step=1)
         np.testing.assert_allclose(old["w"], state["w"])
 
+    def test_concurrent_in_process_saves(self, tmp_path):
+        """In-process trial threads save at the same time, each into its own
+        directory. orbax keeps per-process state for the save in flight, so
+        without the store's lock these saves remove each other's temporary
+        directories (FileExistsError / ENOENT in its commit thread)."""
+        pytest.importorskip("orbax.checkpoint")
+        import threading
+
+        from katib_tpu.runtime.checkpoints import CheckpointStore
+
+        errors = []
+
+        def trial(i):
+            try:
+                store = CheckpointStore(str(tmp_path / f"t{i}"), use_orbax=True)
+                for step in range(1, 4):
+                    store.save(step, {"epoch": step, "w": np.full((4, 4), float(i))})
+                out = store.restore()
+                assert int(out["epoch"]) == 3 and float(out["w"][0, 0]) == float(i)
+            except Exception as e:  # reported below, on the test thread
+                errors.append(f"trial {i}: {type(e).__name__}: {e}")
+
+        threads = [threading.Thread(target=trial, args=(i,)) for i in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads), "a save never returned"
+        assert not errors, errors
+
+    def test_uncommitted_save_raises(self, tmp_path, monkeypatch):
+        """A save that did not commit fails on the trial's own thread."""
+        pytest.importorskip("orbax.checkpoint")
+        import orbax.checkpoint as ocp
+
+        from katib_tpu.runtime.checkpoints import CheckpointError, CheckpointStore
+
+        store = CheckpointStore(str(tmp_path / "ckpt"), use_orbax=True)
+        monkeypatch.setattr(ocp.CheckpointManager, "save", lambda self, *a, **k: False)
+        with pytest.raises(CheckpointError, match="did not commit"):
+            store.save(1, {"epoch": 1})
+
 
 class TestPrometheusCollector:
     def test_parse_prometheus_text(self):

@@ -251,7 +251,7 @@ class TestMfu:
         assert peak_flops_for("TPU v5e") == 197e12
         assert peak_flops_for("TPU v5p") == 459e12
         assert peak_flops_for("NVIDIA H100 80GB HBM3") == 989e12
-        assert peak_flops_for("cpu") == 100e9
+        assert peak_flops_for("cpu") is None  # no made-up peak: a CPU run has no MFU
         assert peak_flops_for("quantum-annealer") is None
         assert peak_flops_for(None) is None
 
