@@ -159,6 +159,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -291,6 +292,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid iterates q blocks innermost for a fixed kv block.
@@ -318,6 +320,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -213,16 +213,24 @@ def run_lm_trial(assignments: Dict[str, str], ctx=None) -> None:
         num_heads=num_heads,
         max_seq_len=seq_len,
     )
-    params, opt_state, step_fn, put_batch = make_lm_train_step(config, mesh, lr)
+    import contextlib
+
+    # stages of the `compile` span (runtime/context.py); the step's own trace,
+    # lowering and compile become its children through jax.monitoring
+    stage = ctx.span if ctx is not None else (lambda name: contextlib.nullcontext())
+    with stage("build"):
+        params, opt_state, step_fn, put_batch = make_lm_train_step(config, mesh, lr)
+    if ctx is not None:
+        step_fn = ctx.watch_step(step_fn)
 
     rng = np.random.default_rng(0)
     data = rng.integers(0, vocab, size=(batch, seq_len + 1), dtype=np.int32)
     profile = ctx is not None and assignments.get("profile", "0") == "1"
-    import contextlib
 
     prof_cm = ctx.profile() if profile else contextlib.nullcontext()
     # the synthetic batch is constant across steps: stage it once
-    tokens, targets, positions = put_batch(data[:, :-1], data[:, 1:])
+    with stage("stage_batch"):
+        tokens, targets, positions = put_batch(data[:, :-1], data[:, 1:])
     with prof_cm:
         for i in range(steps):
             params, opt_state, loss = step_fn(params, opt_state, tokens, targets, positions)

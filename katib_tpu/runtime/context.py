@@ -12,6 +12,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from .. import tracing
 from .metrics import MetricsReporter
 
 
@@ -69,37 +70,89 @@ class TrialContext:
         self._compile_span = None
         self._steps_span = None
         self._report_count = 0
+        self._ledger = tracing.StepLedger()
 
-    def _trace_span(self, name: str, parent: Optional[str] = None, **attrs):
-        if self.tracer is None:
-            return None
-        return self.tracer.start_span(
-            name,
-            getattr(self, "_trace_experiment", self.experiment_name),
-            self.trace_id,
-            parent or self.trace_parent,
-            attrs=attrs or None,
+    def _stage(self, name: str) -> "tracing.StageSpan":
+        return tracing.StageSpan(
+            self.tracer, name, getattr(self, "_trace_experiment", self.experiment_name),
+            self.trace_id, self.trace_parent,
         )
+
+    def span(self, name: str, parent: Optional[str] = None, **attrs):
+        """``with ctx.span("load_data"):`` — a stage of the trial function as
+        a child of the lifecycle span that is open (``compile`` up to the
+        first report, ``steps`` after it), and as ``katib:<name>`` in any
+        profiler trace. A shared no-op where tracing is off."""
+        if self.tracer is None:
+            return tracing._NOOP_CM
+        if parent is None:
+            stage = getattr(self, "_compile_span", None) or getattr(self, "_steps_span", None)
+            open_span = stage.span if stage is not None else None
+            parent = open_span.span_id if open_span is not None else self.trace_parent
+        return self.tracer.span(
+            name, getattr(self, "_trace_experiment", self.experiment_name),
+            self.trace_id, parent, **attrs,
+        )
+
+    def watch_step(self, step_fn: Callable) -> Callable:
+        """Wrap the trial's step function so that the ``steps`` span's ledger
+        (tracing.StepLedger) sees each call: the seconds inside it (dispatch)
+        and when it returned; each call is a ``katib:step`` annotation too.
+        Arguments, outputs and donation are the wrapped function's. With no
+        tracer bound this is the identity."""
+        ledger = getattr(self, "_ledger", None)
+        if ledger is None:
+            return step_fn
+        clock, stepped = ledger.clock, ledger.stepped
+        enter, leave = tracing.enter_annotation, tracing.leave_annotation
+
+        def step(*args, **kwargs):
+            note = enter("step")
+            t_call = clock()
+            try:
+                out = step_fn(*args, **kwargs)
+            finally:
+                t_return = clock()
+                leave(note)
+            stepped(t_call, t_return)
+            return out
+
+        return step
 
     def _trace_fn_start(self) -> None:
         """Executor hook: the trial function is about to run. Everything up
         to the first report is attributed to `compile` (trace-and-compile of
-        the train step dominates it on JAX workloads)."""
+        the train step dominates it on JAX workloads); what JAX times of it
+        on this thread becomes its children."""
         if self.tracer is not None:
-            self._compile_span = self._trace_span("compile")
+            self._compile_span = self._stage("compile")
+            if self._compile_span.span is not None:
+                tracing.route_compile_events()
         if self.step_clock is not None:
             from . import stepstats
 
             self._step_clock_token = stepstats.activate([self.step_clock])
 
-    def _trace_mark_report(self) -> None:
+    def _trace_mark_report(self, t_entry: Optional[float] = None) -> None:
         """First report = compile boundary: end `compile`, open `steps`."""
         self._report_count = getattr(self, "_report_count", 0) + 1
         cs = getattr(self, "_compile_span", None)
         if cs is not None:
-            self.tracer.end_span(cs, first_report=True)
+            ledger = getattr(self, "_ledger", None)
+            first = ledger.first_report(t_entry) if ledger is not None and t_entry is not None else {}
+            self._end_compile(cs, first_report=True, **first)
             self._compile_span = None
-            self._steps_span = self._trace_span("steps")
+            self._steps_span = self._stage("steps")
+
+    def _end_compile(self, cs: "tracing.StageSpan", **attrs) -> None:
+        if cs.span is not None:
+            short = tracing.unroute_compile_events(
+                self.tracer, getattr(self, "_trace_experiment", self.experiment_name),
+                self.trace_id, cs.span.span_id,
+            )
+            if short:
+                attrs["short_events"] = short
+        cs.end(**attrs)
 
     def _trace_fn_end(self) -> None:
         """Executor hook: the trial function returned/unwound."""
@@ -115,11 +168,15 @@ class TrialContext:
         if cs is not None:
             # the function never reported: the whole run was one opaque
             # stretch — keep it labeled compile with the zero-report marker
-            self.tracer.end_span(cs, reports=0)
+            self._end_compile(cs, reports=0)
             self._compile_span = None
         ss = getattr(self, "_steps_span", None)
         if ss is not None:
-            self.tracer.end_span(ss, reports=getattr(self, "_report_count", 0))
+            ledger = getattr(self, "_ledger", None)
+            ss.end(
+                **(ledger.attrs() if ledger is not None else {}),
+                reports=getattr(self, "_report_count", 0),
+            )
             self._steps_span = None
 
     def report(self, **metrics: float) -> None:
@@ -127,8 +184,23 @@ class TrialContext:
         early-stopping rules have tripped, TrialPreempted when the fair-share
         policy needs this trial's chips (metrics are persisted first — save
         your checkpoint BEFORE reporting and preemption loses nothing)."""
-        if self.tracer is not None:
-            self._trace_mark_report()
+        ledger = getattr(self, "_ledger", None)
+        if ledger is None:
+            if self.tracer is not None:
+                self._trace_mark_report()
+            self._push(metrics, None)
+            return
+        t_entry = ledger.clock()
+        self._trace_mark_report(t_entry)
+        note = tracing.enter_annotation("report")
+        try:
+            self._push(metrics, ledger)
+        finally:
+            # an unwind (early stop, kill, preemption) leaves through here too
+            tracing.leave_annotation(note)
+            ledger.reported(t_entry)
+
+    def _push(self, metrics: Dict[str, float], ledger) -> None:
         if self.on_report is not None:
             self.on_report()  # watchdog heartbeat BEFORE a possible unwind
         sc = self.step_clock
@@ -144,7 +216,16 @@ class TrialContext:
                 self.reporter.store.report_observation_log(
                     self.trial_name, stepstats.perf_logs(rows)
                 )
-        self.reporter.report(**metrics)
+        if ledger is None:
+            self.reporter.report(**metrics)
+            return
+        note = tracing.enter_annotation("store_write")
+        t0 = ledger.clock()
+        try:
+            self.reporter.report(**metrics)
+        finally:
+            ledger.stored(ledger.clock() - t0)
+            tracing.leave_annotation(note)
 
     def flush_metrics(self) -> None:
         """Durability barrier for write-behind observation stores
@@ -152,12 +233,8 @@ class TrialContext:
         reported so far is persisted. The runtime calls it on checkpoint
         save and before TrialPreempted/TrialKilled unwind; trial code only
         needs it around its own external side effects."""
-        span = self._trace_span("obslog_flush") if self.tracer is not None else None
-        try:
+        with self.span("obslog_flush", parent=self.trace_parent):
             self.reporter.store.flush()
-        finally:
-            if span is not None:
-                self.tracer.end_span(span)
 
     @property
     def preempt_requested(self) -> bool:
@@ -240,12 +317,8 @@ class TrialContext:
         notify, orig_save, orig_restore = self.on_checkpoint, store.save, store.restore
 
         def _save(step, state, _notify=notify, _orig=orig_save):
-            span = self._trace_span("checkpoint_save", step=int(step)) if self.tracer else None
-            try:
+            with self.span("checkpoint_save", parent=self.trace_parent, step=int(step)):
                 _orig(step, state)
-            finally:
-                if span is not None:
-                    self.tracer.end_span(span)
             if _notify is not None:
                 _notify(step)
             # every save is a durability point: a preemption decided against
@@ -253,14 +326,11 @@ class TrialContext:
             self.flush_metrics()
 
         def _restore(step=None, template=None, _orig=orig_restore):
-            span = self._trace_span("checkpoint_restore") if self.tracer else None
-            restored = None
-            try:
+            with self.span("checkpoint_restore", parent=self.trace_parent) as span:
+                span.set(found=False)
                 restored = _orig(step=step, template=template)
+                span.set(found=restored is not None)
                 return restored
-            finally:
-                if span is not None:
-                    self.tracer.end_span(span, found=restored is not None)
 
         store.save = _save  # instance-level shadow; CheckpointStore API unchanged
         store.restore = _restore

@@ -50,6 +50,17 @@ class WorkerContext:
         for name, value in metrics.items():
             print(f"{name}={value}", flush=True)
 
+    def span(self, name: str, **attrs):
+        """TrialContext.span where no tracer is bound: gang workers keep no
+        spans, so the shared no-op."""
+        from ..tracing import _NOOP_CM
+
+        return _NOOP_CM
+
+    def watch_step(self, step_fn):
+        """TrialContext.watch_step where no tracer is bound: the identity."""
+        return step_fn
+
     def param(self, name: str, default: Optional[str] = None) -> Optional[str]:
         return self.assignments.get(name, default)
 

@@ -38,6 +38,7 @@ import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -514,7 +515,7 @@ class Tracer:
 class _SpanCM:
     """Context manager returned by Tracer.span when enabled."""
 
-    __slots__ = ("_tracer", "_name", "_experiment", "_trace_id", "_parent_id", "_attrs", "_span", "_token")
+    __slots__ = ("_tracer", "_name", "_experiment", "_trace_id", "_parent_id", "_attrs", "_stage", "_token")
 
     def __init__(self, tracer, name, experiment, trace_id, parent_id, attrs):
         self._tracer = tracer
@@ -523,7 +524,7 @@ class _SpanCM:
         self._trace_id = trace_id
         self._parent_id = parent_id
         self._attrs = attrs
-        self._span = None
+        self._stage = None
         self._token = None
 
     def __enter__(self) -> Span:
@@ -538,18 +539,239 @@ class _SpanCM:
                     trace_id, parent_id = inherited
                 else:
                     trace_id = Tracer.new_trace_id()
-        self._span = self._tracer.start_span(
-            self._name, self._experiment, trace_id, parent_id, attrs=self._attrs
+        self._stage = StageSpan(
+            self._tracer, self._name, self._experiment, trace_id, parent_id, self._attrs
         )
-        self._token = _current_span.set(self._span)
-        return self._span
+        self._token = _current_span.set(self._stage.span)
+        return self._stage.span
 
     def __exit__(self, exc_type, exc, tb):
         _current_span.reset(self._token)
-        self._tracer.end_span(
-            self._span, **({"error": exc_type.__name__} if exc_type else {})
-        )
+        self._stage.end(**({"error": exc_type.__name__} if exc_type else {}))
         return False
+
+
+# -- both clocks: the profiler's mirror of one-thread spans -------------------
+#
+# A span that one thread opens and closes is also a
+# ``jax.profiler.TraceAnnotation`` named ``katib:<name>``, so any xplane
+# trace of the process (``ctx.profile()``, a harness's) shows the program's
+# spans on the profiler's clock beside the device's operations. Only where
+# ``jax`` is already imported (the CLI and controllers of subprocess trials
+# never import it), and only from call sites that tracing being on guards.
+# With no profiler session an annotation is a flag check.
+
+ANNOTATION_PREFIX = "katib:"
+
+
+def enter_annotation(name: str):
+    """An entered ``katib:<name>`` annotation, or None where this process has
+    not imported jax. Leave it on the same thread with leave_annotation."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        note = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    except AttributeError:  # another thread is half way through importing jax
+        return None
+    note.__enter__()
+    return note
+
+
+def leave_annotation(note) -> None:
+    if note is not None:
+        note.__exit__(None, None, None)
+
+
+class StageSpan:
+    """A span that one thread opens and closes, on both clocks: what
+    ``Tracer.span`` blocks are made of, and by itself the runtime's ``compile``
+    and ``steps``, which do not nest lexically."""
+
+    __slots__ = ("span", "_tracer", "_note")
+
+    def __init__(self, tracer: Tracer, name: str, experiment: str, trace_id: str,
+                 parent_id: Optional[str], attrs: Optional[Dict[str, Any]] = None):
+        self._tracer = tracer
+        self.span = tracer.start_span(name, experiment, trace_id, parent_id, attrs=attrs)
+        self._note = enter_annotation(name) if self.span is not None else None
+
+    def end(self, **attrs) -> None:
+        leave_annotation(self._note)
+        self._note = None
+        self._tracer.end_span(self.span, **attrs)
+
+
+# -- step ledger ---------------------------------------------------------------
+
+class StepLedger:
+    """Where a trial thread's time goes between two reports.
+
+    Each report closes one *interval*, from the previous report's exit to its
+    own: the seconds inside step calls (``dispatch_s``), from the last step
+    call's return to the report's entry (``wait_s``: the trial's
+    ``float(loss)``, the thread waiting for the device), inside the report
+    (``report_s``) and, of those, inside the store write (``store_s``). What is
+    left of ``seconds`` is the trial function's own Python. Durations are on
+    ``clock`` (``time.perf_counter``), ``t_end`` on ``wall`` (``time.time``,
+    the Tracer's clock). Steps called before the first report belong to the
+    ``compile`` span: the first report hands them over (``first_report``) and
+    starts the first interval at its exit.
+
+    One thread writes it; the newest ``RING`` intervals are kept, the totals
+    keep counting.
+    """
+
+    RING = 1024
+    FIELDS = ("t_end", "seconds", "steps", "dispatch_s", "wait_s", "report_s", "store_s")
+
+    def __init__(self, clock=time.perf_counter, wall=time.time):
+        self.clock = clock
+        self.wall = wall
+        self.intervals: Deque[Tuple[float, ...]] = collections.deque(maxlen=self.RING)
+        self.totals: Dict[str, float] = dict.fromkeys(self.FIELDS[1:], 0)
+        self._t_prev: Optional[float] = None  # the previous report's exit
+        self._steps = 0
+        self._dispatch = 0.0
+        self._last_return: Optional[float] = None
+        self._store = 0.0
+
+    def stepped(self, t_call: float, t_return: float) -> None:
+        """One step call returned (``clock`` readings around it)."""
+        self._steps += 1
+        self._dispatch += t_return - t_call
+        self._last_return = t_return
+
+    def stored(self, seconds: float) -> None:
+        """The store write inside the report that is open."""
+        self._store += seconds
+
+    def _wait(self, t_entry: float) -> float:
+        return t_entry - self._last_return if self._last_return is not None else 0.0
+
+    def first_report(self, t_entry: float) -> Dict[str, float]:
+        """The step calls so far, for the ``compile`` span that this report ends."""
+        return {"steps": self._steps, "dispatch_s": self._dispatch, "wait_s": self._wait(t_entry)}
+
+    def reported(self, t_entry: float) -> None:
+        """A report that was entered at ``t_entry`` is leaving now."""
+        now = self.clock()
+        if self._t_prev is not None:
+            row = (
+                self.wall(), now - self._t_prev, self._steps, self._dispatch,
+                self._wait(t_entry), now - t_entry, self._store,
+            )
+            self.intervals.append(row)
+            for key, value in zip(self.FIELDS[1:], row[1:]):
+                self.totals[key] += value
+        self._t_prev = now
+        self._steps, self._dispatch, self._last_return, self._store = 0, 0.0, None, 0.0
+
+    def attrs(self) -> Dict[str, Any]:
+        """What the ``steps`` span carries when it ends."""
+        return dict(
+            self.totals, interval_fields=list(self.FIELDS),
+            intervals=[list(row) for row in self.intervals],
+        )
+
+
+# -- compile stages: jax.monitoring's timed events, routed by thread -----------
+
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class _CompileRoute:
+    """What JAX timed on one thread while its ``compile`` span is open. JAX
+    times every jitted function it traces, the ones inside another's trace
+    too, so of one stage only the outermost events are kept: the children
+    of one stage never overlap, and their seconds add up. Events under
+    ``SHORT_S`` (a hundred or more of them in a model's initialisation) are
+    only counted, ``{stage: [events, seconds]}``."""
+
+    SHORT_S = 1e-3
+    __slots__ = ("events", "short", "cache")
+
+    def __init__(self) -> None:
+        self.events: List[Tuple[str, float, float, Dict[str, Any]]] = []
+        self.short: Dict[str, List[float]] = {}
+        self.cache: Optional[str] = None  # a verdict no backend_compile carries yet
+
+    def add(self, name: str, start: float, end: float) -> None:
+        if end - start < self.SHORT_S:
+            count = self.short.setdefault(name, [0, 0.0])
+            count[0] += 1
+            count[1] += end - start
+            return
+        attrs = {}
+        if name == "backend_compile" and self.cache is not None:
+            attrs["cache"], self.cache = self.cache, None
+        # an event ends after everything inside it: what it encloses is here already
+        self.events = [e for e in self.events if e[0] != name or e[1] < start]
+        self.events.append((name, start, end, attrs))
+
+
+# thread ident -> its route; a thread adds and removes only its own
+_compile_routes: Dict[int, _CompileRoute] = {}
+_compile_listener_lock = threading.Lock()
+_compile_listener_installed = False
+
+
+def _on_compile_time_span(event: str, start: float, end: float, **_kw) -> None:
+    name = COMPILE_STAGES.get(event)
+    route = _compile_routes.get(threading.get_ident()) if name else None
+    if route is not None:
+        route.add(name, start, end)  # JAX's own time.time() readings: the Tracer's clock
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    verdict = _CACHE_EVENTS.get(event)
+    route = _compile_routes.get(threading.get_ident()) if verdict else None
+    if route is not None:
+        route.cache = verdict
+
+
+def route_compile_events() -> None:
+    """From now until unroute_compile_events, collect each trace, lowering and
+    backend compile that JAX times on the calling thread. The process's one
+    listener is registered at the first call; nothing happens where jax is
+    not imported."""
+    global _compile_listener_installed
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    with _compile_listener_lock:
+        if not _compile_listener_installed:
+            try:
+                jax.monitoring.register_event_time_span_listener(_on_compile_time_span)
+                jax.monitoring.register_event_listener(_on_compile_event)
+            except AttributeError:  # half-imported jax, or one without these hooks
+                return
+            _compile_listener_installed = True
+    _compile_routes[threading.get_ident()] = _CompileRoute()
+
+
+def unroute_compile_events(
+    tracer: Tracer, experiment: str, trace_id: str, parent_id: str
+) -> Dict[str, List[float]]:
+    """Stop collecting on the calling thread and record what was collected as
+    child spans of ``parent_id``: ``jaxpr_trace``, ``lower`` and
+    ``backend_compile`` (with the persistent cache's ``hit`` or ``miss``
+    where JAX reported one). Returns the count of the events too short for a
+    span of their own, for the parent's attrs."""
+    route = _compile_routes.pop(threading.get_ident(), None)
+    if route is None:
+        return {}
+    for name, start, end, attrs in route.events:
+        tracer.record_span(name, experiment, trace_id, parent_id, start, end, **attrs)
+    return route.short
 
 
 # -- process-global tracer (subprocess trials, RPC services) -----------------
@@ -981,7 +1203,7 @@ def render_tree(spans: Sequence[Span]) -> str:
         label = ("  " * depth + span.name).ljust(width + depth * 2)
         note = "" if span.ended else "  (open)"
         keys = {
-            k: v
+            k: f"[{len(v)}]" if isinstance(v, list) else v  # the step ledger's ring: its length
             for k, v in span.attrs.items()
             if k not in ("experiment", "trial") and v not in (None, "")
         }
