@@ -13,6 +13,7 @@ tests in this one file for the same reason.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,3 +124,79 @@ def test_flash_value_and_grad_compiles_for_v5e(one_chip, shape, dtype):
 
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x)
     assert text.count("tpu_custom_call") == 3
+
+
+def entry_ops_by_estimated_cycles(compiled_text):
+    """[(cycles, instruction, start of its result type, op_name)] of the
+    compiled module's entry computation, the compiler's costliest first.
+    Pallas calls carry no estimate and are left out. At the v5e's 1.5 GHz
+    the estimates read within 4 % of the chip on the head's products and
+    the embedding's AdamW, and 60 % over it on the convolution this file
+    guards against (PERF.md, PR 28): good for an order, not for a time."""
+    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", compiled_text, re.S | re.M).group(1)
+    rows = []
+    for line in entry.splitlines():
+        cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
+        head = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+)", line)
+        if cycles and head:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            rows.append((int(cycles.group(1)), head.group(1), head.group(2)[:48],
+                         op_name.group(1) if op_name else ""))
+    return sorted(rows, reverse=True)
+
+
+def test_qkv_weight_gradient_is_no_windowed_convolution_on_v5e(one_chip, monkeypatch):
+    """One Block + AdamW at the benchmark cells' widths (E 2048, 16 heads of
+    128, batch 4 x 2048). With q, k and v sliced out of one DenseGeneral's
+    result, XLA made the weight gradient one convolution over the tokens
+    with [3, H] as its window (``window={size=3x16x4 ...}``), a third as
+    fast as the three products it stands for (PERF.md, PR 28)."""
+    import optax
+
+    from katib_tpu.models.transformer import Block, TransformerConfig
+    from katib_tpu.ops import flash_attention as fa
+
+    # the CPU backend is the default one here: take the chip's branch
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    heads, b, t, e = 16, 4, 2048, 2048
+    cfg = TransformerConfig(embed_dim=e, num_heads=heads, max_seq_len=t)
+    block = Block(cfg)
+    tx = optax.adamw(1e-3, weight_decay=0.01)
+    init_x = jnp.zeros((1, 16, e), cfg.dtype)
+    init_pos = jnp.zeros((1, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda k: block.init(k, init_x, init_pos)["params"], jax.random.PRNGKey(0)
+    )
+    opt_state = jax.eval_shape(tx.init, params)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    def step(params, opt_state, x, positions):
+        def loss_fn(p):
+            return block.apply({"params": p}, x, positions).astype(jnp.float32).sum()
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state),
+        jax.ShapeDtypeStruct((b, t, e), cfg.dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # the flash kernels are in it
+    windowed = [
+        line.strip()[:200] for line in text.splitlines()
+        if " convolution(" in line and f"window={{size=3x{heads}x" in line
+    ]
+    assert windowed == []
+    ranked = entry_ops_by_estimated_cycles(text)
+    qkv_grad = [r for r in ranked if "transpose(jvp" in r[3] and "attn/qkv" in r[3]]
+    mlp_grad = [r for r in ranked if "transpose(jvp" in r[3] and "/mlp/" in r[3]]
+    assert qkv_grad and mlp_grad, ranked[:10]
+    # the MLP's weight gradients do 1.3 times the arithmetic of q/k/v's
+    assert qkv_grad[0][0] < mlp_grad[0][0], (qkv_grad[0], mlp_grad[0])
