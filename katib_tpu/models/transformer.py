@@ -22,6 +22,7 @@ bfloat16 activations/matmuls with f32 params + optimizer state.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -32,6 +33,58 @@ import numpy as np
 
 from ..ops.flash_attention import flash_attention, sharded_flash_attention
 from ..ops.ring_attention import dense_attention, ring_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN's rescaling of rotary frequencies (arXiv:2309.00071): frequencies
+    that turn fewer than ``beta_slow`` times over ``original_positions`` are
+    divided by ``factor``, those that turn more than ``beta_fast`` times are
+    kept, the ones between are blended; cos and sin carry ``attention_factor``."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class RotaryConfig:
+    theta: float = 10000.0
+    fraction: float = 1.0               # share of each head that is rotated: its first dimensions
+    yarn: Optional[YarnConfig] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerConfig:
+    """What one depth is made of. ``attention``: "full" (causal) or "sliding"
+    (causal within ``TransformerConfig.window``); ``mlp``: "dense" (SwiGLU) or
+    "routed" (RoutedExperts)."""
+
+    attention: str = "full"
+    num_heads: Optional[int] = None     # query heads of this layer; None: TransformerConfig.num_heads
+    mlp: str = "dense"
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedExpertsConfig:
+    """Drop-free routing of every token to ``experts_per_token`` of
+    ``router_width`` experts, of which this program holds ``num_experts``
+    starting at ``first_expert`` (None: all of them). What the experts held
+    elsewhere would add is left out: the layer computes its share."""
+
+    router_width: int
+    experts_per_token: int
+    hidden: int                         # an expert's width
+    num_experts: Optional[int] = None
+    first_expert: int = 0
+    routed_scale: float = 1.0
+    shared_hidden: int = 0              # width of the expert every token passes; 0: none
+
+    @property
+    def held(self) -> int:
+        return self.router_width if self.num_experts is None else self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,10 +102,28 @@ class TransformerConfig:
     num_experts: int = 0
     expert_capacity_factor: float = 2.0
     moe_aux_weight: float = 1e-2
+    # What follows is None/empty for the decoder above: one kind of layer,
+    # multi-head attention, heads of embed_dim / num_heads, a tied head.
+    head_size: Optional[int] = None     # stated head size (num_heads * head_size need not be embed_dim)
+    num_kv_heads: Optional[int] = None  # grouped KV heads; None: one per query head
+    mlp_hidden: Optional[int] = None    # the dense SwiGLU's width; None: mlp_ratio * embed_dim
+    window: Optional[int] = None        # keys a "sliding" layer's query sees, itself included
+    layers: Tuple[LayerConfig, ...] = ()                # by depth; empty: LayerConfig() at every depth
+    rotary: Tuple[Tuple[str, RotaryConfig], ...] = ()   # by attention kind; a kind left out: RotaryConfig()
+    attention_gate: bool = False        # a per-head sigmoid gate on the attention output
+    tied_head: bool = True
+    routed: Optional[RoutedExpertsConfig] = None        # the "routed" layers' experts
+    rms_eps: float = 1e-6
 
     @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.head_size or self.embed_dim // self.num_heads
+
+    def layer(self, depth: int) -> LayerConfig:
+        return self.layers[depth] if self.layers else LayerConfig()
+
+    def rotary_of(self, kind: str) -> RotaryConfig:
+        return dict(self.rotary).get(kind, RotaryConfig())
 
 
 def bench_lm_config(size: str, on_tpu: bool):
@@ -81,16 +152,52 @@ def bench_lm_config(size: str, on_tpu: bool):
     )
 
 
-def rotary_embed(x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
-    """RoPE on [B, T, H, D] with explicit global positions [B, T]."""
+def rotary_frequencies(rotated: int, theta: float, yarn: YarnConfig) -> np.ndarray:
+    """YaRN's ``rotated // 2`` frequencies, as the published modelling code
+    computes them (transformers ``_compute_yarn_parameters``)."""
+    half = rotated // 2
+    turns = theta ** (np.arange(half, dtype=np.float64) * 2.0 / rotated)
+
+    def correction(rotations: float) -> float:
+        return rotated * math.log(yarn.original_positions / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction(yarn.beta_slow)), rotated - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return ((1.0 / (yarn.factor * turns)) * ramp + (1.0 / turns) * (1.0 - ramp)).astype(np.float32)
+
+
+def rotary_embed(
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    theta: float = 10000.0,
+    fraction: float = 1.0,
+    yarn: Optional[YarnConfig] = None,
+) -> jnp.ndarray:
+    """RoPE on [B, T, H, D] with explicit global positions [B, T]: the first
+    ``fraction`` of D is rotated (its two halves are the pairs), the rest is
+    passed through."""
     d = x.shape[-1]
-    half = d // 2
-    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32) * (math.log(10000.0) / half))
+    rotated = int(d * fraction)
+    half = rotated // 2
+    if yarn is None:
+        freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32) * (math.log(theta) / half))
+        scale = 1.0
+    else:
+        freqs = jnp.asarray(rotary_frequencies(rotated, theta, yarn))
+        scale = yarn.attention_factor
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, half]
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    sin = jnp.sin(angles)[:, :, None, :]
+    cos = jnp.cos(angles)[:, :, None, :]
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
+    sin, cos = sin.astype(x.dtype), cos.astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:rotated]
+    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if rotated < d:
+        parts.append(x[..., rotated:])
+    return jnp.concatenate(parts, axis=-1)
 
 
 class RMSNorm(nn.Module):
@@ -134,6 +241,25 @@ class QKVProjection(nn.Module):
         return tuple(jnp.einsum("...e,ehd->...hd", x, kernel[:, i]) for i in range(3))
 
 
+class GroupedQKVProjection(nn.Module):
+    """q over ``num_heads`` heads, k and v over ``num_kv_heads``: three stored
+    kernels, three plain products (grouped KV heads have no [E, 3, H, D])."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def project(name, heads):
+            return nn.DenseGeneral(
+                (heads, self.head_dim), use_bias=False, dtype=self.dtype, name=name)(x)
+
+        return project("q", self.num_heads), project("k", self.num_kv_heads), project(
+            "v", self.num_kv_heads)
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     mesh: Optional[Any] = None
@@ -142,26 +268,38 @@ class Attention(nn.Module):
     # runs the ring schedule directly over that axis instead of wrapping its
     # own shard_map. positions must be GLOBAL (caller offsets by rank).
     seq_axis: Optional[str] = None
+    layer: LayerConfig = LayerConfig()
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
-        h, d = cfg.num_heads, cfg.head_dim
-        q, k, v = QKVProjection(h, d, dtype=cfg.dtype, name="qkv")(x)
-        q = rotary_embed(q, positions)
-        k = rotary_embed(k, positions)
+        h, d = self.layer.num_heads or cfg.num_heads, cfg.head_dim
+        kv = cfg.num_kv_heads or h
+        if self.layer.attention not in ("full", "sliding"):
+            raise ValueError(f"no attention of the kind {self.layer.attention!r}")
+        window = cfg.window if self.layer.attention == "sliding" else None
+        if self.layer.attention == "sliding" and not window:
+            raise ValueError("a sliding layer needs TransformerConfig.window")
+        if kv == h:
+            q, k, v = QKVProjection(h, d, dtype=cfg.dtype, name="qkv")(x)
+        else:
+            q, k, v = GroupedQKVProjection(h, kv, d, dtype=cfg.dtype, name="qkv")(x)
+        rotary = cfg.rotary_of(self.layer.attention)
+        q = rotary_embed(q, positions, rotary.theta, rotary.fraction, rotary.yarn)
+        k = rotary_embed(k, positions, rotary.theta, rotary.fraction, rotary.yarn)
+        ring_over_mesh = self.mesh is not None and _mesh_axis_size(self.mesh, "seq") > 1
+        if (self.seq_axis is not None or ring_over_mesh) and (kv != h or window is not None):
+            raise NotImplementedError(
+                "ring attention over a sequence axis takes neither grouped KV heads nor a window")
         if self.seq_axis is not None:
             from ..ops.ring_attention import ring_attention_local
 
             o = ring_attention_local(q, k, v, self.seq_axis, causal=cfg.causal)
         elif self.mesh is not None:
-            from ..parallel.mesh import mesh_axis_sizes
-
-            sizes = mesh_axis_sizes(self.mesh)
             # 'expert' is a batch axis here: outside the MoE layers it acts
             # as pure data parallelism (see parallel.mesh.activation_batch_axes)
             batch_axes = ("data", "fsdp", "expert")
-            if sizes.get("seq", 1) > 1:
+            if ring_over_mesh:
                 # cross-device sequence blocks: ring schedule over ppermute
                 o = ring_attention(
                     q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes
@@ -169,27 +307,192 @@ class Attention(nn.Module):
             else:
                 # seq unsharded: fused Pallas flash kernel per local shard
                 o = sharded_flash_attention(
-                    q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes
+                    q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes,
+                    **({} if window is None else {"window": window}),
                 )
         else:
-            o = flash_attention(q, k, v, causal=cfg.causal)
+            o = flash_attention(q, k, v, causal=cfg.causal, window=window)
+        if cfg.attention_gate:
+            # one sigmoid gate a head, from the layer's input, scores in float32
+            gate = nn.Dense(h, use_bias=False, dtype=jnp.float32, name="gate")(
+                x.astype(jnp.float32))
+            o = o * jax.nn.sigmoid(gate)[..., None].astype(o.dtype)
         return nn.DenseGeneral(
             cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out"
         )(o)
 
 
+def _mesh_axis_size(mesh, axis: str) -> int:
+    from ..parallel.mesh import mesh_axis_sizes
+
+    return mesh_axis_sizes(mesh).get(axis, 1)
+
+
 class MLP(nn.Module):
     config: TransformerConfig
+    hidden: Optional[int] = None  # None: the configuration's dense width
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        hidden = cfg.embed_dim * cfg.mlp_ratio
+        hidden = self.hidden or cfg.mlp_hidden or cfg.embed_dim * cfg.mlp_ratio
         up = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="up")(x)
         gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="gate")(x)
         return nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype, name="down")(
             nn.silu(gate) * up
         )
+
+
+# ---------------------------------------------------------------------------
+# Routed experts: drop-free top-k over a share of the experts
+# ---------------------------------------------------------------------------
+
+def route(scores: jnp.ndarray, routed: RoutedExpertsConfig):
+    """``scores`` [N, router_width], float32 in (0, 1). Returns ``weights``
+    [N, k] float32 — the k largest scores of each token, normalised to sum 1
+    and scaled —, ``local`` [N, k] int32: the chosen experts counted from the
+    first one held here, ``routed.held`` for one held elsewhere, and ``chosen``
+    [N, k]: the experts by the router's own numbering."""
+    top, chosen = jax.lax.top_k(scores, routed.experts_per_token)
+    weights = routed.routed_scale * top / jnp.sum(top, axis=-1, keepdims=True)
+    local = chosen - routed.first_expert
+    local = jnp.where((local >= 0) & (local < routed.held), local, routed.held)
+    return weights, local.astype(jnp.int32), chosen
+
+
+def dispatch_plan(local: jnp.ndarray, held: int, tile: int, spare: int = 0):
+    """Where every assignment goes in the tile-aligned buffer that the grouped
+    products read (ops/grouped_matmul.py): tokens sorted by expert, every
+    expert's rows starting on a tile and at least one tile long.
+
+    ``local`` [N, k] as ``route`` gives it. The buffer has
+    ``(ceil(N k / tile) + held + spare) * tile`` rows, enough for any routing
+    with ``spare`` tiles never in use at its end: no assignment is ever
+    dropped. Returns a dict of
+    ``dest`` [N, k]: an assignment's row (0 where ``landed`` is false),
+    ``landed`` [N, k]: it is to an expert held here,
+    ``source`` [M]: the flat assignment ``t * k + j`` a row holds (N k: none),
+    ``tile_group`` [M / tile], ``num_tiles`` (), ``load`` [held]."""
+    n, k = local.shape
+    flat = local.reshape(-1)
+    landed = flat < held
+    onehot = (flat[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]).astype(jnp.int32)
+    load = jnp.sum(onehot, axis=0)                                   # [held]
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)  # place among its expert's
+    tiles_of = jnp.maximum((load + tile - 1) // tile, 1)
+    first_tile = jnp.cumsum(tiles_of) - tiles_of                     # [held]
+    num_tiles = jnp.sum(tiles_of)
+    safe = jnp.minimum(flat, held - 1)
+    dest = jnp.where(landed, first_tile[safe] * tile + rank, 0)
+
+    tiles = -(-n * k // tile) + held + spare
+    # tile i's expert: the last one whose first tile is not after i
+    tile_group = jnp.sum(
+        jnp.arange(tiles, dtype=jnp.int32)[:, None] >= first_tile[None, :], axis=1) - 1
+    tile_group = jnp.clip(tile_group, 0, held - 1).astype(jnp.int32)
+    # row r of expert g holds the (r - first row of g)-th of g's assignments in
+    # token order: position first_sorted[g] + that in the stable sort by expert
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    first_sorted = jnp.cumsum(load) - load
+    rows = jnp.arange(tiles * tile, dtype=jnp.int32)
+    group = tile_group[rows // tile]
+    within = rows - first_tile[group] * tile
+    filled = (within < load[group]) & (rows // tile < num_tiles)
+    source = jnp.where(filled, order[jnp.minimum(first_sorted[group] + within, n * k - 1)], n * k)
+    return {
+        "dest": dest.reshape(n, k).astype(jnp.int32), "landed": landed.reshape(n, k),
+        "source": source.astype(jnp.int32), "tile_group": tile_group,
+        "num_tiles": num_tiles.astype(jnp.int32), "load": load,
+    }
+
+
+@jax.custom_vjp
+def gather_rows(x, index, valid, readers, readers_valid):
+    """``y[r] = x[index[r]]`` where ``valid[r]``, else 0. ``readers``
+    [rows of x, fan] names, for each row of ``x``, the rows of ``y`` that read
+    it (``readers_valid`` says which entries count), so the gradient is a
+    gather too: a scatter-add over tens of thousands of rows costs many times
+    the gather on a TPU."""
+    return jnp.where(valid[:, None], jnp.take(x, index, axis=0, mode="clip"), 0).astype(x.dtype)
+
+
+def _gather_rows_fwd(x, index, valid, readers, readers_valid):
+    return gather_rows(x, index, valid, readers, readers_valid), (index, valid, readers, readers_valid)
+
+
+def _gather_rows_bwd(res, dy):
+    index, valid, readers, readers_valid = res
+    read = jnp.take(dy, readers, axis=0, mode="clip")               # [rows of x, fan, E]
+    dx = jnp.sum(
+        jnp.where(readers_valid[..., None], read, 0).astype(jnp.float32), axis=1)
+    return dx.astype(dy.dtype), None, None, None, None
+
+
+gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """The experts of a "routed" layer that this program holds, and the expert
+    every token passes.
+
+    Router scores are sigmoid, float32; each token goes to its
+    ``experts_per_token`` best of ``router_width``, with weights normalised
+    over the chosen and scaled. No capacity: every assignment to an expert held
+    here is computed, whatever the load (``dispatch_plan``); assignments to
+    experts held elsewhere add nothing here. On a mesh with an 'expert' axis
+    this is the same layer told another share; no exchange is part of it.
+
+    Sows ``routing`` under 'intermediates': the assignments that landed here,
+    the largest and the mean load of a held expert (and ``chosen``, the experts
+    each token chose, for who wants to compare selections)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.grouped_matmul import CHUNK_TILES, TILE, grouped_matmul
+
+        cfg, routed = self.config, self.config.routed
+        b, t, e = x.shape
+        n, k, held, f = b * t, routed.experts_per_token, routed.held, routed.hidden
+        tokens = x.reshape(n, e)
+
+        logits = nn.Dense(
+            routed.router_width, use_bias=False, dtype=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST, name="router",
+        )(tokens.astype(jnp.float32))
+        weights, local, chosen = route(jax.nn.sigmoid(logits), routed)
+        plan = dispatch_plan(local, held, TILE, spare=CHUNK_TILES - 1)
+        self.sow("intermediates", "routing", {
+            "landed": jnp.sum(plan["landed"]).astype(jnp.float32),
+            "load_max": jnp.max(plan["load"]).astype(jnp.float32),
+            "load_mean": jnp.mean(plan["load"].astype(jnp.float32)),
+            "chosen": chosen,
+        })
+
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        w_gate = self.param("gate", init, (held, e, f), jnp.float32)
+        w_up = self.param("up", init, (held, e, f), jnp.float32)
+        w_down = self.param("down", init, (held, f, e), jnp.float32)
+
+        @jax.checkpoint  # a layer's buffers are for N k rows: kept for one layer at a time
+        def experts(tokens, weights, w_gate, w_up, w_down):
+            tokens = tokens.astype(cfg.dtype)
+            source, dest, landed = plan["source"], plan["dest"], plan["landed"]
+            filled = source < n * k
+            rows = gather_rows(tokens, source // k, filled, dest, landed)
+            products = functools.partial(
+                grouped_matmul, tile_group=plan["tile_group"], num_tiles=plan["num_tiles"])
+            hidden = nn.silu(products(rows, w_gate)) * products(rows, w_up)
+            out = products(hidden, w_down)
+            back = gather_rows(out, dest.reshape(-1), landed.reshape(-1), source[:, None],
+                               filled[:, None]).reshape(n, k, e)
+            return jnp.sum(weights[..., None] * back.astype(jnp.float32), axis=1).astype(cfg.dtype)
+
+        y = experts(tokens, weights, w_gate, w_up, w_down).reshape(b, t, e)
+        if routed.shared_hidden:
+            y = y + MLP(cfg, hidden=routed.shared_hidden, name="shared")(x)
+        return y
 
 
 class MoE(nn.Module):
@@ -373,6 +676,23 @@ def collect_moe_aux(mutated) -> jnp.ndarray:
     )
 
 
+def collect_routing(mutated) -> Dict[str, jnp.ndarray]:
+    """The counters every RoutedExperts layer sowed, summed over the layers:
+    ``landed`` (assignments to experts held here), ``load_max`` and
+    ``load_mean`` (the largest and the mean load of a held expert)."""
+    import flax
+
+    flat = flax.traverse_util.flatten_dict(mutated.get("intermediates", {}))
+    total: Dict[str, jnp.ndarray] = {}
+    for path, sown in flat.items():
+        if path[-1] == "routing":  # a tuple: one entry a call of the layer
+            for counters in sown:
+                for name, value in counters.items():
+                    if name != "chosen":
+                        total[name] = total.get(name, 0.0) + value
+    return total
+
+
 def _pin_residual(x, mesh):
     """Pin the residual stream [B, T, E] to its canonical layout (batch over
     'data'/'fsdp', sequence over 'seq', embed replicated).
@@ -406,22 +726,28 @@ class Block(nn.Module):
     seq_axis: Optional[str] = None      # see Attention.seq_axis
     expert_axis: Optional[str] = None   # see MoE.expert_axis
     expert_axis_size: int = 1
+    layer: LayerConfig = LayerConfig()  # what this depth is made of
 
     @nn.compact
     def __call__(self, x, positions):
+        eps = self.config.rms_eps
         x = _pin_residual(
-            x + Attention(self.config, self.mesh, self.seq_axis, name="attn")(
-                RMSNorm(name="ln1")(x), positions
+            x + Attention(self.config, self.mesh, self.seq_axis, self.layer, name="attn")(
+                RMSNorm(eps, name="ln1")(x), positions
             ),
             self.mesh,
         )
-        if self.config.num_experts > 0:
+        if self.layer.mlp == "routed":
+            x = x + RoutedExperts(self.config, name="experts")(RMSNorm(eps, name="ln2")(x))
+        elif self.layer.mlp != "dense":
+            raise ValueError(f"no MLP of the kind {self.layer.mlp!r}")
+        elif self.config.num_experts > 0:
             x = x + MoE(
                 self.config, self.mesh, self.expert_axis, self.expert_axis_size,
                 name="moe",
-            )(RMSNorm(name="ln2")(x))
+            )(RMSNorm(eps, name="ln2")(x))
         else:
-            x = x + MLP(self.config, name="mlp")(RMSNorm(name="ln2")(x))
+            x = x + MLP(self.config, name="mlp")(RMSNorm(eps, name="ln2")(x))
         return _pin_residual(x, self.mesh)
 
 
@@ -453,12 +779,16 @@ class TransformerLM(nn.Module):
             )
         x = _pin_residual(emb[tokens].astype(cfg.dtype), self.mesh)
         for i in range(cfg.num_layers):
-            x = Block(cfg, self.mesh, name=f"block{i}")(x, positions)
-        x = RMSNorm(name="ln_f")(x)
-        # tied output head — the largest matmul in the model: bf16 operands
-        # at native MXU rate, f32 accumulation for the softmax/loss
+            x = Block(cfg, self.mesh, layer=cfg.layer(i), name=f"block{i}")(x, positions)
+        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        # the output head, tied to the embedding or its own [V, E] — the
+        # largest matmul in the model: bf16 operands at native MXU rate, f32
+        # accumulation for the softmax/loss
+        head = emb if cfg.tied_head else self.param(
+            "head", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.embed_dim), jnp.float32
+        )
         logits = jnp.einsum(
-            "bte,ve->btv", x.astype(cfg.dtype), emb.astype(cfg.dtype),
+            "bte,ve->btv", x.astype(cfg.dtype), head.astype(cfg.dtype),
             preferred_element_type=jnp.float32,
         )
         return logits
@@ -530,6 +860,8 @@ def param_sharding_rules(path: Tuple[str, ...]):
     name = "/".join(path)
     if "qkv/kernel" in name:
         return P("fsdp", None, "model", None)     # [E, 3, H, D]
+    if "qkv/q/kernel" in name or "qkv/k/kernel" in name or "qkv/v/kernel" in name:
+        return P("fsdp", "model", None)           # [E, H or KV, D]
     if "attn/out/kernel" in name:
         return P("model", None, "fsdp")           # [H, D, E]
     if "up/kernel" in name or "gate/kernel" in name:
@@ -540,7 +872,7 @@ def param_sharding_rules(path: Tuple[str, ...]):
         return P("expert", "fsdp", "model")       # [X, E, F]
     if "moe/w_out" in name:
         return P("expert", "model", "fsdp")       # [X, F, E]
-    if name == "embed":
+    if name in ("embed", "head"):
         return P(None, "fsdp")                    # [V, E]
     return P()  # replicated (norms, biases, router)
 

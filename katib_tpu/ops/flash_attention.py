@@ -63,24 +63,87 @@ def _use_kernel(interpret: Optional[bool]) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# Grouped KV heads and the window
+# ---------------------------------------------------------------------------
+#
+# Grouped heads: q is [B*H, T, D], k and v [B*KV, T, D] with H = KV * group;
+# query row ``b`` of the flattened batch reads KV row ``b // group``. The dk/dv
+# kernel walks the group's query heads in its innermost grid axis and sums.
+#
+# Window: key ``s`` is visible to query ``t`` iff ``0 <= t - s < window``. The
+# innermost grid axis then runs over the blocks of the band only: its length is
+# the most blocks any outer block's band touches, step ``j`` is block
+# ``first(outer) + j``, and a step past the band's end is skipped (its block
+# index is clamped, so nothing is fetched for it). Blocks wholly outside the
+# band are never computed. With ``window=None`` and ``group == 1`` every index
+# map and kernel body below is the plain causal one.
+
+def _first_kv_block(qi, window, block_q, block_k):
+    """First kv block that the band of q block ``qi`` touches."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _band_kv_steps(t, window, block_q, block_k) -> int:
+    """Most kv blocks a q block's band touches: keys qi*bq - window + 1 .. qi*bq + bq - 1."""
+    return max(
+        (qi * block_q + block_q - 1) // block_k - max(qi * block_q - (window - 1), 0) // block_k + 1
+        for qi in range(t // block_q)
+    )
+
+
+def _first_q_block(ki, block_q, block_k):
+    """First q block that sees kv block ``ki`` (causal: the one holding its first key)."""
+    return (ki * block_k) // block_q
+
+
+def _band_q_steps(t, window, block_q, block_k) -> int:
+    """Most q blocks that see one kv block: queries ki*bk .. ki*bk + bk + window - 2."""
+    last = t // block_q - 1
+    return max(
+        min((ki * block_k + block_k + window - 2) // block_q, last) - (ki * block_k) // block_q + 1
+        for ki in range(t // block_k)
+    )
+
+
+def _mask_scores(s, q0, k0, causal, window):
+    """Scores of the block at rows ``q0``.., columns ``k0``.. with the keys a
+    query may not see set to NEG_INF."""
+    if not causal and window is None:
+        return s
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    hidden = k_pos > q_pos
+    if window is not None:
+        hidden = hidden | (q_pos - k_pos >= window)
+    return jnp.where(hidden, NEG_INF, s)
+
+
+def _kernel_name(base: str, window) -> str:
+    """Windowed calls carry their own names, so a trace tells them apart."""
+    return base if window is None else base.replace("flash_", "flash_window_", 1)
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, causal: bool, sm_scale: float, block_q: int, block_k: int,
-                kv_steps: int):
+                kv_steps: int, window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    if window is not None:  # the grid's last axis walks the band's blocks
+        ki = _first_kv_block(qi, window, block_q, block_k) + ki
 
-    @pl.when(ki == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: skip blocks strictly above the diagonal.
+    # Causal: skip blocks strictly above the diagonal (in a band: past its end).
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
 
     @pl.when(run)
@@ -97,11 +160,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
             precision=prec,
         ) * sm_scale                                # [bq, bk] f32
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos > q_pos, NEG_INF, s)
+        s = _mask_scores(s, qi * block_q, ki * block_k, causal, window)
 
+        # a row whose keys in this block are all hidden (a band's first block)
+        # accumulates p = 1 here; the block that holds its diagonal follows,
+        # and alpha = exp(NEG_INF - m) = 0 wipes that out
         m_prev = m_ref[:, 0:1]                      # [bq, 1]
         l_prev = l_ref[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -115,7 +178,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(ki == kv_steps - 1)
+    @pl.when(pl.program_id(2) == kv_steps - 1)
     def _finish():
         l = l_ref[:, 0:1]
         denom = jnp.where(l == 0.0, 1.0, l)
@@ -123,24 +186,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = m_ref[:, 0:1] + jnp.log(jnp.maximum(l, 1e-30))
 
 
-def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    """q/k/v: [BH, T, D] -> (o [BH, T, D], lse [BH, T])."""
+def _kv_index_maps(t, block_q, block_k, window, group):
+    """(kv_steps, index map of a k/v block) for the grids whose last axis
+    walks kv blocks (forward, dq): grid ``(b, q block, step)``."""
+    if window is None:
+        if group == 1:
+            return t // block_k, lambda b, i, j: (b, j, 0)
+        return t // block_k, lambda b, i, j: (b // group, j, 0)
+    last = t // block_k - 1
+
+    def kv_index(b, i, j):
+        return (b // group, jnp.minimum(_first_kv_block(i, window, block_q, block_k) + j, last), 0)
+
+    return _band_kv_steps(t, window, block_q, block_k), kv_index
+
+
+def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, group=1):
+    """q: [B*H, T, D], k/v: [B*KV, T, D] -> (o [B*H, T, D], lse [B*H, T, 1])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
-    grid = (bh, t // block_q, t // block_k)
+    kv_steps, kv_index = _kv_index_maps(t, block_q, block_k, window, group)
+    grid = (bh, t // block_q, kv_steps)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, kv_steps=t // block_k,
+        block_q=block_q, block_k=block_k, kv_steps=kv_steps, window=window,
     )
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -159,7 +238,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
     )(q, k, v)
 
 
@@ -168,7 +247,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, causal, sm_scale,
-                    block_q, block_k):
+                    block_q, block_k, window=None):
     """Shared bwd block math: p [bq,bk] and ds [bq,bk] (pre-scaled, f32).
 
     Dots take the blocks in their native dtype (bf16 MXU rate) and accumulate
@@ -179,10 +258,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, causal, sm_scale,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         precision=prec,
     ) * sm_scale
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(k_pos > q_pos, NEG_INF, s)
+    s = _mask_scores(s, qi * block_q, ki * block_k, causal, window)
     p = jnp.exp(s - lse)                            # lse [bq, 1] broadcasts
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
@@ -193,13 +269,16 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, causal, sm_scale,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, causal, sm_scale, block_q, block_k, kv_steps):
+                   dq_acc, *, causal, sm_scale, block_q, block_k, kv_steps,
+                   window=None):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    if window is not None:
+        ki = _first_kv_block(qi, window, block_q, block_k) + ki
 
-    @pl.when(ki == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -213,32 +292,40 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         do = do_ref[0]
         _, ds = _recompute_p_ds(
             q, k, v, do, lse_ref[0], delta_ref[0], qi, ki, causal, sm_scale,
-            block_q, block_k,
+            block_q, block_k, window,
         )
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=_dot_precision(k.dtype),
         )
 
-    @pl.when(ki == kv_steps - 1)
+    @pl.when(pl.program_id(2) == kv_steps - 1)
     def _finish():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, causal, sm_scale, block_q, block_k, q_steps):
+                    *, causal, sm_scale, block_q, block_k, q_steps,
+                    window=None, group=1, q_blocks=None):
+    """One kv block of one KV head: the innermost grid axis walks the q
+    blocks that see it, of every query head of the group in turn."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = step if group == 1 else step % q_steps
+    if window is not None:
+        qi = _first_q_block(ki, block_q, block_k) + qi
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    if window is not None:  # not past the band's end, nor past the last block
+        run = run & (qi * block_q <= ki * block_k + block_k + window - 2) & (qi < q_blocks)
 
     @pl.when(run)
     def _step():
@@ -248,7 +335,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0]
         p, ds = _recompute_p_ds(
             q, k, v, do, lse_ref[0], delta_ref[0], qi, ki, causal, sm_scale,
-            block_q, block_k,
+            block_q, block_k, window,
         )
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -259,31 +346,34 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32, precision=_dot_precision(q.dtype),
         )
 
-    @pl.when(qi == q_steps - 1)
+    @pl.when(step == group * q_steps - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret):
+def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret,
+         window=None, group=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
+    bkv = k.shape[0]
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BH, T, 1]
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_spec_dq = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    kv_steps, kv_index = _kv_index_maps(t, block_q, block_k, window, group)
+    kv_spec_dq = pl.BlockSpec((1, block_k, d), kv_index)
 
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, kv_steps=t // block_k,
+            block_q=block_q, block_k=block_k, kv_steps=kv_steps, window=window,
         ),
-        grid=(bh, t // block_q, t // block_k),
+        grid=(bh, t // block_q, kv_steps),
         in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
@@ -292,25 +382,43 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_kernel_name("flash_bwd_dq", window),
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid iterates q blocks innermost for a fixed kv block.
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
+    # dk/dv: grid iterates q blocks innermost for a fixed kv block (of every
+    # query head of the group: the sum over the group is this kernel's).
+    q_blocks = t // block_q
+    if window is None:
+        q_steps = q_blocks
+        if group == 1:
+            def q_index(b, i, j):
+                return (b, j, 0)
+        else:
+            def q_index(b, i, j):
+                return (b * group + j // q_steps, j % q_steps, 0)
+    else:
+        q_steps = _band_q_steps(t, window, block_q, block_k)
+
+        def q_index(b, i, j):
+            qi = jnp.minimum(_first_q_block(i, block_q, block_k) + j % q_steps, q_blocks - 1)
+            return (b * group + j // q_steps, qi, 0)
+
+    q_spec2 = pl.BlockSpec((1, block_q, d), q_index)
     kv_spec2 = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-    row_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
+    row_spec2 = pl.BlockSpec((1, block_q, 1), q_index)
 
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, q_steps=t // block_q,
+            block_q=block_q, block_k=block_k, q_steps=q_steps,
+            window=window, group=group, q_blocks=q_blocks,
         ),
-        grid=(bh, t // block_k, t // block_q),
+        grid=(bkv, t // block_k, group * q_steps),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=[kv_spec2, kv_spec2],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, t, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, t, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -320,7 +428,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_kernel_name("flash_bwd_dkv", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -329,29 +437,30 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret):
 # custom_vjp wrapper on [BH, T, D]
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhtd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    o, _ = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_bhtd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, group=1):
+    o, _ = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, group)
     return o
 
 
-def _flash_bhtd_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+def _flash_bhtd_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, group):
+    o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, group)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bhtd_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+def _flash_bhtd_bwd(causal, sm_scale, block_q, block_k, interpret, window, group, res, do):
     q, k, v, o, lse = res
     # The backward kernels prefer symmetric MXU-sized tiles: measured on v5e
     # (T=2048 d=64 causal), fwd+bwd with the forward's asymmetric bq=512
     # runs 10% SLOWER than bq=bk=1024 despite the faster forward — so bwd
-    # blocks are chosen independently of the forward's (BWD_BLOCK_CAP).
+    # blocks are chosen independently of the forward's (BWD_BLOCK_CAP). A
+    # windowed call keeps the forward's: they were cut to the band already.
     t = q.shape[1]
-    bwd_block = _auto_block(t, BWD_BLOCK_CAP)
+    bwd_block = None if window is not None else _auto_block(t, BWD_BLOCK_CAP)
     bq = bwd_block or block_q
     bk = bwd_block or block_k
     dq, dk, dv = _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk,
-                      interpret)
+                      interpret, window, group)
     return dq, dk, dv
 
 
@@ -378,6 +487,31 @@ BWD_BLOCK_CAP = 1024    # backward tiles stay symmetric/large (see
                         # _flash_bhtd_bwd: small Q tiles regress fwd+bwd 10%)
 
 
+def _dense_grouped(q, k, v, causal, window, sm_scale):
+    """Masked dense attention for what ring_attention.dense_attention does not
+    take: grouped KV heads and a window. [B, T, H, D] against [B, T, KV, D]."""
+    b, t, h, d = q.shape
+    group = h // k.shape[2]
+    qg = q.reshape(b, t, k.shape[2], group, d)
+    prec = _dot_precision(q.dtype)
+    s = jnp.einsum("bqcgd,bkcd->bcgqk", qg, k, precision=prec,
+                   preferred_element_type=jnp.float32) * sm_scale
+    pos = jnp.arange(t)
+    gap = pos[:, None] - pos[None, :]
+    seen = jnp.ones((t, t), bool)
+    if causal:
+        seen = seen & (gap >= 0)
+    if window is not None:
+        seen = seen & (gap < window)
+    p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
+    o = jnp.einsum("bcgqk,bkcd->bqcgd", p.astype(v.dtype), v, precision=prec)
+    return o.reshape(b, t, h, d).astype(q.dtype)
+
+
+WINDOW_BLOCK_CAP = 512  # windowed calls, all three kernels: tiles no wider than
+                        # the band is deep, so most of a tile lies inside it
+
+
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -387,8 +521,14 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention on [B, T, H, D] (same layout as ring/dense attention).
+
+    ``k`` and ``v`` may carry fewer heads, [B, T, KV, D] with H a multiple of
+    KV: query head ``j`` reads KV head ``j // (H // KV)``. ``window`` (with
+    ``causal``) hides every key more than ``window - 1`` positions behind its
+    query; blocks outside the band are not computed.
 
     Differentiable (custom VJP, recompute-based backward). Forward block
     sizes default to the largest dividing multiple of 128, asymmetric
@@ -401,10 +541,24 @@ def flash_attention(
     from .ring_attention import dense_attention
 
     b, t, h, d = q.shape
-    block_q = min(block_q, t) if block_q else (_auto_block(t, FWD_BLOCK_Q_CAP) or t + 1)
-    block_k = min(block_k, t) if block_k else (_auto_block(t, FWD_BLOCK_K_CAP) or t + 1)
+    kv_heads = k.shape[2]
+    if h % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(f"{h} query heads cannot share {kv_heads} key/value heads")
+    group = h // kv_heads
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal band of at least one key")
+        if window >= t:
+            window = None  # the band is the causal half
+    q_cap, k_cap = (FWD_BLOCK_Q_CAP, FWD_BLOCK_K_CAP) if window is None else (
+        max(128, min(WINDOW_BLOCK_CAP, window)),) * 2
+    block_q = min(block_q, t) if block_q else (_auto_block(t, q_cap) or t + 1)
+    block_k = min(block_k, t) if block_k else (_auto_block(t, k_cap) or t + 1)
 
     def dense_fallback():
+        if group > 1 or window is not None:
+            return _dense_grouped(q, k, v, causal, window,
+                                  1.0 / math.sqrt(d) if sm_scale is None else sm_scale)
         # dense_attention hard-codes 1/sqrt(d); fold a custom sm_scale into q
         # so fallback results match the kernel on every platform
         qs = q if sm_scale is None else q * (sm_scale * math.sqrt(d))
@@ -416,11 +570,11 @@ def flash_attention(
         sm_scale = 1.0 / math.sqrt(d)
 
     def to_bhtd(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, d)
 
     o = _flash_bhtd(
         to_bhtd(q), to_bhtd(k), to_bhtd(v),
-        causal, float(sm_scale), block_q, block_k, bool(interpret),
+        causal, float(sm_scale), block_q, block_k, bool(interpret), window, group,
     )
     return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 

@@ -47,7 +47,10 @@ def make_lm_train_step(
 
     step_fn(params, opt_state, tokens, targets) -> (params, opt_state, loss),
     jitted with NamedShardings: tokens/targets P(('data','fsdp'), 'seq'),
-    params per katib_tpu.models.transformer.param_sharding_rules.
+    params per katib_tpu.models.transformer.param_sharding_rules. A model with
+    "routed" layers returns a fourth value, its routing counters summed over
+    those layers (models.transformer.collect_routing): device scalars that are
+    ready when the loss is.
     """
     import flax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -129,6 +132,8 @@ def make_lm_train_step(
         with jax.default_device(pin_device):
             opt_state = tx.init(params)
 
+    routed = any(config.layer(i).mlp == "routed" for i in range(config.num_layers))
+
     def step(params, opt_state, tokens, targets, positions):
         def loss_fn(p):
             if config.num_experts > 0:
@@ -137,14 +142,21 @@ def make_lm_train_step(
                 logits, mutated = model.apply(
                     {"params": p}, tokens, positions, mutable=["intermediates"]
                 )
-                return lm_loss(logits, targets) + collect_moe_aux(mutated)
-            logits = model.apply({"params": p}, tokens, positions)
-            return lm_loss(logits, targets)
+                return lm_loss(logits, targets) + collect_moe_aux(mutated), ()
+            if routed:  # nothing is added to the loss for these layers
+                from ..models.transformer import collect_routing
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+                logits, mutated = model.apply(
+                    {"params": p}, tokens, positions, mutable=["intermediates"]
+                )
+                return lm_loss(logits, targets), (collect_routing(mutated),)
+            logits = model.apply({"params": p}, tokens, positions)
+            return lm_loss(logits, targets), ()
+
+        (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        return (params, opt_state, loss) + counters
 
     jitted_step = jax.jit(step, donate_argnums=(0, 1))
 
@@ -187,32 +199,43 @@ def make_lm_train_step(
 def run_lm_trial(assignments: Dict[str, str], ctx=None) -> None:
     """HPO trial over the distributed LM: hyperparameters learning_rate,
     embed_dim, num_layers; reports per-epoch loss. Builds its mesh from the
-    trial's gang-allocated devices (dp [+ tp/sp via assignments])."""
+    trial's gang-allocated devices (dp [+ tp/sp via assignments]).
+
+    The model is the dense decoder of four sizes (vocab_size, embed_dim,
+    num_layers, num_heads), or — with an ``architecture`` assignment, the path
+    of a JSON file of published config keys — the decoder that file describes,
+    whole (models/architecture.py, docs/architecture-handoff.md); the four
+    sizes may then not be given too."""
     import numpy as np
 
     from .mesh import make_mesh
 
     lr = float(assignments.get("learning_rate", "1e-3"))
-    embed_dim = int(assignments.get("embed_dim", "128"))
-    num_layers = int(assignments.get("num_layers", "2"))
-    num_heads = int(assignments.get("num_heads", "4"))
     tp = int(assignments.get("tensor_parallel", "1"))
     sp = int(assignments.get("sequence_parallel", "1"))
     steps = int(assignments.get("num_steps", "20"))
     batch = int(assignments.get("batch_size", "8"))
     seq_len = int(assignments.get("seq_len", "128"))
-    vocab = int(assignments.get("vocab_size", "512"))
 
     devices = ctx.jax_devices() or None if ctx is not None else None
     mesh = make_mesh(devices, model=tp, seq=sp)
 
-    config = TransformerConfig(
-        vocab_size=vocab,
-        embed_dim=embed_dim,
-        num_layers=num_layers,
-        num_heads=num_heads,
-        max_seq_len=seq_len,
-    )
+    if "architecture" in assignments:
+        from ..models.architecture import architecture_config, load_architecture
+
+        sizes = sorted({"vocab_size", "embed_dim", "num_layers", "num_heads"} & set(assignments))
+        if sizes:
+            raise ValueError(f"an architecture is handed in whole: {sizes} may not be given beside it")
+        config = architecture_config(load_architecture(assignments["architecture"]), seq_len)
+    else:
+        config = TransformerConfig(
+            vocab_size=int(assignments.get("vocab_size", "512")),
+            embed_dim=int(assignments.get("embed_dim", "128")),
+            num_layers=int(assignments.get("num_layers", "2")),
+            num_heads=int(assignments.get("num_heads", "4")),
+            max_seq_len=seq_len,
+        )
+    vocab = config.vocab_size
     import contextlib
 
     # stages of the `compile` span (runtime/context.py); the step's own trace,
@@ -231,14 +254,21 @@ def run_lm_trial(assignments: Dict[str, str], ctx=None) -> None:
     # the synthetic batch is constant across steps: stage it once
     with stage("stage_batch"):
         tokens, targets, positions = put_batch(data[:, :-1], data[:, 1:])
+    def report(loss, counters):
+        value = float(loss)  # the one wait for the device
+        for routing in counters:  # of the step whose loss this is: ready with it
+            ctx.count(**{name: float(v) for name, v in routing.items()})
+        ctx.report(loss=value)
+
     with prof_cm:
         for i in range(steps):
-            params, opt_state, loss = step_fn(params, opt_state, tokens, targets, positions)
+            params, opt_state, loss, *counters = step_fn(
+                params, opt_state, tokens, targets, positions)
             if ctx is not None and (i + 1) % 5 == 0:
-                ctx.report(loss=float(loss))
+                report(loss, counters)
     if ctx is not None:
         if steps % 5 != 0:  # final value not yet reported by the loop
-            ctx.report(loss=float(loss))
+            report(loss, counters)
     else:
         print(f"loss={float(loss)}")
 

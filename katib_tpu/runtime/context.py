@@ -119,6 +119,16 @@ class TrialContext:
 
         return step
 
+    def count(self, **counters: float) -> None:
+        """Counters of the program's own (a routed layer's loads, say) for the
+        report interval that is open: the step ledger sums them into the
+        interval's row under their names (tracing.StepLedger). The values are
+        the caller's, fetched however it fetched its metrics; this call waits
+        for nothing. With no tracer bound it does nothing."""
+        ledger = getattr(self, "_ledger", None)
+        if ledger is not None:
+            ledger.count(counters)
+
     def _trace_fn_start(self) -> None:
         """Executor hook: the trial function is about to run. Everything up
         to the first report is attributed to `compile` (trace-and-compile of

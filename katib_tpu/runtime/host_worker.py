@@ -61,6 +61,10 @@ class WorkerContext:
         """TrialContext.watch_step where no tracer is bound: the identity."""
         return step_fn
 
+    def count(self, **counters: float) -> None:
+        """TrialContext.count where no tracer is bound: gang workers keep no
+        step ledger, so nothing."""
+
     def param(self, name: str, default: Optional[str] = None) -> Optional[str]:
         return self.assignments.get(name, default)
 

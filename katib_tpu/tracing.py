@@ -618,6 +618,10 @@ class StepLedger:
     ``compile`` span: the first report hands them over (``first_report``) and
     starts the first interval at its exit.
 
+    A trial may add counters of its own to the open interval (``count``: a
+    routed layer's loads, say): an interval's row then carries their sums
+    after the fixed fields, under their names, in the order they first came.
+
     One thread writes it; the newest ``RING`` intervals are kept, the totals
     keep counting.
     """
@@ -635,6 +639,13 @@ class StepLedger:
         self._dispatch = 0.0
         self._last_return: Optional[float] = None
         self._store = 0.0
+        self._counted: Dict[str, float] = {}  # the trial's own counters; the keys stay, in order
+
+    def count(self, counters: Dict[str, float]) -> None:
+        """Add to the open interval's counters."""
+        for name, value in counters.items():
+            self._counted[name] = self._counted.get(name, 0.0) + value
+            self.totals.setdefault(name, 0.0)
 
     def stepped(self, t_call: float, t_return: float) -> None:
         """One step call returned (``clock`` readings around it)."""
@@ -660,17 +671,18 @@ class StepLedger:
             row = (
                 self.wall(), now - self._t_prev, self._steps, self._dispatch,
                 self._wait(t_entry), now - t_entry, self._store,
-            )
+            ) + tuple(self._counted.values())
             self.intervals.append(row)
-            for key, value in zip(self.FIELDS[1:], row[1:]):
+            for key, value in zip(self.FIELDS[1:] + tuple(self._counted), row[1:]):
                 self.totals[key] += value
         self._t_prev = now
         self._steps, self._dispatch, self._last_return, self._store = 0, 0.0, None, 0.0
+        self._counted = dict.fromkeys(self._counted, 0.0)
 
     def attrs(self) -> Dict[str, Any]:
         """What the ``steps`` span carries when it ends."""
         return dict(
-            self.totals, interval_fields=list(self.FIELDS),
+            self.totals, interval_fields=list(self.FIELDS) + list(self._counted),
             intervals=[list(row) for row in self.intervals],
         )
 

@@ -52,7 +52,7 @@ def no_persistent_cache():
 
 # [BH, T, D] as the kernels see it. The first is the LM-large attention
 # shape (batch 4 x 16 heads, T 2048, head_dim 64); d=128 is the head width
-# of the configurations queued in ROADMAP R2-R4.
+# of the benchmark's configurations.
 SHAPES = [
     pytest.param((64, 2048, 64), jnp.bfloat16, id="lm_large-bh64-t2048-d64-bf16"),
     pytest.param((64, 1024, 64), jnp.bfloat16, id="bh64-t1024-d64-bf16"),
@@ -200,3 +200,53 @@ def test_qkv_weight_gradient_is_no_windowed_convolution_on_v5e(one_chip, monkeyp
     assert qkv_grad and mlp_grad, ranked[:10]
     # the MLP's weight gradients do 1.3 times the arithmetic of q/k/v's
     assert qkv_grad[0][0] < mlp_grad[0][0], (qkv_grad[0], mlp_grad[0])
+
+
+# The sparse cell's kernels at its own shapes (PR 30): T 8192, heads of 128,
+# 8 KV heads under 48 query heads (full layers) and 64 (sliding layers, window
+# 512); one routed layer's grouped products, 32 experts of 2048 x 512.
+GROUPED = [
+    pytest.param(48, None, id="full-48over8-t8192"),
+    pytest.param(64, 512, id="window512-64over8-t8192"),
+]
+
+
+@pytest.mark.parametrize("heads,window", GROUPED)
+def test_grouped_and_windowed_flash_kernels_compile_for_v5e(one_chip, monkeypatch, heads, window):
+    from katib_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window).astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") == 3
+    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if window is None else (
+        "flash_window_fwd", "flash_window_bwd_dq", "flash_window_bwd_dkv")
+    for name in names:
+        assert name in text
+
+
+def test_grouped_expert_products_compile_for_v5e(one_chip, monkeypatch):
+    from katib_tpu.ops import flash_attention as fa
+    from katib_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    rows_n = (8192 * 8 // gm.TILE + 32 + gm.CHUNK_TILES - 1) * gm.TILE
+    rows = jax.ShapeDtypeStruct((rows_n, 2048), jnp.bfloat16, sharding=one_chip)
+    w_in = jax.ShapeDtypeStruct((32, 2048, 512), jnp.float32, sharding=one_chip)
+    w_out = jax.ShapeDtypeStruct((32, 512, 2048), jnp.float32, sharding=one_chip)
+    tile_group = jax.ShapeDtypeStruct((rows_n // gm.TILE,), jnp.int32, sharding=one_chip)
+    num_tiles = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def loss(rows, w_in, w_out, tile_group, num_tiles):
+        hidden = gm.grouped_matmul(rows, w_in, tile_group, num_tiles)
+        return gm.grouped_matmul(hidden, w_out, tile_group, num_tiles).astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), rows, w_in, w_out, tile_group, num_tiles)
+    assert text.count("tpu_custom_call") == 6
+    for name in ("expert_gmm_fwd", "expert_gmm_dlhs", "expert_gmm_dw"):
+        assert name in text
