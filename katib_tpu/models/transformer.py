@@ -412,16 +412,25 @@ def gather_rows(x, index, valid, readers, readers_valid):
     [rows of x, fan] names, for each row of ``x``, the rows of ``y`` that read
     it (``readers_valid`` says which entries count), so the gradient is a
     gather too: a scatter-add over tens of thousands of rows costs many times
-    the gather on a TPU."""
-    return jnp.where(valid[:, None], jnp.take(x, index, axis=0, mode="clip"), 0).astype(x.dtype)
+    the gather on a TPU.
+
+    Where each mask sits. Forward: on the index — a row that is not valid
+    reads one zero row appended to ``x``, so its zeros are exact and no select
+    passes over the ``[rows of y, E]`` result. Backward: on the gathered
+    ``[rows of x, fan, E]`` values, as a select — the rows of ``dy`` may be
+    ones nothing ever wrote (the dispatch's ``dy`` comes out of the grouped
+    kernels), and a product with 0 would let a NaN there through."""
+    zero_row = jnp.zeros((1,) + x.shape[1:], x.dtype)
+    index = jnp.where(valid, index, x.shape[0])
+    return jnp.take(jnp.concatenate([x, zero_row]), index, axis=0, mode="clip")
 
 
 def _gather_rows_fwd(x, index, valid, readers, readers_valid):
-    return gather_rows(x, index, valid, readers, readers_valid), (index, valid, readers, readers_valid)
+    return gather_rows(x, index, valid, readers, readers_valid), (readers, readers_valid)
 
 
 def _gather_rows_bwd(res, dy):
-    index, valid, readers, readers_valid = res
+    readers, readers_valid = res
     read = jnp.take(dy, readers, axis=0, mode="clip")               # [rows of x, fan, E]
     dx = jnp.sum(
         jnp.where(readers_valid[..., None], read, 0).astype(jnp.float32), axis=1)
@@ -429,6 +438,52 @@ def _gather_rows_bwd(res, dy):
 
 
 gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(out, weights, dest, landed, source, filled):
+    """``y[t] = sum_j weights[t, j] * out[dest[t, j]]`` over the assignments
+    that ``landed``: what a routed layer hands back, float32 sums returned in
+    ``out``'s dtype. ``out`` [M, E] is the buffer ``dispatch_plan`` lays out,
+    ``weights`` [N, k] float32; ``dest``, ``landed`` [N, k] and ``source`` [M]
+    are the plan's, ``filled`` [M] says which rows hold an assignment.
+
+    Which rows may be unwritten: those of tiles at or past ``num_tiles`` hold
+    whatever the buffer held (ops/grouped_matmul.py), so a row reaches a
+    result only through a select on scalars, never through a product with 0.
+    Forward: the mask is on the ``[N, k]`` weights; an assignment that did not
+    land has ``dest`` 0, and row 0 is finite (tile 0 is always in use, its
+    padding rows are zero). No select passes over ``[N k, E]``.
+
+    The gradient is its own, buffer-major, so that neither pass keeps or makes
+    again the ``[N k, E]`` gather: one row gather of ``dy`` over the buffer's
+    M rows, ``d_out`` its rows times a weight a row (0 where not ``filled``,
+    chosen on the M scalars), ``d_weights`` a row-wise dot over the buffer
+    gathered as N k scalars (0 where not ``landed``)."""
+    n, k = weights.shape
+    back = jnp.take(out, dest.reshape(-1), axis=0, mode="clip").reshape(n, k, -1)
+    w = jnp.where(landed, weights, 0.0)
+    return jnp.sum(w[..., None] * back.astype(jnp.float32), axis=1).astype(out.dtype)
+
+
+def _combine_rows_fwd(out, weights, dest, landed, source, filled):
+    return combine_rows(out, weights, dest, landed, source, filled), (
+        out, weights, dest, landed, source, filled)
+
+
+def _combine_rows_bwd(res, dy):
+    out, weights, dest, landed, source, filled = res
+    k = weights.shape[1]
+    # an unfilled row's source is N k: it reads the last token's dy, a finite row
+    g = jnp.take(dy, source // k, axis=0, mode="clip").astype(jnp.float32)        # [M, E]
+    w_row = jnp.where(filled, jnp.take(weights.reshape(-1), source, mode="clip"), 0.0)
+    d_out = (w_row[:, None] * g).astype(out.dtype)
+    dots = jnp.sum(g * out.astype(jnp.float32), axis=1)                            # [M]; anything where unwritten
+    d_weights = jnp.where(landed, jnp.take(dots, dest, mode="clip"), 0.0)
+    return d_out, d_weights.astype(weights.dtype), None, None, None, None
+
+
+combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 class RoutedExperts(nn.Module):
@@ -441,6 +496,18 @@ class RoutedExperts(nn.Module):
     here is computed, whatever the load (``dispatch_plan``); assignments to
     experts held elsewhere add nothing here. On a mesh with an 'expert' axis
     this is the same layer told another share; no exchange is part of it.
+
+    Around the grouped products the rows move once each way and every mask is
+    on scalars. The dispatch (``gather_rows``) fills the tile-aligned buffer:
+    a row that pads an expert out, or lies on a tile past ``num_tiles``, reads
+    one zero row appended to the tokens, so the rows the kernels read are
+    zero where the layout says so, to the bit. The kernels never write the
+    rows of tiles at or past ``num_tiles``: in ``hidden``, ``out`` and the
+    gradient that comes back to the dispatch those rows hold anything. The
+    combine (``combine_rows``) masks on the ``[N, k]`` weights and lets an
+    assignment that did not land read row 0, a finite row; its gradient masks
+    on a weight a row and on the gathered dots. Only the dispatch's gradient
+    selects over gathered rows, ``[N, k, E]``: it reads what the kernels left.
 
     Sows ``routing`` under 'intermediates': the assignments that landed here,
     the largest and the mean load of a held expert (and ``chosen``, the experts
@@ -485,9 +552,7 @@ class RoutedExperts(nn.Module):
                 grouped_matmul, tile_group=plan["tile_group"], num_tiles=plan["num_tiles"])
             hidden = nn.silu(products(rows, w_gate)) * products(rows, w_up)
             out = products(hidden, w_down)
-            back = gather_rows(out, dest.reshape(-1), landed.reshape(-1), source[:, None],
-                               filled[:, None]).reshape(n, k, e)
-            return jnp.sum(weights[..., None] * back.astype(jnp.float32), axis=1).astype(cfg.dtype)
+            return combine_rows(out, weights, dest, landed, source, filled)
 
         y = experts(tokens, weights, w_gate, w_up, w_down).reshape(b, t, e)
         if routed.shared_hidden:

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from katib_tpu.models.transformer import (
-    RoutedExperts, RoutedExpertsConfig, TransformerConfig, dispatch_plan, gather_rows, route)
+    RoutedExperts, RoutedExpertsConfig, TransformerConfig, combine_rows, dispatch_plan, gather_rows,
+    route)
 from katib_tpu.ops import grouped_matmul as gm
 
 E, F, WIDTH, PER_TOKEN = 32, 16, 16, 3
@@ -157,6 +158,118 @@ def test_gather_rows_has_a_gather_for_a_gradient():
         rtol=1e-6)
     assert "scatter" not in str(jax.make_jaxpr(jax.grad(
         lambda x: jnp.sum(gather_rows(x, index, valid, readers, readers_valid))))(x))
+
+
+# held experts, the expert given no token: one starved beside one of many tiles; expert 0 starved, so
+# that row 0 pads its one tile; a share of the experts, so that most assignments land elsewhere
+PLANS = {"expert_2_starved": (WIDTH, 2), "row_0_is_padding": (WIDTH, 0), "a_share_of_the_experts": (4, 2)}
+PLAN_TILE = 8
+
+
+def _skewed_plan(layer, case):
+    """The plan of the fixture's tokens under the skewed router for a case of
+    PLANS, and an ``out`` buffer that is zero where no assignment sits, as the
+    kernels leave the padding rows of tiles in use."""
+    config, x, params = layer
+    held, starved = PLANS[case]
+    kernel = _skewed(params, x)["router"]["kernel"]
+    kernel = kernel.at[:, starved].set(0.0).at[-1, starved].set(-1e3)
+    tokens = x.reshape(-1, E)
+    scores = jax.nn.sigmoid(jnp.dot(tokens, kernel, precision="highest"))
+    weights, local, _ = route(scores, _config(held=held).routed)
+    plan = dispatch_plan(local, held, PLAN_TILE, spare=2)
+    filled = plan["source"] < local.size
+    load = np.asarray(plan["load"])
+    assert load[starved] == 0 and load[1] > 4 * PLAN_TILE       # an empty expert, one of many tiles
+    assert bool(filled[0]) == (starved != 0)
+    assert bool(plan["landed"].all()) == (held == WIDTH)
+    out = jnp.where(filled[:, None], jax.random.normal(jax.random.PRNGKey(5), (filled.shape[0], E)), 0.0)
+    return tokens, weights, plan, filled, out
+
+
+def _combine_written_out(out, weights, dest, landed):
+    back = jnp.where(landed[..., None], jnp.take(out, dest, axis=0), 0.0)
+    return jnp.sum(weights[..., None] * back, axis=1)
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+def test_combine_rows_is_take_and_a_masked_weighted_sum_whatever_unused_tiles_hold(layer, case):
+    """Value and both gradients against autodiff of the written-out form; then
+    the same with NaN in every row the kernels never write."""
+    _, weights, plan, filled, out = _skewed_plan(layer, case)
+    dest, landed, source = plan["dest"], plan["landed"], plan["source"]
+    cot = jax.random.normal(jax.random.PRNGKey(6), (weights.shape[0], E))
+
+    def program(out, weights):
+        return combine_rows(out, weights, dest, landed, source, filled)
+
+    want, pullback_want = jax.vjp(lambda o, w: _combine_written_out(o, w, dest, landed), out, weights)
+    got, pullback = jax.vjp(program, out, weights)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    (d_out, d_w), (d_out_want, d_w_want) = pullback(cot), pullback_want(cot)
+    np.testing.assert_allclose(d_out, d_out_want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(d_w, d_w_want, rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(d_out[~filled]).max()) == 0.0
+    assert landed.all() or float(jnp.abs(d_w[~landed]).max()) == 0.0
+
+    unwritten = (jnp.arange(out.shape[0]) // PLAN_TILE >= plan["num_tiles"])[:, None]
+    assert int(unwritten.sum()) >= 2 * PLAN_TILE                # the spare tiles at least
+    poisoned = jnp.where(unwritten, jnp.nan, out)
+    got_p, pullback_p = jax.vjp(program, poisoned, weights)
+    d_out_p, d_w_p = pullback_p(cot)
+    for a, b in ((got_p, got), (d_out_p, d_out), (d_w_p, d_w)):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+def test_the_dispatch_s_unfilled_rows_are_exactly_zero_and_no_select_passes_over_its_rows(layer, case):
+    tokens, _, plan, filled, _ = _skewed_plan(layer, case)
+    k = plan["dest"].shape[1]
+    tokens = tokens.at[-1].set(jnp.inf)                         # what an unfilled row's clipped index would read
+
+    def dispatch(tokens):
+        return gather_rows(tokens, plan["source"] // k, filled, plan["dest"], plan["landed"])
+
+    rows = dispatch(tokens)
+    assert rows.shape == (filled.shape[0], E) and rows.dtype == tokens.dtype
+    assert int((~filled).sum()) > 0
+    np.testing.assert_array_equal(rows[~filled], 0.0)
+    assert not bool(jnp.signbit(rows[~filled]).any())           # +0, to the bit
+    np.testing.assert_array_equal(rows[filled], tokens[(plan["source"] // k)[filled]])
+    wide_selects = [eqn for eqn in jax.make_jaxpr(dispatch)(tokens).eqns
+                    if eqn.primitive.name == "select_n" and eqn.outvars[0].aval.ndim == 2]
+    assert wide_selects == []
+
+
+def _gathers(jaxpr, found):
+    """Result shapes of every gather in ``jaxpr`` and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            found.append(eqn.outvars[0].aval.shape)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)          # a ClosedJaxpr's jaxpr
+                if hasattr(sub, "eqns"):
+                    _gathers(sub, found)
+    return found
+
+
+def test_the_rematerialised_layer_gathers_the_assignments_rows_twice_not_three_times(layer):
+    """Under ``jax.checkpoint`` the combine's own gradient leaves the second
+    forward no use for the ``[N k, E]`` gather: the gradient's program holds
+    the combine's gather (forward), the dispatch's gradient, and nothing else
+    of N k rows; over the buffer's M rows the dispatch twice and ``dy`` once."""
+    config, x, params = layer
+    n = x.shape[0] * x.shape[1]
+    m = (-(-n * PER_TOKEN // gm.TILE) + WIDTH + gm.CHUNK_TILES - 1) * gm.TILE
+
+    def loss(params, x):
+        return jnp.sum(RoutedExperts(config).apply({"params": params}, x) ** 2)
+
+    shapes = _gathers(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr, [])
+    assert sorted(s for s in shapes if len(s) > 1 and s[-1] == E) == sorted(
+        [(n * PER_TOKEN, E), (n, PER_TOKEN, E)] + 3 * [(m, E)])
 
 
 # -- the kernels, in interpret mode -------------------------------------------------------------

@@ -250,3 +250,51 @@ def test_grouped_expert_products_compile_for_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") == 6
     for name in ("expert_gmm_fwd", "expert_gmm_dlhs", "expert_gmm_dw"):
         assert name in text
+
+
+def _instructions(compiled_text, opcode):
+    """Result types (``bf16[65536,2048]``) of every ``opcode`` instruction of a
+    compiled module, those inside fusions among them."""
+    return re.findall(rf"= (\w+\[[\d,]*\])\S* {opcode}\(", compiled_text)
+
+
+def test_routed_layer_moves_its_rows_once_each_way_on_v5e(one_chip, monkeypatch):
+    """One routed layer's value and gradients at the sparse cell's shapes
+    ([1, 8192, 2048], 32 of 256 experts, 8 a token, width 512; no shared
+    expert). With the combine written as a gather and a weighted sum,
+    autodiff kept the ``[65536, 2048]`` gather for the weights' gradient, so
+    the rematerialised forward made it again (3 gathers of N k rows a layer,
+    ~2.4 ms each on the chip), and every gather was followed by a select over
+    its whole result (PERF.md, PR 31). ``combine_rows``' own gradient works
+    over the buffer's rows, and the masks sit on scalars."""
+    from katib_tpu.models.transformer import RoutedExperts, RoutedExpertsConfig, TransformerConfig
+    from katib_tpu.ops import flash_attention as fa
+    from katib_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    n, k, e, held = 8192, 8, 2048, 32
+    m = (n * k // gm.TILE + held + gm.CHUNK_TILES - 1) * gm.TILE       # 69,888 rows
+    cfg = TransformerConfig(embed_dim=e, routed=RoutedExpertsConfig(
+        router_width=256, experts_per_token=k, hidden=512, num_experts=held, routed_scale=2.5))
+    layer = RoutedExperts(cfg)
+    params = jax.eval_shape(
+        lambda key: layer.init(key, jnp.zeros((1, 256, e), cfg.dtype))["params"], jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, n, e), cfg.dtype, sharding=one_chip)
+
+    def loss(params, x):
+        return layer.apply({"params": params}, x).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    per_assignment = (f"bf16[{n * k},{e}]", f"bf16[{n},{k},{e}]")
+    gathers, selects = _instructions(text, "gather"), _instructions(text, "select")
+    # the combine's forward and the dispatch's gradient; not the combine again
+    assert sorted(g for g in gathers if g in per_assignment) == sorted(per_assignment)
+    # the dispatch, the dispatch again (rematerialised), dy for the combine's gradient
+    assert gathers.count(f"bf16[{m},{e}]") == 3
+    # only the dispatch's gradient selects over rows: it reads what the kernels left unwritten
+    rows = (f"[{n * k},{e}]", f"[{n},{k},{e}]", f"[{m},{e}]")
+    assert [s for s in selects if s[s.index("["):] in rows] == [f"bf16[{n},{k},{e}]"]
+    assert text.count("tpu_custom_call") == 12
+    for name in ("expert_gmm_fwd", "expert_gmm_dlhs", "expert_gmm_dw"):
+        assert name in text
