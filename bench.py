@@ -1,38 +1,22 @@
-"""Benchmark harness — robust, bounded, and measured.
+"""CPU rehearsals of the control plane: 18 standalone scenarios.
 
-The reference publishes no performance numbers (BASELINE.md); its only
-quantitative envelope is the CI bound for the DARTS e2e experiment — the
-darts-cpu example (num_epochs=1, num_nodes=1, init_channels=1, batch 128,
-full CIFAR-10) must finish inside the 40-minute workflow timeout
-(reference test/e2e/v1beta1/scripts/gh-actions/run-e2e-experiment.py:10-11,
-examples/v1beta1/nas/darts-cpu.yaml).
+Each scenario drives one plane of the package (observation store, tracing,
+step stats, telemetry, the analyzers, the compile service, fused
+populations, the suggesters, multi-fidelity, recovery, the replica control
+plane, tenancy, framed ingest) through its public path on the CPU, most of
+them once with the plane on and once with its legacy twin, and checks that
+both sides agree. What a scenario prints are counts and identities (rows,
+trials, device-epochs, "bit_identical"); a rate or a ratio among them was
+timed on this sandbox's shared CPU and is never a speed (ROADMAP aim 1).
+What the model programs cost on the chip is measured by ``benchmarks/``
+alone (``BENCHMARK.json``, ``PERF.md``).
 
-Structure (round-1 failed with an unbounded in-process TPU init that died on
-a wedged backend; round-3's driver capture was rc=124 because the children's
-summed worst-case budgets exceeded the driver's own timeout): the parent
-process never touches JAX and enforces ONE total deadline
-(``BENCH_TOTAL_BUDGET``, default 1140 s) from which every child timeout is
-derived. A cheap bounded probe subprocess measures the accelerator's
-round-trip latency FIRST — a wedged backend (roundtrip ≫ 10 ms, or a probe
-that hangs) skips the TPU child entirely so the CPU fallback inherits the
-whole envelope. Children self-trim optional stages against
-``BENCH_CHILD_DEADLINE`` and checkpoint every finished stage to
-``BENCH_RESULT_FILE`` so a mid-run kill still yields the stages that
-completed. The sentinel JSON line is therefore printed with time to spare in
-every failure mode. The child measures:
+    python bench.py <scenario> [--smoke] [--distributed]
 
-- DARTS bilevel search-step latency (darts-cpu e2e config) and the
-  steady-state 1-epoch wall-clock vs the reference's 40-min CI envelope
-  (one-time compile amortizes via the persistent cache and is quoted
-  separately in extras with the first-trial projection);
-- transformer LM train-step tokens/s on the flash-attention path;
-- MFU = model FLOPs / step-time / chip peak (TPU only, peak by device_kind);
-- flash-attention vs dense XLA attention step-time ratio (TPU only).
-
-Output: ONE JSON line {"metric", "value", "unit", "vs_baseline", "extras"}
-where vs_baseline = baseline_seconds / steady_state_epoch_seconds (>1 =
-faster than the reference CI envelope; the one-time compile and the
-first-trial projection are quoted in extras).
+prints ONE JSON line ``{"metric": <scenario>, ...}``. ``--smoke`` trims the
+sizes to the tier-1 wiring run (``tests/test_bench_budget.py``,
+``scripts/check.sh``); ``--distributed`` is ``tracing_overhead``'s. With no
+or an unknown name the exit code is 2 and the names are listed.
 """
 
 import json
@@ -40,480 +24,6 @@ import os
 import subprocess
 import sys
 import time
-
-BASELINE_SECONDS = 2400.0  # reference e2e CI bound (40 min)
-STEPS_PER_EPOCH = 390      # 25_000 train images (half of CIFAR-10) / batch 128
-
-# bf16 peak FLOP/s by TPU generation (public spec sheets); order matters —
-# match the more specific kind strings first.
-TPU_PEAK_FLOPS = (
-    ("v6", 918e12),
-    ("trillium", 918e12),
-    ("v5 lite", 197e12),
-    ("v5e", 197e12),
-    ("v5p", 459e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 46e12),
-)
-
-
-def _peak_flops(device_kind: str):
-    kind = device_kind.lower()
-    for key, peak in TPU_PEAK_FLOPS:
-        if key in kind:
-            return peak
-    return None
-
-
-# ---------------------------------------------------------------------------
-# Child: actual measurements (runs entirely inside one bounded subprocess)
-# ---------------------------------------------------------------------------
-
-def _child_remaining() -> float:
-    """Seconds left in this child's envelope (inf when unbounded)."""
-    deadline = os.environ.get("BENCH_CHILD_DEADLINE")
-    return float(deadline) - time.time() if deadline else float("inf")
-
-
-def _checkpoint_stage(payload: dict) -> None:
-    """Persist the stages finished so far; the parent salvages this file if
-    the child is killed mid-run, so a deadline never zeroes the evidence."""
-    path = os.environ.get("BENCH_RESULT_FILE")
-    if not path:
-        return
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(payload, f)
-    os.replace(tmp, path)
-
-
-def _force_cpu() -> None:
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
-
-def _sync(x) -> float:
-    """See katib_tpu.utils.timing: a 1-element host read is a
-    synchronisation that holds on every backend."""
-    from katib_tpu.utils.timing import host_sync
-
-    return host_sync(x)
-
-
-def _roundtrip_ms(jax) -> float:
-    """Per-call host-read round-trip latency (subtracted from loop timings)."""
-    from katib_tpu.utils.timing import roundtrip_ms
-
-    return roundtrip_ms()
-
-
-def _bench_darts(jax, np, on_tpu: bool):
-    """darts-cpu e2e configuration: step latency + projected 1-epoch clock."""
-    from katib_tpu.models.darts_trainer import DartsSearch
-
-    primitives = [
-        "max_pooling_3x3",
-        "skip_connection",
-        "separable_convolution_3x3",
-    ]
-    settings = {
-        "num_epochs": 1,
-        "num_nodes": 1,
-        "init_channels": 1,
-        "batch_size": 128,
-        "stem_multiplier": 3,
-    }
-    search = DartsSearch(primitives=primitives, num_layers=3, settings=settings)
-
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((256, 32, 32, 3)).astype("float32")
-    y = rng.integers(0, 10, 256).astype("int32")
-
-    rt_ms = _roundtrip_ms(jax)
-    t0 = time.time()
-    search.build((32, 32, 3), STEPS_PER_EPOCH)
-    import jax.numpy as jnp
-
-    # stage the fixed batch on device once: the metric is step latency, not
-    # host->device transfer of a batch the loop reuses (a real input
-    # pipeline prefetches/overlaps; the e2e stage below measures that path)
-    bx, by = jnp.asarray(x[:128]), jnp.asarray(y[:128])
-    vx, vy = jnp.asarray(x[128:]), jnp.asarray(y[128:])
-    state = search._search_step(
-        search.weights, search.alphas, search.w_opt_state, search.a_opt_state,
-        search.step_idx, search.hyper, (bx, by), (vx, vy),
-    )
-    _sync(state[-1])
-    compile_s = time.time() - t0
-    search.weights, search.alphas, search.w_opt_state, search.a_opt_state = state[:4]
-
-    n_steps = int(os.environ.get("BENCH_STEPS", "30"))
-    step_s = None
-    for _pass in range(2):  # min of 2 passes: the TPU pool is shared/noisy
-        t0 = time.time()
-        for _ in range(n_steps):
-            state = search._search_step(
-                search.weights, search.alphas, search.w_opt_state, search.a_opt_state,
-                search.step_idx, search.hyper, (bx, by), (vx, vy),
-            )
-            search.weights, search.alphas, search.w_opt_state, search.a_opt_state = state[:4]
-        _sync(state[-1])  # host read: the loss chains through every step's params
-        cur = max((time.time() - t0 - rt_ms / 1e3) / n_steps, 1e-9)
-        step_s = cur if step_s is None else min(step_s, cur)
-    projected = compile_s + step_s * STEPS_PER_EPOCH
-    return {"compile_s": compile_s, "step_ms": step_s * 1e3, "projected_s": projected}
-
-
-def _bench_lm(jax, np, on_tpu: bool, size: str = "small"):
-    """Transformer LM train step (flash-attention path): tokens/s + MFU.
-
-    Two TPU configs so the MFU claim isn't a single-toy-shape artifact
-    (round-2 verdict): "small" ~21M params at T=1024, "large" ~134M params
-    at T=2048."""
-    from katib_tpu.models.transformer import TransformerConfig, bench_lm_config
-    from katib_tpu.parallel.mesh import make_mesh
-    from katib_tpu.parallel.train import make_lm_train_step
-
-    cfg, batch, seq, _ = bench_lm_config(size, on_tpu)
-    config = TransformerConfig(**cfg)
-    mesh = make_mesh(jax.devices()[:1])  # single-chip: data=1 mesh, flash path
-    params, opt_state, step_fn, put_batch = make_lm_train_step(config, mesh, 1e-3)
-
-    rng = np.random.default_rng(0)
-    data = rng.integers(0, config.vocab_size, size=(batch, seq + 1), dtype=np.int32)
-    tokens, targets, positions = put_batch(data[:, :-1], data[:, 1:])
-
-    rt_ms = _roundtrip_ms(jax)
-    t0 = time.time()
-    params, opt_state, loss = step_fn(params, opt_state, tokens, targets, positions)
-    _sync(loss)
-    compile_s = time.time() - t0
-
-    n_steps = int(os.environ.get("BENCH_STEPS", "30"))
-    t0 = time.time()
-    for _ in range(n_steps):
-        params, opt_state, loss = step_fn(params, opt_state, tokens, targets, positions)
-    _sync(loss)  # chained through params; host read forces the whole loop
-    step_s = max((time.time() - t0 - rt_ms / 1e3) / n_steps, 1e-9)
-
-    n_tokens = batch * seq
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-    # standard MFU accounting (PaLM appendix B): 6*N per token for parameter
-    # matmuls (fwd+bwd) + 12*L*T*E per token for attention score/value matmuls
-    flops_per_step = 6 * n_params * n_tokens + 12 * config.num_layers * batch * seq * seq * config.embed_dim
-    device_kind = getattr(jax.devices()[0], "device_kind", "cpu")
-    peak = _peak_flops(device_kind) if on_tpu else None
-    mfu = flops_per_step / step_s / peak if peak else None
-    return {
-        "compile_s": compile_s,
-        "step_ms": step_s * 1e3,
-        "tokens_per_s": n_tokens / step_s,
-        "mfu": round(mfu, 4) if mfu is not None else None,
-        "device_kind": device_kind,
-        "n_params": int(n_params),
-        "batch": batch,
-        "seq_len": seq,
-    }
-
-
-# Uncontended darts-stage step latency on the two backends this box runs
-# (calibrated in-repo; env-overridable). The e2e stage divides the measured
-# step time by this pin to estimate how contended the box is RIGHT NOW and
-# inflates its trial-cost estimates accordingly — round-4 lesson: a fixed
-# estimate calibrated on a quiet box fit 0 trials when three suites shared
-# the machine and every step ran ~2.5x slower.
-NOMINAL_DARTS_STEP_MS = {"cpu": 1100.0, "tpu": 25.0}
-
-
-def _e2e_plan(on_tpu: bool, run_timeout: float, darts, n_trials: int):
-    """Pick (scale, n_trials, contention) for the e2e stage, or None if even
-    the cheapest rung cannot fit one trial. Pure so the budget tests can pin
-    the ladder/contention arithmetic without running trials."""
-    backend = "tpu" if on_tpu else "cpu"
-    # per-backend override first: one bench run can execute BOTH children
-    # (TPU then CPU fallback) under the same environment, so a shared pin
-    # calibrated for one backend would corrupt the other's estimate
-    try:
-        nominal = float(
-            os.environ.get(f"BENCH_NOMINAL_DARTS_STEP_MS_{backend.upper()}")
-            or os.environ.get("BENCH_NOMINAL_DARTS_STEP_MS")
-            or NOMINAL_DARTS_STEP_MS[backend]
-        )
-    except ValueError:
-        nominal = 0.0
-    if nominal <= 0:  # zero/garbage override must not kill the e2e stage
-        nominal = NOMINAL_DARTS_STEP_MS[backend]
-    contention = 1.0
-    if darts and darts.get("step_ms"):
-        contention = max(1.0, float(darts["step_ms"]) / nominal)
-    # The warm-cache rung: the exact darts-cpu headline config _bench_darts
-    # already compiled in this process (same primitives order, shapes, and
-    # schedule_horizon=390 → _compiled_search_step lru hit), so its first
-    # trial pays only the forward-only eval compile plus a handful of
-    # steps. It also matches the reference CI's own e2e scale
-    # (darts-cpu.yaml: 1 epoch, 1 node, 1 channel, batch 128).
-    warm_rung = dict(num_epochs=2, num_train_examples=1024, batch_size=128,
-                     init_channels=1, num_nodes=1, stem_multiplier=3,
-                     num_layers=3,
-                     primitives=["max_pooling_3x3", "skip_connection",
-                                 "separable_convolution_3x3"],
-                     schedule_horizon=STEPS_PER_EPOCH)
-    if on_tpu:
-        # 192 search steps/trial (6 epochs x 4096 examples) — the budget at
-        # which good optimizer settings learn the round-5 calibrated
-        # discriminative stand-in while bad ones stay near chance, matching
-        # scripts/run_north_star.py's TPU scale so the e2e distribution
-        # spreads instead of collapsing at either end; a squeezed budget
-        # degrades to the warm rung instead of skipping
-        ladder = [
-            (dict(num_epochs=6, num_train_examples=4096, batch_size=64,
-                  init_channels=8, num_nodes=2, stem_multiplier=3,
-                  num_layers=3),
-             150.0, 22.0),
-            (warm_rung, 45.0, 8.0),
-        ]
-    else:
-        # Rung 1 exercises the full bilevel pipeline; on the calibrated
-        # task this capacity/step budget lands low on the accuracy range
-        # (the spread evidence lives in the TPU rung — CPU is
-        # capacity-starved by design). It pays a fresh multi-minute cold
-        # bilevel compile — XLA:CPU gets no persistent cache
-        # (utils/compilation.py SIGILL note), so its first trial is honest
-        # at ~650s uncontended.
-        ladder = [
-            (dict(num_epochs=3, num_train_examples=2048, batch_size=64,
-                  init_channels=4, num_nodes=2, stem_multiplier=1,
-                  num_layers=3),
-             650.0, 350.0),
-            (warm_rung, 150.0, 40.0),
-        ]
-    # Prefer a rung that yields a DISTRIBUTION (≥3 trials) over a bigger
-    # model with a single accuracy point — the e2e stage's evidence value is
-    # the spread; fall back to the best single-trial rung only when no rung
-    # fits three.
-    want = min(3, n_trials)
-    for min_fit in (want, 1):
-        for cand_scale, base_first, base_trial in ladder:
-            est_first = base_first * contention
-            if run_timeout >= est_first:
-                fit = 1 + int(
-                    (run_timeout - est_first) / (base_trial * contention)
-                )
-                if fit >= min_fit:
-                    return cand_scale, max(1, min(n_trials, fit)), contention
-    return None
-
-
-def _bench_e2e_experiment(jax, np, on_tpu: bool, darts=None):
-    """The north-star experiment THROUGH the framework: a multi-trial DARTS
-    HPO experiment (TPE over the bilevel search's optimizer hyperparameters)
-    driven by ExperimentController.run() — suggestion protocol, collectors,
-    scheduler — verified against the reference's e2e invariants, wall-clock
-    and the per-trial accuracy distribution recorded. Because DartsSearch
-    traces its hyperparameters, all trials share ONE compiled search step
-    (first trial compiles; the rest are persistent-cache hits). Bounded by
-    the parent's child deadline (BENCH_CHILD_DEADLINE): the trial count is
-    trimmed to fit, and a run that still overruns degrades to a 'partial'
-    entry carrying the completed trials' accuracies."""
-    import shutil
-    import tempfile
-
-    from katib_tpu.api import (
-        AlgorithmSpec, Distribution, ExperimentSpec, FeasibleSpace,
-        ObjectiveSpec, ObjectiveType, ParameterSpec, ParameterType,
-        TrialTemplate,
-    )
-    from katib_tpu.controller.experiment import ExperimentController
-    from katib_tpu.utils.e2e_verify import verify_experiment_results
-
-    run_timeout = 2400.0
-    deadline = os.environ.get("BENCH_CHILD_DEADLINE")
-    if deadline:
-        run_timeout = _child_remaining() - 30.0  # kill margin
-        if run_timeout < 60.0:
-            return {"skipped": f"only {run_timeout:.0f}s left in child budget"}
-
-    n_requested = int(os.environ.get("BENCH_E2E_TRIALS", "10" if on_tpu else "3"))
-    # Trial-cost estimates are scaled by the contention the darts stage just
-    # measured in THIS child (measured step ms / uncontended pin) — a fixed
-    # estimate fit 0 trials when the box ran ~2.5x slow under three
-    # concurrent suites. The ladder degrades to the north-star scale (~3x
-    # chance val-acc, warm-cache trials) before giving up entirely.
-    plan = _e2e_plan(on_tpu, run_timeout, darts, n_requested)
-    if plan is None:
-        return {"skipped": (
-            f"{run_timeout:.0f}s left cannot fit a first trial at any scale")}
-    scale, n_trials, contention = plan
-
-    def darts_hpo_trial(assignments, ctx):
-        from katib_tpu.models.darts_trainer import run_darts_hpo_trial
-
-        run_darts_hpo_trial(assignments, ctx, **scale)
-
-    root = tempfile.mkdtemp(prefix="bench-e2e-")
-    ctrl = ExperimentController(root_dir=root)
-    try:
-        spec = ExperimentSpec(
-            name="bench-darts-hpo-e2e",
-            objective=ObjectiveSpec(
-                type=ObjectiveType.MAXIMIZE,
-                objective_metric_name="Validation-accuracy",
-                additional_metric_names=["Train-loss"],
-            ),
-            algorithm=AlgorithmSpec("tpe"),
-            parameters=[
-                ParameterSpec(
-                    "w_lr", ParameterType.DOUBLE,
-                    FeasibleSpace(min="0.005", max="0.2",
-                                  distribution=Distribution.LOG_UNIFORM),
-                ),
-                ParameterSpec(
-                    "alpha_lr", ParameterType.DOUBLE,
-                    FeasibleSpace(min="0.0001", max="0.01",
-                                  distribution=Distribution.LOG_UNIFORM),
-                ),
-                ParameterSpec(
-                    "w_momentum", ParameterType.DOUBLE,
-                    FeasibleSpace(min="0.5", max="0.99"),
-                ),
-            ],
-            trial_template=TrialTemplate(function=darts_hpo_trial),
-            max_trial_count=n_trials,
-            parallel_trial_count=1,
-        )
-        ctrl.create_experiment(spec)
-        t0 = time.time()
-        exp = timed_out = None
-        try:
-            exp = ctrl.run("bench-darts-hpo-e2e", timeout=run_timeout)
-        except TimeoutError as e:
-            # keep the distribution of the trials that DID finish — the
-            # evidence must degrade to partial, never to an error string
-            timed_out = str(e)
-        wallclock = time.time() - t0
-        trial_accs = []
-        for t in ctrl.state.list_trials("bench-darts-hpo-e2e"):
-            m = t.observation.metric("Validation-accuracy") if t.observation else None
-            if m is not None and m.max != "unavailable":
-                trial_accs.append(round(float(m.max), 4))
-        out = {
-            "wallclock_s": round(wallclock, 2),
-            "algorithm": "tpe",
-            "n_trials": n_trials,
-            "trial_accs": trial_accs,
-            "best_val_acc": max(trial_accs) if trial_accs else None,
-            "scale": scale,
-            "contention_factor": round(contention, 2),
-        }
-        if timed_out is None:
-            verify_experiment_results(ctrl, exp)
-            out["verified"] = True
-        else:
-            out["partial"] = f"run timeout after {len(trial_accs)} trials: {timed_out}"
-        if n_trials < n_requested:
-            out["trimmed_from"] = n_requested  # budget, not capability
-        return out
-    finally:
-        ctrl.close()
-        shutil.rmtree(root, ignore_errors=True)
-
-
-def _bench_pack_throughput(jax, np):
-    """Vmapped trial packing (controller/packing.py): N small MNIST-CNN
-    trials run twice THROUGH the framework — sequentially (pack_size=1,
-    parallel=1, each trial paying its own dispatch + compile) and as one
-    packed vmapped program (pack_size=N) — and the trials/sec ratio is the
-    packing win. Per-trial objective metrics must be bit-identical between
-    the two runs (same member program, K=1 vs K=N; tests/test_packing.py
-    pins the same invariant at smaller N)."""
-    import shutil
-    import tempfile
-
-    from katib_tpu.api import (
-        AlgorithmSpec, ExperimentSpec, FeasibleSpace, ObjectiveSpec,
-        ObjectiveType, ParameterSpec, ParameterType, TrialTemplate,
-    )
-    from katib_tpu.api.spec import TrialResources
-    from katib_tpu.controller.experiment import ExperimentController
-
-    n_trials = int(os.environ.get("BENCH_PACK_TRIALS", "16"))
-    lrs = ["%0.4f" % (0.005 + 0.005 * i) for i in range(n_trials)]
-
-    def run(pack_size: int):
-        root = tempfile.mkdtemp(prefix="bench-pack-")
-        ctrl = ExperimentController(root_dir=root)
-        try:
-            spec = ExperimentSpec(
-                name="bench-pack-throughput",
-                parameters=[
-                    ParameterSpec(
-                        "lr", ParameterType.DISCRETE, FeasibleSpace(list=lrs)
-                    ),
-                    # shape-affecting knobs: single-value spaces, uniform
-                    # across the pack (docs/trial-packing.md)
-                    ParameterSpec(
-                        "num_train_examples", ParameterType.DISCRETE,
-                        FeasibleSpace(list=["256"]),
-                    ),
-                    ParameterSpec(
-                        "batch_size", ParameterType.DISCRETE,
-                        FeasibleSpace(list=["64"]),
-                    ),
-                    ParameterSpec(
-                        "conv1_channels", ParameterType.DISCRETE,
-                        FeasibleSpace(list=["8"]),
-                    ),
-                    ParameterSpec(
-                        "conv2_channels", ParameterType.DISCRETE,
-                        FeasibleSpace(list=["16"]),
-                    ),
-                    ParameterSpec(
-                        "hidden_size", ParameterType.DISCRETE,
-                        FeasibleSpace(list=["64"]),
-                    ),
-                ],
-                objective=ObjectiveSpec(
-                    type=ObjectiveType.MAXIMIZE,
-                    objective_metric_name="accuracy",
-                    additional_metric_names=["loss"],
-                ),
-                algorithm=AlgorithmSpec("grid"),
-                trial_template=TrialTemplate(
-                    entry_point="katib_tpu.models.mnist_cnn:run_mnist_trial_packed",
-                    resources=TrialResources(pack_size=pack_size),
-                ),
-                max_trial_count=n_trials,
-                parallel_trial_count=max(pack_size, 1),
-            )
-            ctrl.create_experiment(spec)
-            t0 = time.time()
-            ctrl.run("bench-pack-throughput", timeout=_child_remaining() - 20.0)
-            wall = time.time() - t0
-            metrics = {}
-            for t in ctrl.state.list_trials("bench-pack-throughput"):
-                logs = ctrl.obs_store.get_observation_log(t.name, metric_name="accuracy")
-                metrics[t.assignments_dict()["lr"]] = [l.value for l in logs]
-            return wall, metrics
-        finally:
-            ctrl.close()
-            shutil.rmtree(root, ignore_errors=True)
-
-    seq_wall, seq_metrics = run(1)
-    pack_wall, pack_metrics = run(n_trials)
-    return {
-        "n_trials": n_trials,
-        "workload": "small mnist-cnn 8/16/64 (256 train examples, batch 64, 1 epoch)",
-        "sequential_s": round(seq_wall, 2),
-        "packed_s": round(pack_wall, 2),
-        "sequential_trials_per_s": round(n_trials / seq_wall, 3),
-        "packed_trials_per_s": round(n_trials / pack_wall, 3),
-        "speedup": round(seq_wall / pack_wall, 2),
-        "bit_identical_metrics": seq_metrics == pack_metrics,
-    }
 
 
 def _bench_obslog_report_throughput(smoke: bool = False):
@@ -3758,986 +3268,7 @@ def _bench_ingest_throughput(smoke: bool = False):
     return out
 
 
-def _bench_preemption_latency(jax, np):
-    """Fair-share preemption round trip (controller/fairshare.py) on 8
-    abstract device slots: a low-priority 8-chip trial checkpointing every
-    20ms is preempted by a high-priority 4-chip gang. Reported legs:
-    signal→requeue (submit of the gang to the victim's TrialPreempted
-    requeue, i.e. checkpoint + cooperative exit), requeue→resume (gang runs,
-    victim redispatches and restores), and the total turnaround."""
-    import shutil
-    import tempfile
-    import threading
-
-    from katib_tpu.api.spec import (
-        AlgorithmSpec, ExperimentSpec, FeasibleSpace, ObjectiveSpec,
-        ObjectiveType, ParameterSpec, ParameterType, TrialResources,
-        TrialTemplate,
-    )
-    from katib_tpu.api.status import Experiment, Trial, TrialCondition
-    from katib_tpu.controller.events import EventRecorder, MetricsRegistry
-    from katib_tpu.controller.scheduler import TrialScheduler
-    from katib_tpu.db.state import ExperimentStateStore
-    from katib_tpu.db.store import open_store
-
-    root = tempfile.mkdtemp(prefix="bench-preempt-")
-    stamps = {}
-    resumed = threading.Event()
-
-    def victim_fn(assignments, ctx):
-        store = ctx.checkpoint_store()
-        restored = store.restore()
-        start = int(restored["epoch"]) + 1 if restored else 0
-        if restored is not None:
-            stamps["resumed"] = time.time()
-            resumed.set()
-        limit = start + 3 if restored is not None else 2000
-        for epoch in range(start, limit):
-            store.save(epoch, {"epoch": epoch})
-            ctx.report(score=float(epoch))
-            time.sleep(0.02)
-
-    def urgent_fn(assignments, ctx):
-        stamps["gang_ran"] = time.time()
-        ctx.report(score=1.0)
-
-    def make_exp(name, fn, num_devices, priority):
-        return Experiment(spec=ExperimentSpec(
-            name=name,
-            parameters=[ParameterSpec(
-                "x", ParameterType.DOUBLE, FeasibleSpace(min="0", max="1"))],
-            objective=ObjectiveSpec(
-                type=ObjectiveType.MAXIMIZE, objective_metric_name="score"),
-            algorithm=AlgorithmSpec("random"),
-            trial_template=TrialTemplate(
-                function=fn, resources=TrialResources(num_devices=num_devices)),
-            priority_class=priority,
-        ))
-
-    recorder = EventRecorder()
-    sched = TrialScheduler(
-        ExperimentStateStore(None), open_store(None),
-        devices=list(range(8)), workdir_root=root,
-        events=recorder, metrics=MetricsRegistry(),
-    )
-    try:
-        lo = make_exp("bench-lo", victim_fn, 8, "low")
-        hi = make_exp("bench-hi", urgent_fn, 4, "high")
-        sched.state.create_experiment(lo)
-        sched.state.create_experiment(hi)
-        victim = Trial(name="bench-victim", experiment_name="bench-lo")
-        sched.state.create_trial(victim)
-        sched.submit(lo, victim)
-
-        def wait(cond, timeout=30.0):
-            deadline = time.time() + timeout
-            while time.time() < deadline:
-                if cond():
-                    return True
-                time.sleep(0.005)
-            return False
-
-        wait(lambda: "bench-victim" in sched._last_checkpoint)
-        t_signal = time.time()
-        urgent = Trial(name="bench-urgent", experiment_name="bench-hi")
-        sched.state.create_trial(urgent)
-        sched.submit(hi, urgent)
-        wait(lambda: any(
-            e.reason == "TrialPreempted" for e in recorder.list("bench-lo")))
-        requeue_event = next(
-            e for e in recorder.list("bench-lo") if e.reason == "TrialPreempted")
-        wait(lambda: resumed.is_set(), timeout=60)
-        wait(lambda: (sched.state.get_trial("bench-lo", "bench-victim")
-                      or victim).is_terminal, timeout=60)
-        t_resumed = stamps.get("resumed", time.time())
-        return {
-            "devices": 8,
-            "victim": "8-chip low-priority, checkpoint every 20ms",
-            "preemptor": "4-chip high-priority gang",
-            "signal_to_requeue_s": round(requeue_event.timestamp - t_signal, 4),
-            "requeue_to_resume_s": round(t_resumed - requeue_event.timestamp, 4),
-            "total_roundtrip_s": round(t_resumed - t_signal, 4),
-        }
-    finally:
-        sched.kill_all()
-        sched.join(timeout=10)
-        shutil.rmtree(root, ignore_errors=True)
-
-
-def _bench_fairshare_throughput(jax, np):
-    """Mixed small/large gang traffic through the full controller, FIFO
-    baseline (no fair-share knobs) vs fair-share (large gangs high-priority):
-    with FIFO, 6-chip gangs starve behind 1-chip churn on an 8-slot machine;
-    the policy's ordering + reservation pulls their completion forward while
-    total trials/sec stays comparable."""
-    import shutil
-    import tempfile
-    import threading
-
-    from katib_tpu.api.spec import (
-        AlgorithmSpec, ExperimentSpec, FeasibleSpace, ObjectiveSpec,
-        ObjectiveType, ParameterSpec, ParameterType, TrialResources,
-        TrialTemplate,
-    )
-    from katib_tpu.controller.experiment import ExperimentController
-
-    def napping_trial(assignments, ctx):
-        time.sleep(0.03)
-        ctx.report(score=float(assignments["x"]))
-
-    def run(priorities: bool):
-        root = tempfile.mkdtemp(prefix="bench-fairshare-")
-        ctrl = ExperimentController(root_dir=root, devices=list(range(8)))
-        try:
-            def spec(name, num_devices, max_trials, parallel, priority=""):
-                return ExperimentSpec(
-                    name=name,
-                    parameters=[ParameterSpec(
-                        "x", ParameterType.DOUBLE,
-                        FeasibleSpace(min="0", max="1"))],
-                    objective=ObjectiveSpec(
-                        type=ObjectiveType.MAXIMIZE,
-                        objective_metric_name="score"),
-                    algorithm=AlgorithmSpec("random"),
-                    trial_template=TrialTemplate(
-                        function=napping_trial,
-                        resources=TrialResources(num_devices=num_devices)),
-                    priority_class=priority if priorities else "",
-                    max_trial_count=max_trials,
-                    parallel_trial_count=parallel,
-                )
-
-            ctrl.create_experiment(spec("bench-small", 1, 32, 8))
-            ctrl.create_experiment(spec("bench-large", 6, 4, 1, priority="high"))
-            done = {}
-
-            def drive(name):
-                done[name] = ctrl.run(name, timeout=90)
-
-            t0 = time.time()
-            threads = [
-                threading.Thread(target=drive, args=(n,), daemon=True)
-                for n in ("bench-small", "bench-large")
-            ]
-            for t in threads:
-                t.start()
-            large_done = None
-            for t in threads:
-                t.join(timeout=100)
-            wall = time.time() - t0
-            large = done.get("bench-large")
-            large_done = (
-                max(t.completion_time or 0.0
-                    for t in ctrl.state.list_trials("bench-large")) - t0
-                if large is not None else None
-            )
-            n_ok = sum(
-                1
-                for e in ("bench-small", "bench-large")
-                for t in ctrl.state.list_trials(e)
-                if t.is_succeeded
-            )
-            return wall, large_done, n_ok
-        finally:
-            ctrl.close()
-            shutil.rmtree(root, ignore_errors=True)
-
-    fifo_wall, fifo_large, fifo_ok = run(priorities=False)
-    fair_wall, fair_large, fair_ok = run(priorities=True)
-    return {
-        "workload": "32x 1-chip + 4x 6-chip (30ms trials, 8 slots)",
-        "fifo_wall_s": round(fifo_wall, 2),
-        "fairshare_wall_s": round(fair_wall, 2),
-        "fifo_trials_per_s": round(fifo_ok / fifo_wall, 2),
-        "fairshare_trials_per_s": round(fair_ok / fair_wall, 2),
-        "fifo_large_gangs_done_s": round(fifo_large, 2) if fifo_large else None,
-        "fairshare_large_gangs_done_s": round(fair_large, 2) if fair_large else None,
-        "large_gang_speedup": (
-            round(fifo_large / fair_large, 2)
-            if fifo_large and fair_large else None
-        ),
-    }
-
-
-def _bench_darts_mfu(jax, np, remat: bool = False):
-    """TPU-only: the DARTS supernet at the REFERENCE search configuration —
-    8 cells, 4 nodes, init_channels 16, batch 128, the full 7-op primitive
-    set (/root/reference/pkg/suggestion/v1beta1/nas/darts/service.py:120-135)
-    — bilevel search-step latency + MFU.
-
-    FLOPs come from XLA's own cost model on the compiled bilevel step
-    (lowered.compile().cost_analysis()), which counts every conv/matmul in
-    the mixed-op supernet including the Hessian-vector terms — more honest
-    than a hand flops model that inevitably drops terms. The round-4 review
-    flagged that the headline workload had step time but no MFU; this stage
-    answers "is DARTS fast on TPU?" at the scale the reference searches.
-
-    If the plain step exhausts HBM, it retries itself ONCE with
-    ``remat_cells`` on (the jax.checkpoint flag on the supernet cells) and
-    reports which mode produced the number — MFU-with-remat trades extra
-    recompute FLOPs for memory, so the result is labeled."""
-    from katib_tpu.models.darts_trainer import DartsSearch
-
-    primitives = [
-        "max_pooling_3x3",
-        "avg_pooling_3x3",
-        "skip_connection",
-        "separable_convolution_3x3",
-        "separable_convolution_5x5",
-        "dilated_convolution_3x3",
-        "dilated_convolution_5x5",
-        "none",
-    ]
-    settings = {
-        "num_epochs": 50,
-        "num_nodes": 4,
-        "init_channels": 16,
-        "batch_size": 128,
-        "stem_multiplier": 3,
-    }
-    if remat:
-        settings["remat_cells"] = "1"
-    search = DartsSearch(primitives=primitives, num_layers=8, settings=settings)
-
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((256, 32, 32, 3)).astype("float32")
-    y = rng.integers(0, 10, 256).astype("int32")
-
-    rt_ms = _roundtrip_ms(jax)
-    t0 = time.time()
-    try:
-        search.build((32, 32, 3), STEPS_PER_EPOCH * settings["num_epochs"])
-        import jax.numpy as jnp
-
-        bx, by = jnp.asarray(x[:128]), jnp.asarray(y[:128])
-        vx, vy = jnp.asarray(x[128:]), jnp.asarray(y[128:])
-        args = (
-            search.weights, search.alphas, search.w_opt_state,
-            search.a_opt_state, search.step_idx, search.hyper,
-            (bx, by), (vx, vy),
-        )
-        # AOT compile ONCE: the 8-cell bilevel step is the most expensive
-        # compile in this file, and a jit warmup call followed by a separate
-        # .lower().compile() for cost_analysis would pay it twice
-        compiled = search._search_step.lower(*args).compile()
-        state = compiled(*args)
-        _sync(state[-1])
-    except Exception as e:
-        msg = f"{type(e).__name__}: {e}"[:300]
-        oom = "RESOURCE_EXHAUSTED" in str(e) or "Out of memory" in str(e)
-        if oom and not remat and _child_remaining() > 420.0:
-            # one retry with cell-level rematerialization: the canonical
-            # HBM-for-FLOPs trade — still the reference config, labeled
-            out = _bench_darts_mfu(jax, np, remat=True)
-            if isinstance(out, dict) and "error" not in out:
-                out["memory_note"] = (
-                    "plain bilevel step exhausted HBM; measured with "
-                    "remat_cells=1 (jax.checkpoint per cell)"
-                )
-            return out
-        out = {
-            "error": msg,
-            "config": (
-                "cells=8 nodes=4 C=16 batch=128 full-op-set"
-                + (" remat_cells=1" if remat else "")
-            ),
-            "remat": remat,
-        }
-        if oom:
-            out["memory_note"] = (
-                "reference-config supernet bilevel step does not fit this "
-                "chip's HBM even with remat_cells=1; smaller batch is the "
-                "remaining mitigation (models/darts_trainer.py remat flag)"
-                if remat else
-                "reference-config supernet bilevel step does not fit this "
-                "chip's HBM and the budget left no room for the remat retry"
-            )
-        return out
-    compile_s = time.time() - t0
-
-    flops = None
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = float(ca.get("flops", 0.0)) or None
-    except Exception:
-        flops = None  # backend without cost analysis: report step time only
-
-    n_steps = int(os.environ.get("BENCH_STEPS", "30"))
-    step_s = None
-    for _pass in range(2):  # min of 2 passes: the TPU pool is shared/noisy
-        t0 = time.time()
-        for _ in range(n_steps):
-            state = compiled(*args)
-            args = tuple(state[:4]) + args[4:]
-        _sync(state[-1])
-        cur = max((time.time() - t0 - rt_ms / 1e3) / n_steps, 1e-9)
-        step_s = cur if step_s is None else min(step_s, cur)
-
-    device_kind = getattr(jax.devices()[0], "device_kind", "?")
-    peak = _peak_flops(device_kind)
-    n_params = sum(
-        int(p.size)
-        for p in jax.tree_util.tree_leaves((search.weights, search.alphas))
-    )
-    return {
-        "config": (
-            "cells=8 nodes=4 C=16 batch=128 full-op-set (reference scale)"
-            + (" remat_cells=1" if remat else "")
-        ),
-        "remat": remat,
-        # under remat, XLA's cost model counts the recomputed forward too,
-        # so this is hardware-FLOPs utilization, not model-FLOPs MFU —
-        # labeled so cross-chip comparisons don't mix the two
-        "mfu_includes_recompute": remat,
-        "compile_s": round(compile_s, 1),
-        "step_ms": round(step_s * 1e3, 2),
-        "n_params": n_params,
-        "flops_per_step": flops,
-        "flops_source": "xla cost_analysis" if flops else None,
-        "mfu": round(flops / step_s / peak, 4) if flops and peak else None,
-        "device_kind": device_kind,
-    }
-
-
-def _bench_flash_vs_dense(jax, np):
-    """TPU-only: fused Pallas flash kernel vs plain XLA dense attention."""
-    import jax.numpy as jnp
-
-    from katib_tpu.ops.flash_attention import flash_attention
-    from katib_tpu.ops.ring_attention import dense_attention
-
-    b, t, h, d = 4, 2048, 8, 64
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((b, t, h, d)), dtype=jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((b, t, h, d)), dtype=jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((b, t, h, d)), dtype=jnp.bfloat16)
-
-    flash = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
-    dense = jax.jit(lambda q, k, v: dense_attention(q, k, v, causal=True))
-    rt_ms = _roundtrip_ms(jax)
-
-    def timeit(fn, n=50):
-        _sync(fn(q, k, v))  # compile + sync
-        t0 = time.time()
-        out = q
-        for _ in range(n):
-            out = fn(out, k, v)  # chain q through: forces sequential execution
-        _sync(out)
-        return max((time.time() - t0 - rt_ms / 1e3) / n, 1e-9)
-
-    flash_s = timeit(flash)
-    dense_s = timeit(dense)
-    # numerics evidence on the same compiled kernels (bf16 tolerance)
-    max_err = float(
-        jnp.max(jnp.abs(flash(q, k, v).astype(jnp.float32)
-                        - dense(q, k, v).astype(jnp.float32)))
-    )
-    return {
-        "flash_ms": flash_s * 1e3,
-        "dense_ms": dense_s * 1e3,
-        "speedup": dense_s / flash_s,
-        "max_err_vs_dense": round(max_err, 4),
-        "shape": f"b{b} t{t} h{h} d{d} bf16 causal",
-    }
-
-
-def child_main(platform: str) -> None:
-    if platform == "cpu":
-        _force_cpu()
-    else:
-        # TPU child trains on the calibrated harder knob set, when populated
-        # (set-if-unset, before any katib_tpu.utils.datasets import), so the
-        # e2e rung's trial-accuracy distribution discriminates at the TPU
-        # scale; the CPU child stays at the datasets.py defaults its records
-        # were calibrated for. Timing stages are content-independent.
-        from katib_tpu.utils.synth_calibration import apply_tpu_rung_knobs
-
-        apply_tpu_rung_knobs()
-    import jax
-    import numpy as np
-
-    from katib_tpu.utils.compilation import enable_compilation_cache
-
-    enable_compilation_cache()
-    from katib_tpu.utils.backend import require_devices
-
-    # bounded first device touch (ISSUE 12): a child whose backend wedges
-    # AFTER the parent's probe passed raises within this bound and the
-    # parent's retry/CPU fallback engages with most of its budget intact —
-    # instead of the child silently eating its whole timeout
-    devices = require_devices(timeout_seconds=90.0)
-    on_tpu = devices[0].platform != "cpu"
-    if platform == "tpu" and not on_tpu:
-        # fail loudly so the parent's retry/fallback engages — otherwise a
-        # soft CPU fallback would be reported as the TPU result
-        raise SystemExit("tpu child got a CPU backend (accelerator init fell back)")
-
-    darts = _bench_darts(jax, np, on_tpu)  # required: the headline metric
-    projected = darts["projected_s"]
-    steady_state = darts["step_ms"] / 1e3 * STEPS_PER_EPOCH
-    # Headline = the steady-state epoch, NOT compile + epoch: the round-4
-    # review flagged that the projected first-trial number was 98% one-time
-    # XLA compile — a projection artifact, since real sweeps amortize the
-    # compile through the persistent cache (utils/compilation.py; measured
-    # 5.5s/trial across the 50-trial north star vs a 75s first compile).
-    # The first-trial projection stays in extras with the compile quoted.
-    payload = {
-        "metric": "darts_cifar10_e2e_steady_state_epoch",
-        "value": round(steady_state, 2),
-        "unit": (
-            "seconds (1-epoch darts-cpu e2e config at steady state: "
-            f"step {darts['step_ms']:.1f}ms x {STEPS_PER_EPOCH}; one-time "
-            f"compile {darts['compile_s']:.1f}s amortized by the persistent "
-            "cache across a sweep — first-trial projection in extras)"
-        ),
-        "vs_baseline": round(BASELINE_SECONDS / steady_state, 2),
-        "extras": {
-            "platform": devices[0].platform,
-            "device_kind": getattr(devices[0], "device_kind", "cpu"),
-            "darts_step_ms": round(darts["step_ms"], 2),
-            # the old headline, decomposed: one-time XLA compile + epoch —
-            # quote BOTH when citing cold-start behavior
-            "darts_compile_s": round(darts["compile_s"], 1),
-            "darts_projected_first_trial_s": round(projected, 2),
-            "darts_steady_state_epoch_s": round(steady_state, 2),
-        },
-    }
-    extras = payload["extras"]
-    _checkpoint_stage(payload)
-
-    # optional stages, cheapest-first, each budget-gated and checkpointed so
-    # a mid-run kill keeps everything already measured
-    def gate(name: str, need_s: float) -> bool:
-        left = _child_remaining()
-        if left - need_s < 15.0:
-            extras[name] = {"skipped": f"{left:.0f}s left < {need_s:.0f}s estimate"}
-            _checkpoint_stage(payload)
-            return False
-        return True
-
-    if gate("lm", 90.0):
-        try:
-            lm = _bench_lm(jax, np, on_tpu)
-            extras.update({
-                "lm_step_ms": round(lm["step_ms"], 2),
-                "lm_tokens_per_s": round(lm["tokens_per_s"]),
-                "lm_config": f"params={lm['n_params']}, b={lm['batch']}, T={lm['seq_len']}",
-                "mfu": lm["mfu"],
-                "mfu_small": lm["mfu"],
-            })
-        except Exception as e:
-            extras["lm"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if os.environ.get("BENCH_SKIP_PACK") != "1" and gate("pack_throughput", 150.0):
-        try:
-            extras["pack_throughput"] = _bench_pack_throughput(jax, np)
-        except Exception as e:
-            extras["pack_throughput"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if os.environ.get("BENCH_SKIP_FAIRSHARE") != "1" and gate("fairshare", 60.0):
-        try:
-            extras["preemption_latency"] = _bench_preemption_latency(jax, np)
-        except Exception as e:
-            extras["preemption_latency"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        try:
-            extras["fairshare_throughput"] = _bench_fairshare_throughput(jax, np)
-        except Exception as e:
-            extras["fairshare_throughput"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if os.environ.get("BENCH_SKIP_FUSEDPOP") != "1" and gate("pbt_fused", 90.0):
-        try:
-            extras["pbt_fused_throughput"] = _bench_pbt_fused_throughput()
-        except Exception as e:
-            extras["pbt_fused_throughput"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if os.environ.get("BENCH_SKIP_SUGGEST") != "1" and gate("suggestion", 90.0):
-        try:
-            extras["suggestion_throughput"] = _bench_suggestion_throughput()
-        except Exception as e:
-            extras["suggestion_throughput"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        try:
-            extras["suggestion_pipeline_latency"] = _bench_suggestion_pipeline_latency()
-        except Exception as e:
-            extras["suggestion_pipeline_latency"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if os.environ.get("BENCH_SKIP_OBSLOG") != "1" and gate("obslog", 30.0):
-        try:
-            extras["obslog_report_throughput"] = _bench_obslog_report_throughput()
-        except Exception as e:
-            extras["obslog_report_throughput"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        try:
-            extras["obslog_fold_latency"] = _bench_obslog_fold_latency()
-        except Exception as e:
-            extras["obslog_fold_latency"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    # darts_mfu runs BEFORE the cheaper lm_large/flash stages: it is the
-    # review-mandated number (reference-scale supernet MFU) and its 8-cell
-    # bilevel compile alone can take several minutes on a degraded backend —
-    # the 2026-08-01 capture lost it by ordering it after the optional
-    # stages (child killed mid-compile at the 753s budget). The estimate is
-    # honest about that compile cost.
-    if (
-        on_tpu
-        and os.environ.get("BENCH_SKIP_DARTS_MFU") != "1"
-        and gate("darts_mfu", 420.0)
-    ):
-        try:
-            extras["darts_mfu"] = _bench_darts_mfu(jax, np)
-        except Exception as e:
-            extras["darts_mfu"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if on_tpu and os.environ.get("BENCH_SKIP_LM_LARGE") != "1" and gate("lm_large", 150.0):
-        try:
-            lm_large = _bench_lm(jax, np, on_tpu, size="large")
-            extras["mfu_large"] = lm_large["mfu"]
-            extras["lm_large"] = {
-                "step_ms": round(lm_large["step_ms"], 2),
-                "tokens_per_s": round(lm_large["tokens_per_s"]),
-                "config": f"params={lm_large['n_params']}, b={lm_large['batch']}, T={lm_large['seq_len']}",
-                "compile_s": round(lm_large["compile_s"], 1),
-            }
-        except Exception as e:
-            extras["lm_large"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if on_tpu and gate("flash_attention", 90.0):
-        try:
-            flash = _bench_flash_vs_dense(jax, np)
-            extras["flash_attention"] = {
-                "flash_ms": round(flash["flash_ms"], 3),
-                "dense_ms": round(flash["dense_ms"], 3),
-                "speedup": round(flash["speedup"], 2),
-                "max_err_vs_dense": flash["max_err_vs_dense"],
-                "shape": flash["shape"],
-            }
-        except Exception as e:
-            extras["flash_attention"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    if os.environ.get("BENCH_SKIP_E2E") != "1":
-        try:
-            extras["e2e_experiment"] = _bench_e2e_experiment(jax, np, on_tpu, darts)
-        except Exception as e:  # keep the primary metric even if e2e breaks
-            extras["e2e_experiment"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-        _checkpoint_stage(payload)
-
-    print(json.dumps(payload))
-    sys.stdout.flush()
-    # Skip interpreter teardown: an e2e run timeout can leave executor
-    # threads mid-XLA-call, and finalizing the runtime under them has
-    # segfaulted (rc=-11) AFTER every result was already written — exit
-    # hard with the success code the parent expects.
-    os._exit(0)
-
-
-# ---------------------------------------------------------------------------
-# Parent: bounded orchestration, never initializes JAX itself
-# ---------------------------------------------------------------------------
-
-def _north_star_summary(relpath: str):
-    """Load one checked-in north-star record into the compact form the
-    bench artifact carries; an absent/corrupt record degrades to an error
-    entry — same degrade-never-zero pattern as the rest of the file."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), relpath)
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-    except (OSError, ValueError) as e:
-        return {"file": relpath, "error": f"{type(e).__name__}: {e}"[:200]}
-    return {
-        "file": relpath,
-        "n_trials": rec.get("n_trials"),
-        "n_succeeded": rec.get("n_succeeded"),
-        "wallclock_s": rec.get("wallclock_s"),
-        "platform": rec.get("platform"),
-        "best_val_acc": rec.get("best_val_acc"),
-        "median_val_acc": rec.get("median_val_acc"),
-        "acc_quartiles": rec.get("acc_quartiles"),
-        "derived_retrain_val_acc": (rec.get("derived_retrain") or {}).get(
-            "retrain_val_acc"
-        ),
-        "verification": rec.get("verification"),
-    }
-
-
-def _attach_north_star(result: dict) -> None:
-    """Surface the checked-in 50-trial north-star records (scripts/
-    run_north_star.py) in the bench artifact, so the driver-captured JSON
-    carries the experiment-protocol evidence even when the TPU phase is
-    skipped. The verified TPU-scale capture is the headline record; the
-    CPU variant rides along for the reduced-scale comparison."""
-    extras = result.setdefault("extras", {})
-    tpu = _north_star_summary("examples/records/darts_hpo_50trials_tpu.json")
-    cpu = _north_star_summary("examples/records/darts_hpo_50trials_cpu.json")
-    # stable per-platform keys; north_star_record is the headline copy
-    extras["north_star_record_tpu"] = tpu
-    extras["north_star_record_cpu"] = cpu
-    extras["north_star_record"] = tpu if tpu.get("verification") == "ok" else cpu
-
-
-def _salvage(result_file: str, diag: str):
-    """Recover the stages a killed child had already checkpointed — a
-    deadline mid-run degrades the report to 'partial', never to nothing."""
-    try:
-        with open(result_file) as f:
-            payload = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if not payload.get("metric"):
-        return None
-    payload.setdefault("extras", {})["partial"] = diag
-    return payload
-
-
-def _run_child(platform: str, timeout_s: float, extra_env=None):
-    """Returns (parsed_json | None, diagnostic_str | None)."""
-    import tempfile
-
-    env = dict(os.environ)
-    if platform == "cpu":
-        env["JAX_PLATFORMS"] = "cpu"
-    env.update(extra_env or {})
-    env["BENCH_CHILD_DEADLINE"] = str(time.time() + timeout_s)
-    result_file = os.path.join(
-        tempfile.gettempdir(), f"bench-{platform}-{os.getpid()}.json"
-    )
-    try:
-        os.unlink(result_file)  # never salvage a previous attempt's file
-    except OSError:
-        pass
-    env["BENCH_RESULT_FILE"] = result_file
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--child", platform],
-            capture_output=True,
-            text=True,
-            timeout=timeout_s,
-            env=env,
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-        )
-    except subprocess.TimeoutExpired:
-        diag = f"{platform} child timed out after {timeout_s:.0f}s"
-        return _salvage(result_file, diag), diag
-    def _stdout_json():
-        for line in reversed(proc.stdout.strip().splitlines()):
-            if line.startswith("{"):
-                try:
-                    payload = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if isinstance(payload, dict) and payload.get("metric"):
-                    return payload  # the bench line, not a stray JSON log
-        return None
-    if proc.returncode != 0:
-        tail = (proc.stderr or proc.stdout or "").strip().splitlines()[-3:]
-        diag = f"{platform} child rc={proc.returncode}: {' | '.join(tail)[-400:]}"
-        # a child may die in interpreter teardown (e.g. SIGSEGV unwinding
-        # abandoned JAX threads) AFTER printing its complete result — prefer
-        # that over the per-stage salvage file
-        full = _stdout_json()
-        if full is not None:
-            full.setdefault("extras", {})["partial"] = diag
-            return full, diag
-        return _salvage(result_file, diag), diag
-    result = _stdout_json()
-    if result is not None:
-        return result, None
-    return None, f"{platform} child produced no JSON line"
-
-
-def _probe_tpu(timeout_s: float):
-    """Bounded probe subprocess: init the accelerator backend and measure the
-    host round-trip BEFORE committing the TPU child's budget.
-
-    Tri-state verdict, because a backend that is merely *slow* is still worth
-    benching (the timed loops chain device-side and subtract one measured
-    round-trip, so latency biases nothing — it only adds noise that longer
-    loops amortize):
-      ("healthy",  diag, rt) — rt ≤ BENCH_PROBE_MAX_RT_MS (40)
-      ("degraded", diag, rt) — rt ≤ BENCH_PROBE_DEGRADED_RT_MS (250);
-                               caller lengthens the timed loops
-      ("dead",     diag, None) — init hung/failed or rt past the ceiling
-    """
-    max_rt = float(os.environ.get("BENCH_PROBE_MAX_RT_MS", "40"))
-    ceiling = max(max_rt, float(os.environ.get("BENCH_PROBE_DEGRADED_RT_MS", "250")))
-    # acquisition through the device plane (ISSUE 12): the probe child's
-    # OWN first jax touch is bounded with a cached verdict, so even if the
-    # parent's subprocess timeout were generous, a wedged backend costs the
-    # inner bound — and the wedge is reported as a verdict, not a hang
-    inner = max(timeout_s - 10.0, 10.0)
-    code = (
-        "import json\n"
-        "from katib_tpu.controller.deviceplane import acquire_backend\n"
-        f"d, diag = acquire_backend(timeout_seconds={inner:.0f}, retries=1)\n"
-        "assert d is not None, 'backend probe failed: ' + diag\n"
-        "assert d[0].platform != 'cpu', 'no accelerator backend'\n"
-        "from katib_tpu.utils.timing import roundtrip_ms\n"
-        "print(json.dumps({'rt_ms': round(roundtrip_ms(), 2),"
-        " 'device_kind': getattr(d[0], 'device_kind', '?')}))\n"
-    )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", code],
-            capture_output=True,
-            text=True,
-            timeout=timeout_s,
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-        )
-    except subprocess.TimeoutExpired:
-        return "dead", f"probe timed out after {timeout_s:.0f}s (backend wedged or hung)", None
-    if proc.returncode != 0:
-        tail = (proc.stderr or proc.stdout or "").strip().splitlines()[-2:]
-        return "dead", f"probe rc={proc.returncode}: {' | '.join(tail)[-200:]}", None
-    for line in reversed(proc.stdout.strip().splitlines()):
-        if line.startswith("{"):
-            try:
-                info = json.loads(line)
-                rt = float(info["rt_ms"])
-            except (ValueError, KeyError, TypeError):
-                continue  # stray log line; keep scanning upward
-            kind = info.get("device_kind", "?")
-            if rt > ceiling:
-                return "dead", (
-                    f"roundtrip {rt}ms > {ceiling}ms ceiling "
-                    "(backend degraded past use; timings would be garbage)"
-                ), None
-            if rt > max_rt:
-                return "degraded", (
-                    f"rt {rt}ms on {kind} (> {max_rt}ms healthy threshold; "
-                    "timed loops lengthened to amortize)"
-                ), rt
-            return "healthy", f"rt {rt}ms on {kind}", rt
-    return "dead", "probe produced no JSON", None
-
-
-def _probe_until_live(window_end, probe=None, sleep=time.sleep, clock=time.time):
-    """Retry the TPU probe across the whole window instead of one shot.
-
-    Round-4 lesson: the driver bench reached the TPU in only 1 of 4 rounds
-    because a single 150s probe landed inside a wedge stretch while the
-    backend recovered minutes later. This loop spends the window the TPU
-    child would have had anyway — a healthy probe exits immediately, a
-    wedged backend is re-probed every BENCH_PROBE_RETRY_SLEEP (45s) until
-    the window (total budget minus the CPU reserve) is gone.
-
-    Returns (verdict, diag, rt_ms, attempt_errors).
-    """
-    probe = probe or _probe_tpu
-    timeout = float(os.environ.get("BENCH_PROBE_TIMEOUT", "150"))
-    retry_sleep = float(os.environ.get("BENCH_PROBE_RETRY_SLEEP", "45"))
-    # Absolute attempt cap: the window bound alone would let a fast-failing
-    # probe (rc!=0 in ms, not a hang) spin thousands of times; 12 attempts
-    # out-lasts any real window at the default timing (12 x ~195s > 2300s).
-    max_attempts = int(os.environ.get("BENCH_PROBE_MAX_ATTEMPTS", "12"))
-    attempts, attempt_errors = 0, []
-    while attempts < max_attempts:
-        budget = min(timeout, window_end - clock())
-        if budget < 10:
-            return (
-                "dead",
-                attempt_errors[-1] if attempt_errors else "probe window too small",
-                None,
-                attempt_errors,
-            )
-        attempts += 1
-        verdict, diag, rt = probe(budget)
-        if verdict != "dead":
-            return verdict, diag, rt, attempt_errors
-        attempt_errors.append(f"probe attempt {attempts}: {diag}")
-        # Only wedge-shaped failures are worth waiting out (hung probe, or a
-        # round-trip past the ceiling). A fast deterministic failure — e.g.
-        # rc=1 'no accelerator backend' on a box with no accelerator at all —
-        # will not change in 45s, and retrying it would sleep away most of
-        # the CPU child's budget.
-        if "timed out" not in diag and "roundtrip" not in diag:
-            return "dead", diag, None, attempt_errors
-        if window_end - clock() < retry_sleep + 15:
-            return "dead", diag, None, attempt_errors
-        sleep(retry_sleep)
-    return (
-        "dead",
-        f"backend wedged through {attempts} probe attempts "
-        f"(last: {attempt_errors[-1] if attempt_errors else '?'})",
-        None,
-        attempt_errors,
-    )
-
-
-def _freshest_tpu_capture():
-    """Summary of the newest watcher-captured TPU bench record, labeled as
-    such — when the driver's own run cannot reach the TPU (wedge that
-    outlasts the whole budget), the artifact still carries the freshest
-    real-TPU numbers WITH their provenance instead of nothing."""
-    import glob
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    paths = sorted(glob.glob(os.path.join(here, "examples", "records", "bench_tpu_*.json")))
-    if not paths:
-        return None
-    path = paths[-1]
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-    except (OSError, ValueError):
-        return None
-    res = rec.get("result") or {}
-    ex = res.get("extras") or {}
-    darts_mfu = ex.get("darts_mfu") if isinstance(ex.get("darts_mfu"), dict) else {}
-    flash = ex.get("flash_attention") if isinstance(ex.get("flash_attention"), dict) else {}
-    return {
-        "file": os.path.relpath(path, here),
-        "captured_at": rec.get("captured_at"),
-        "probe_rt_ms": rec.get("probe_rt_ms"),
-        "provenance": (
-            "builder capture (examples/records/) from a "
-            "probe-verified live backend — NOT measured by this driver run"
-        ),
-        "headline_value_s": res.get("value"),
-        "darts_step_ms": ex.get("darts_step_ms"),
-        "mfu_small": ex.get("mfu_small"),
-        "mfu_large": ex.get("mfu_large"),
-        "darts_mfu_reference_scale": darts_mfu.get("mfu"),
-        # remat-mode numbers include recompute FLOPs; carry the label so the
-        # summary can't present them as plain model-MFU
-        "darts_mfu_remat": darts_mfu.get("remat"),
-        "flash_speedup": flash.get("speedup"),
-    }
-
-
-def main() -> None:
-    """One total deadline governs everything (round-3 lesson: the children's
-    summed worst cases must never exceed what the caller is willing to wait).
-    Order: cheap probe (retried across the TPU window when wedged) → TPU
-    child (budget minus the CPU reserve) → CPU child (whatever remains) →
-    sentinel. Every arm is derived from `remaining()`, so the sentinel line
-    always prints inside BENCH_TOTAL_BUDGET. When the TPU never answers,
-    the CPU/sentinel artifact carries the freshest watcher capture's TPU
-    numbers labeled with their provenance."""
-    deadline = time.time() + float(os.environ.get("BENCH_TOTAL_BUDGET", "1140"))
-    margin = 20.0  # sentinel/print headroom
-    cpu_reserve = float(os.environ.get("BENCH_CPU_RESERVE", "360"))
-
-    def remaining() -> float:
-        return deadline - time.time()
-
-    errors = []
-    use_tpu = os.environ.get("BENCH_FORCE_CPU") != "1"
-    probe_note = None
-    tpu_child_env = None
-    if use_tpu:
-        probe_window_end = time.time() + (remaining() - cpu_reserve - margin)
-        if probe_window_end - time.time() < 10:
-            use_tpu = False
-            errors.append("tpu probe skipped: total budget too small")
-        else:
-            verdict, diag, rt_ms, attempt_errors = _probe_until_live(probe_window_end)
-            probe_note = diag
-            if len(attempt_errors) > 1:
-                probe_note = f"{diag} (after {len(attempt_errors)} wedged attempts)"
-            if verdict == "dead":
-                use_tpu = False
-                errors.append(f"tpu probe: {diag}")
-                errors.extend(attempt_errors[:-1])
-            elif verdict == "degraded" and "BENCH_STEPS" not in os.environ:
-                # rt is subtracted once per timed pass, so its residual noise
-                # scales as rt / (steps * step_ms). steps ≈ 0.9*rt_ms keeps
-                # that residual ≈ 1/(0.9*step_ms) — about 11% of a 10ms step,
-                # 4% of a 28ms step — versus 3-8x worse at the default 30
-                # steps; the 150 cap bounds added wall-clock on slow configs.
-                # TPU child only: on the CPU fallback there is no round-trip to
-                # amortize and longer loops would just burn its reserve.
-                tpu_child_env = {
-                    "BENCH_STEPS": str(min(150, max(30, int(rt_ms * 0.9))))
-                }
-    if use_tpu:
-        for attempt in range(int(os.environ.get("BENCH_TPU_ATTEMPTS", "2"))):
-            budget = remaining() - cpu_reserve - margin
-            cap = os.environ.get("BENCH_TPU_TIMEOUT")
-            if cap:
-                budget = min(budget, float(cap))
-            if budget < 120:
-                errors.append(
-                    f"tpu attempt {attempt + 1} skipped: {budget:.0f}s left "
-                    "after the CPU reserve"
-                )
-                break
-            result, err = _run_child("tpu", budget, extra_env=tpu_child_env)
-            if result is not None:
-                extras = result.setdefault("extras", {})
-                if probe_note:
-                    extras["probe"] = probe_note
-                if tpu_child_env is not None or errors:
-                    # the round ran, but on a degraded backend (lengthened
-                    # loops) or after wedged attempts — record it instead
-                    # of letting the flag exist only in prose
-                    extras["backend_degraded"] = True
-                if errors:
-                    extras["tpu_retry_errors"] = errors
-                # a TPU run that was squeezed/killed before the reference-
-                # scale darts_mfu stage still carries the freshest watcher
-                # capture's number, labeled with its provenance
-                if (extras.get("darts_mfu") or {}).get("mfu") is None:
-                    capture = _freshest_tpu_capture()
-                    if capture and capture.get("darts_mfu_reference_scale") is not None:
-                        extras["freshest_tpu_capture"] = capture
-                _attach_north_star(result)
-                print(json.dumps(result))
-                return
-            errors.append(err)
-            if "timed out" in (err or ""):
-                break  # the backend burned its whole leash; don't re-queue it
-            time.sleep(float(os.environ.get("BENCH_RETRY_SLEEP", "5")))
-    cpu_budget = remaining() - margin
-    cap = os.environ.get("BENCH_CPU_TIMEOUT")
-    if cap:
-        cpu_budget = min(cpu_budget, float(cap))
-    if cpu_budget >= 60:
-        result, err = _run_child("cpu", cpu_budget)
-        if result is not None:
-            extras = result.setdefault("extras", {})
-            extras["tpu_init_errors"] = errors
-            if os.environ.get("BENCH_FORCE_CPU") != "1":
-                # the accelerator round degraded to the CPU fallback: the
-                # ROADMAP "bench never loses a round" clause — the record
-                # says backend_degraded, it never times out empty
-                extras["backend_degraded"] = True
-            capture = _freshest_tpu_capture()
-            if capture:  # real-TPU numbers with watcher provenance
-                extras["freshest_tpu_capture"] = capture
-            _attach_north_star(result)
-            print(json.dumps(result))
-            return
-        errors.append(err)
-    else:
-        errors.append(f"cpu child skipped: only {cpu_budget:.0f}s left")
-    # final fallback: still one parseable JSON line, value = sentinel
-    sentinel = {
-        "metric": "darts_cifar10_e2e_steady_state_epoch",
-        "value": -1.0,
-        "unit": "seconds (BENCH FAILED — see extras.errors)",
-        "vs_baseline": 0.0,
-        "extras": {"errors": errors, "backend_degraded": True},
-    }
-    capture = _freshest_tpu_capture()
-    if capture:
-        sentinel["extras"]["freshest_tpu_capture"] = capture
-    _attach_north_star(sentinel)
-    print(json.dumps(sentinel))
-
-
-# control-plane scenarios runnable standalone (no JAX, no child
-# orchestration): `python bench.py obslog_report_throughput [--smoke]`.
-# --smoke trims sizes to the tier-1 wiring run (tests/test_bench_budget.py).
-OBSLOG_SCENARIOS = {
+SCENARIOS = {
     "obslog_report_throughput": _bench_obslog_report_throughput,
     "obslog_fold_latency": _bench_obslog_fold_latency,
     "tracing_overhead": _bench_tracing_overhead,
@@ -4760,13 +3291,15 @@ OBSLOG_SCENARIOS = {
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 2 and sys.argv[1] == "--child":
-        child_main(sys.argv[2])
-    elif len(sys.argv) > 1 and sys.argv[1] in OBSLOG_SCENARIOS:
-        kwargs = {"smoke": "--smoke" in sys.argv[2:]}
-        if "--distributed" in sys.argv[2:]:
-            kwargs["distributed"] = True  # tracing_overhead only (ISSUE 19)
-        result = OBSLOG_SCENARIOS[sys.argv[1]](**kwargs)
-        print(json.dumps({"metric": sys.argv[1], **result}))
-    else:
-        main()
+    if len(sys.argv) < 2 or sys.argv[1] not in SCENARIOS:
+        print(
+            "usage: python bench.py <scenario> [--smoke] [--distributed]\n"
+            "scenarios:\n  " + "\n  ".join(SCENARIOS),
+            file=sys.stderr,
+        )
+        sys.exit(2)
+    kwargs = {"smoke": "--smoke" in sys.argv[2:]}
+    if "--distributed" in sys.argv[2:]:
+        kwargs["distributed"] = True  # tracing_overhead only (ISSUE 19)
+    result = SCENARIOS[sys.argv[1]](**kwargs)
+    print(json.dumps({"metric": sys.argv[1], **result}))

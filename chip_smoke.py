@@ -26,9 +26,8 @@ Phases (no option, one chip):
   ``tpu_custom_call`` (neither interpret mode nor the dense branch ran).
 - sweep: ExperimentController(devices=jax.local_devices()) -> TPE -> scheduler
   -> in-process executor -> ctx.report -> store, over
-  ``katib_tpu.parallel.train:run_lm_trial`` at the "large" widths of
-  ``bench_lm_config`` (depth and widths as published there), 5 trials of 20
-  steps, learning_rate searched log-uniform.
+  ``katib_tpu.parallel.train:run_lm_trial`` at the LM-large widths ``Sizes``
+  states, 5 trials of 20 steps, learning_rate searched log-uniform.
 
 With ``--chips 4``: the same trial at numDevices 4 / tensor_parallel 2 (data 2
 x model 2) through the controller with the four real devices as its pool,
@@ -73,7 +72,7 @@ class Sizes:
     """What the smoke runs at. The defaults are the real thing; a rehearsal
     on the CPU imports this module and passes smaller ones."""
 
-    # bench_lm_config("large", on_tpu=True) — katib_tpu/models/transformer.py
+    # the LM-large widths (heads of 64)
     vocab_size: int = 32768
     embed_dim: int = 1024
     num_layers: int = 8

@@ -103,7 +103,7 @@ def architect_alpha_grad(
     )
 
     # validation grads at (w', alpha) — one joint backward pass for both
-    # cotangents (graph size == compile time on TPU; see bench.py)
+    # cotangents (graph size == compile time on TPU)
     val_loss = lambda w, a: _loss_fn(model, w, a, valid_batch)
     dw, dalpha = jax.grad(val_loss, argnums=(0, 1))(v_weights, alphas)
 

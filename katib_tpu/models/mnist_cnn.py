@@ -25,7 +25,7 @@ from ..utils.datasets import batches, load_mnist
 class MnistCNN(nn.Module):
     """mnist.py Net: two convs + two dense layers. Widths default to the
     reference image's (20/50/500); smaller widths make the "small
-    MNIST-CNN" packing benchmark (bench.py pack_throughput)."""
+    MNIST-CNN" of the packing tests (tests/test_packing.py)."""
 
     conv1: int = 20
     conv2: int = 50

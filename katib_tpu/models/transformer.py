@@ -126,32 +126,6 @@ class TransformerConfig:
         return dict(self.rotary).get(kind, RotaryConfig())
 
 
-def bench_lm_config(size: str, on_tpu: bool):
-    """The canonical benchmark LM shapes — single source for bench.py,
-    scripts/tune_tpu.py and scripts/profile_lm.py so a retune can't leave
-    one of them measuring a stale configuration. Returns
-    ``(config_kwargs, batch, seq, effective_size)``; off-TPU every size
-    degrades to the sub-minute CPU smoke shape (and says so in
-    ``effective_size``)."""
-    if not on_tpu:
-        return (
-            dict(vocab_size=512, embed_dim=128, num_layers=2, num_heads=4,
-                 max_seq_len=256, dtype=jnp.float32),
-            4, 256, "cpu_smoke",
-        )
-    if size == "large":
-        return (
-            dict(vocab_size=32768, embed_dim=1024, num_layers=8, num_heads=16,
-                 max_seq_len=2048, dtype=jnp.bfloat16),
-            4, 2048, "large",
-        )
-    return (
-        dict(vocab_size=8192, embed_dim=512, num_layers=4, num_heads=8,
-             max_seq_len=1024, dtype=jnp.bfloat16),
-        8, 1024, "small",
-    )
-
-
 def rotary_frequencies(rotated: int, theta: float, yarn: YarnConfig) -> np.ndarray:
     """YaRN's ``rotated // 2`` frequencies, as the published modelling code
     computes them (transformers ``_compute_yarn_parameters``)."""

@@ -23,8 +23,8 @@ hyperparameters instead of pegging at the ceiling:
   overlaid and Gaussian pixel noise added.
 
 At the TPU benchmark budget (192 search steps/trial: 6 epochs x 4096
-examples, 8-channel supernet — scripts/run_north_star.py and bench.py's
-e2e rung use exactly this) accuracy spans roughly chance to ~0.9 across an
+examples, 8-channel supernet — scripts/run_north_star.py uses exactly
+this) accuracy spans roughly chance to ~0.9 across an
 HPO sweep; measured anchors: a 12/24-channel Adam CNN reaches ~0.9 in 96
 steps at lr 3e-3 vs ~0.35 at lr 1e-4, and a 4-channel supernet at 192
 steps reaches 0.44 (tests/test_datasets.py pins the contract).

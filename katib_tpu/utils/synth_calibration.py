@@ -3,7 +3,7 @@
 `utils/datasets.py` reads its KATIB_TPU_SYNTH_* knobs once at import. The
 round-5 defaults there are calibrated for the CPU-scale records; at the TPU
 benchmark rung (8-channel supernet, 192 search steps —
-scripts/run_north_star.py --tpu and bench.py's TPU e2e ladder) those
+scripts/run_north_star.py --tpu) those
 defaults leave the ceiling too wide: any decent w_lr reaches ~1.0, TPE
 exploits into the plateau, and the 50-trial quartiles degenerate
 (examples/records/darts_hpo_50trials_tpu.json, 2026-08-01 first recapture).

@@ -8,7 +8,7 @@ reported by something other than the device: the value isn't available
 until the producing computation (and, through data dependencies, everything
 it chains from) has run.
 
-The recipe used by bench.py and the hardware-gated perf tests:
+The recipe of the hardware-gated perf tests (tests/test_tpu_hardware.py):
 
 1. ``host_sync`` once before starting the clock (drains queued work);
 2. chain each iteration's output into the next iteration's input so the
@@ -31,7 +31,7 @@ def host_sync(x) -> float:
 
 def roundtrip_ms(repeats: int = 3) -> float:
     """Per-call dispatch + host-read round-trip latency in milliseconds —
-    what step 4 above subtracts; bench.py's probe records it."""
+    what step 4 above subtracts."""
     import jax
     import jax.numpy as jnp
 

@@ -14,8 +14,7 @@ fixed optimizer settings — good / mid / bad — and reports the val-acc each
 reaches. The knobs are read at import, so every knob set runs in its own
 subprocess. Pick the set where good ≈ 0.75-0.9 (ceiling below saturation),
 mid lands mid-range, and bad stays near chance; wire the winner into
-run_north_star.py's --tpu path and bench.py's TPU child as
-set-if-unset env defaults, and re-capture.
+run_north_star.py's --tpu path as set-if-unset env defaults, and re-capture.
 
 One process owns the chip at a time: this parent never imports JAX, and it
 runs the children one after another, so each child has the chip to itself.

@@ -87,7 +87,7 @@ def main() -> None:
     platform = jax.devices()[0].platform
     on_tpu = platform != "cpu"
     if args.tpu and not on_tpu:
-        # fail loudly (bench.py's tpu child does the same): proceeding would
+        # fail loudly: proceeding would
         # run the CPU scale with the harder TPU knob set already in the
         # environment and overwrite the default-knob CPU record series with
         # an incomparable artifact
@@ -185,7 +185,7 @@ def main() -> None:
             })
         opt = exp.status.current_optimal_trial
         # "verification" and "optimal_assignments" are a stable contract:
-        # run_derived_retrain.py and bench.py read the record by
+        # run_derived_retrain.py reads the record by
         # verification == "ok" and a non-null optimal_assignments
         record = {
             "experiment": name,

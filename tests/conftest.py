@@ -26,8 +26,7 @@ if os.environ.get("KATIB_TPU_TEST_TPU") != "1":
 
 
 def load_bench_module():
-    """Load repo-root bench.py as a module (shared by test_bench_budget's
-    fixture and the hardware-gated MFU test)."""
+    """Load repo-root bench.py as a module (test_bench_budget's fixture)."""
     import importlib.util
 
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py")

@@ -82,15 +82,9 @@ def test_tolerances_and_widths_are_the_stated_ones():
         import chip_smoke
     finally:
         sys.path.remove(REPO)
-    from katib_tpu.models.transformer import bench_lm_config
-
-    kw, batch, seq, size = bench_lm_config("large", on_tpu=True)
     s = chip_smoke.Sizes()
-    assert size == "large"
-    assert (s.vocab_size, s.embed_dim, s.num_layers, s.num_heads) == (
-        kw["vocab_size"], kw["embed_dim"], kw["num_layers"], kw["num_heads"]
-    )
-    assert (s.batch_size, s.seq_len) == (batch, seq)
+    assert (s.vocab_size, s.embed_dim, s.num_layers, s.num_heads) == (32768, 1024, 8, 16)
+    assert (s.batch_size, s.seq_len) == (4, 2048)
     # the kernel phase runs the attention shape of exactly that model
-    assert s.attn_shape == (batch, seq, kw["num_heads"], kw["embed_dim"] // kw["num_heads"])
+    assert s.attn_shape == (s.batch_size, s.seq_len, s.num_heads, s.embed_dim // s.num_heads)
     assert s.max_trials == 5 and s.num_steps == 20

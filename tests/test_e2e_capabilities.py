@@ -102,7 +102,7 @@ def _tiny_darts_hpo(assignments, ctx):
 def test_darts_hpo_multitrial_e2e(controller):
     """The north-star shape: an HPO algorithm (tpe) searching the DARTS
     bilevel trainer's optimizer hyperparameters across multiple trials
-    (bench.py _bench_e2e_experiment runs this at learning scale on TPU)."""
+    (scripts/run_north_star.py runs this at learning scale)."""
     from katib_tpu.api import Distribution
 
     spec = ExperimentSpec(

@@ -92,8 +92,7 @@ def test_record_parses(path):
 )
 def test_north_star_record_contract(name):
     """The stage-2 derived retrain is gated on
-    ``verification == 'ok' and optimal_assignments`` and bench.py attaches
-    the record to its extras by these same fields — the contract the north
+    ``verification == 'ok' and optimal_assignments`` — the contract the north
     star script promises (run_north_star.py 'stable contract' comment) must
     hold in every checked-in artifact."""
     # no skip-on-missing: both records are checked in, and a rename or

@@ -122,7 +122,7 @@ class TestPackedVsSequentialParity:
         assert 'katib_pack_occupancy{experiment="pack-parity"} 1.0' in metrics
 
     def test_mnist_packed_parity_small(self):
-        """The bench.py pack_throughput invariant at small N: the vmapped
+        """The packing invariant on a real model at small N: the vmapped
         MNIST-CNN population produces bit-identical objective metrics to
         solo runs of the same members."""
         from katib_tpu.models.mnist_cnn import run_mnist_trial_packed

@@ -255,6 +255,19 @@ class TestMfu:
         assert peak_flops_for("quantum-annealer") is None
         assert peak_flops_for(None) is None
 
+    def test_peak_flops_agree_with_the_benchmarks_table(self, monkeypatch):
+        """Two tables of peaks remain, the package's and the benchmark's:
+        every device kind the benchmark knows reads the same in both."""
+        from katib_tpu.analysis.costmodel import ENV_PEAK_FLOPS, peak_flops_for
+
+        monkeypatch.delenv(ENV_PEAK_FLOPS, raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(repo, "benchmarks", "peaks.json")) as f:
+            peaks = {k: v for k, v in json.load(f).items() if k != "source"}
+        assert "TPU v5 lite" in peaks
+        for kind, row in peaks.items():
+            assert peak_flops_for(kind) == row["bf16_flops_per_s"], kind
+
     def test_peak_flops_env_override_wins(self, monkeypatch):
         from katib_tpu.analysis.costmodel import ENV_PEAK_FLOPS, peak_flops_for
 
