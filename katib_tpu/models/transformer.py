@@ -30,6 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops.flash_attention import flash_attention, sharded_flash_attention
 from ..ops.ring_attention import dense_attention, ring_attention
@@ -215,9 +216,55 @@ class QKVProjection(nn.Module):
         return tuple(jnp.einsum("...e,ehd->...hd", x, kernel[:, i]) for i in range(3))
 
 
+@jax.custom_vjp
+def _gradient_in_stored_order(kernel):
+    """The identity; the gradient that comes back through it is laid out
+    row-major, as a stored kernel is (GroupedQKVProjection says what for)."""
+    return kernel
+
+
+def _gradient_in_stored_order_bwd(_, g):
+    return (with_layout_constraint(g, Layout(major_to_minor=tuple(range(g.ndim)))),)
+
+
+_gradient_in_stored_order.defvjp(lambda kernel: (kernel, None), _gradient_in_stored_order_bwd)
+
+
+class HeadProjection(nn.Module):
+    """x [..., E] times one stored kernel [E, H, D]: the parameter
+    nn.DenseGeneral((h, d)) creates (same name, shape, dtype and initial
+    values), its value and its gradients."""
+
+    num_heads: int
+    head_dim: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", _lecun_normal_drawn_flat,
+            (x.shape[-1], self.num_heads, self.head_dim), jnp.float32,
+        )
+        x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
+        return jnp.einsum("...e,ehd->...hd", x, _gradient_in_stored_order(kernel))
+
+
 class GroupedQKVProjection(nn.Module):
     """q over ``num_heads`` heads, k and v over ``num_kv_heads``: three stored
-    kernels, three plain products (grouped KV heads have no [E, 3, H, D])."""
+    kernels [E, H, D], three plain products (grouped KV heads have no
+    [E, 3, H, D]).
+
+    Left to nn.DenseGeneral, XLA computes q's weight gradient head-major
+    (``[E, H, D]{2,0,1}``: the flash kernels hand the heads back as
+    ``[H, T, D]``) in one fusion with AdamW, which so reads the kernel and
+    both moments through transposing copies and writes all three head-major,
+    for three more copies behind it: 21.0 ms a step in the sparse cell where
+    the out kernel's update, the same arithmetic over the same bytes, takes
+    8.4. However the product is written, XLA folds it back into that fusion.
+    With the gradient's layout stated, the product is a fusion of its own
+    and the turn is made on its bfloat16 result alone, inside AdamW's fusion,
+    which runs over plain layouts of kernel and moments: 10.2 ms a step
+    (PERF.md, PR 33)."""
 
     num_heads: int
     num_kv_heads: int
@@ -227,8 +274,7 @@ class GroupedQKVProjection(nn.Module):
     @nn.compact
     def __call__(self, x):
         def project(name, heads):
-            return nn.DenseGeneral(
-                (heads, self.head_dim), use_bias=False, dtype=self.dtype, name=name)(x)
+            return HeadProjection(heads, self.head_dim, dtype=self.dtype, name=name)(x)
 
         return project("q", self.num_heads), project("k", self.num_kv_heads), project(
             "v", self.num_kv_heads)

@@ -131,3 +131,86 @@ def test_sliced_kernels_keep_the_rule_of_the_stored_one():
     assert tuple(kernel.sharding.spec) == ("fsdp", None, "model", None)
     grad = grads["block0"]["attn"]["qkv"]["kernel"]
     assert grad.sharding.is_equivalent_to(kernel.sharding, grad.ndim)
+
+
+# The grouped projection (PR 33): each of q, k and v is a HeadProjection, whose
+# weight gradient comes back with its layout stated, against
+# nn.DenseGeneral((h, d)) over the same parameters. 6 query heads over 2 KV heads.
+class _DenseGeneralProjections(nn.Module):
+    """The old form of GroupedQKVProjection."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: object
+
+    @nn.compact
+    def __call__(self, x):
+        def project(name, heads):
+            return nn.DenseGeneral(
+                (heads, self.head_dim), use_bias=False, dtype=self.dtype, name=name)(x)
+
+        return project("q", self.num_heads), project("k", self.num_kv_heads), project(
+            "v", self.num_kv_heads)
+
+
+def _grouped(cls, dtype):
+    return cls(6, 2, 8, dtype=dtype)
+
+
+def _grouped_input(dtype):
+    return jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32), dtype)
+
+
+def test_grouped_parameter_tree_and_initial_values_are_dense_generals():
+    x = _grouped_input(jnp.bfloat16)
+    new = _grouped(transformer.GroupedQKVProjection, jnp.bfloat16).init(jax.random.PRNGKey(3), x)
+    old = _grouped(_DenseGeneralProjections, jnp.bfloat16).init(jax.random.PRNGKey(3), x)
+    new_flat = flax.traverse_util.flatten_dict(new["params"], sep="/")
+    old_flat = flax.traverse_util.flatten_dict(old["params"], sep="/")
+    assert {k: v.shape for k, v in new_flat.items()} == {
+        "q/kernel": (32, 6, 8), "k/kernel": (32, 2, 8), "v/kernel": (32, 2, 8)}
+    assert sorted(new_flat) == sorted(old_flat)
+    for path, leaf in new_flat.items():
+        assert leaf.dtype == old_flat[path].dtype == jnp.float32, path
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(old_flat[path]), err_msg=path)
+
+
+@pytest.mark.parametrize("dtype", [
+    pytest.param(jnp.float32, id="float32"), pytest.param(jnp.bfloat16, id="bfloat16")])
+def test_grouped_value_and_gradients_are_dense_generals(dtype):
+    """Value, input gradient and the three weight gradients: the same products
+    with the same contractions (the weight gradient rounded to the compute
+    dtype once, as DenseGeneral's is), so the same bits in either precision."""
+    x = _grouped_input(dtype)
+    new, old = _grouped(transformer.GroupedQKVProjection, dtype), _grouped(_DenseGeneralProjections, dtype)
+    params = new.init(jax.random.PRNGKey(3), x)["params"]
+    weights = [jax.random.normal(jax.random.PRNGKey(5 + i), (2, 24, h, 8), jnp.float32)
+               for i, h in enumerate((6, 2, 2))]
+
+    def run(module):
+        def loss(p, x):
+            outs = module.apply({"params": p}, x)
+            return sum((o.astype(jnp.float32) * w).sum() for o, w in zip(outs, weights)), outs
+
+        (_, outs), (d_params, d_x) = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+        return {"d_x": d_x, **{f"out_{n}": o for n, o in zip("qkv", outs)},
+                **{f"d_{n}": d_params[n]["kernel"] for n in "qkv"}}
+
+    got, want = run(new), run(old)
+    for name, leaf in got.items():
+        assert leaf.shape == want[name].shape and leaf.dtype == want[name].dtype, name
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want[name]), err_msg=name)
+
+
+def test_grouped_weight_gradients_come_back_with_their_layout_stated():
+    """What keeps XLA from folding the weight gradient's turn, with those of
+    the kernel and both moments, into its AdamW (tests/test_tpu_aot_compile.py
+    holds the compiled form to it)."""
+    x = _grouped_input(jnp.bfloat16)
+    module = _grouped(transformer.GroupedQKVProjection, jnp.bfloat16)
+    params = module.init(jax.random.PRNGKey(3), x)["params"]
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: sum(o.astype(jnp.float32).sum() for o in module.apply({"params": p}, x))))(params))
+    assert text.count("layout_constraint") == 3          # one a kernel

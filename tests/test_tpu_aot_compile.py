@@ -127,7 +127,7 @@ def test_flash_value_and_grad_compiles_for_v5e(one_chip, shape, dtype):
 
 
 def entry_ops_by_estimated_cycles(compiled_text):
-    """[(cycles, instruction, start of its result type, op_name)] of the
+    """[(cycles, instruction, its result type, op_name)] of the
     compiled module's entry computation, the compiler's costliest first.
     Pallas calls carry no estimate and are left out. At the v5e's 1.5 GHz
     the estimates read within 4 % of the chip on the head's products and
@@ -140,32 +140,22 @@ def entry_ops_by_estimated_cycles(compiled_text):
         head = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+)", line)
         if cycles and head:
             op_name = re.search(r'op_name="([^"]*)"', line)
-            rows.append((int(cycles.group(1)), head.group(1), head.group(2)[:48],
+            rows.append((int(cycles.group(1)), head.group(1), head.group(2),
                          op_name.group(1) if op_name else ""))
     return sorted(rows, reverse=True)
 
 
-def test_qkv_weight_gradient_is_no_windowed_convolution_on_v5e(one_chip, monkeypatch):
-    """One Block + AdamW at the benchmark cells' widths (E 2048, 16 heads of
-    128, batch 4 x 2048). With q, k and v sliced out of one DenseGeneral's
-    result, XLA made the weight gradient one convolution over the tokens
-    with [3, H] as its window (``window={size=3x16x4 ...}``), a third as
-    fast as the three products it stands for (PERF.md, PR 28)."""
+def _adamw_step_text(module, one_chip, batch, tokens, embed, dtype):
+    """The compiled text of one step of ``module(x, positions)`` summed as a
+    loss, with its input's gradient, AdamW and donated state, as a train step
+    has them, for the described chip."""
     import optax
 
-    from katib_tpu.models.transformer import Block, TransformerConfig
-    from katib_tpu.ops import flash_attention as fa
-
-    # the CPU backend is the default one here: take the chip's branch
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    heads, b, t, e = 16, 4, 2048, 2048
-    cfg = TransformerConfig(embed_dim=e, num_heads=heads, max_seq_len=t)
-    block = Block(cfg)
     tx = optax.adamw(1e-3, weight_decay=0.01)
-    init_x = jnp.zeros((1, 16, e), cfg.dtype)
+    init_x = jnp.zeros((1, 16, embed), dtype)
     init_pos = jnp.zeros((1, 16), jnp.int32)
     params = jax.eval_shape(
-        lambda k: block.init(k, init_x, init_pos)["params"], jax.random.PRNGKey(0)
+        lambda k: module.init(k, init_x, init_pos)["params"], jax.random.PRNGKey(0)
     )
     opt_state = jax.eval_shape(tx.init, params)
 
@@ -175,19 +165,34 @@ def test_qkv_weight_gradient_is_no_windowed_convolution_on_v5e(one_chip, monkeyp
         )
 
     def step(params, opt_state, x, positions):
-        def loss_fn(p):
-            return block.apply({"params": p}, x, positions).astype(jnp.float32).sum()
+        def loss_fn(p, x):
+            return module.apply({"params": p}, x, positions).astype(jnp.float32).sum()
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        loss, (grads, d_x) = jax.value_and_grad(loss_fn, argnums=(0, 1))(params, x)
         updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        return optax.apply_updates(params, updates), opt_state, loss, d_x
 
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(opt_state),
-        jax.ShapeDtypeStruct((b, t, e), cfg.dtype, sharding=one_chip),
-        jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
-    ).compile()
-    text = compiled.as_text()
+        jax.ShapeDtypeStruct((batch, tokens, embed), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((batch, tokens), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+
+
+def test_qkv_weight_gradient_is_no_windowed_convolution_on_v5e(one_chip, monkeypatch):
+    """One Block + AdamW at the benchmark cells' widths (E 2048, 16 heads of
+    128, batch 4 x 2048). With q, k and v sliced out of one DenseGeneral's
+    result, XLA made the weight gradient one convolution over the tokens
+    with [3, H] as its window (``window={size=3x16x4 ...}``), a third as
+    fast as the three products it stands for (PERF.md, PR 28)."""
+    from katib_tpu.models.transformer import Block, TransformerConfig
+    from katib_tpu.ops import flash_attention as fa
+
+    # the CPU backend is the default one here: take the chip's branch
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    heads, b, t, e = 16, 4, 2048, 2048
+    cfg = TransformerConfig(embed_dim=e, num_heads=heads, max_seq_len=t)
+    text = _adamw_step_text(Block(cfg), one_chip, b, t, e, cfg.dtype)
     assert text.count("tpu_custom_call") >= 3  # the flash kernels are in it
     windowed = [
         line.strip()[:200] for line in text.splitlines()
@@ -228,6 +233,42 @@ def test_grouped_and_windowed_flash_kernels_compile_for_v5e(one_chip, monkeypatc
         "flash_window_fwd", "flash_window_bwd_dq", "flash_window_bwd_dkv")
     for name in names:
         assert name in text
+
+
+@pytest.mark.parametrize("heads,window", GROUPED)
+def test_grouped_q_kernel_s_update_costs_what_out_s_does_on_v5e(one_chip, monkeypatch, heads, window):
+    """One Attention layer + AdamW at the sparse cell's widths (E 2048, heads
+    of 128 over 8 KV heads, window 512, gate, batch 1 x 8192). The q and out
+    kernels' updates are the same arithmetic over the same bytes. Left to
+    nn.DenseGeneral, q's was one fusion of a head-windowed convolution, three
+    transposing copies and AdamW writing head-major, with three copies of
+    ``f32[2048, H, 128]`` behind it: 2.18 times out's by the compiler's
+    estimate, 21.0 ms a step against 8.4 on the chip (PERF.md, PR 33)."""
+    from katib_tpu.models.transformer import Attention, LayerConfig, TransformerConfig
+    from katib_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    e, d, t = 2048, 128, 8192
+    cfg = TransformerConfig(
+        embed_dim=e, num_heads=heads, head_size=d, num_kv_heads=8, max_seq_len=t, window=window,
+        attention_gate=True)
+    layer = Attention(
+        cfg, layer=LayerConfig(attention="sliding" if window else "full", num_heads=heads))
+    text = _adamw_step_text(layer, one_chip, 1, t, e, cfg.dtype)
+    assert text.count("tpu_custom_call") == 3
+    ranked = entry_ops_by_estimated_cycles(text)
+    # every instruction behind the forward pass that makes an array of the q
+    # kernel's shape: its weight gradient, its AdamW (fused with it or apart),
+    # every copy or turn of the kernel, a moment or the gradient
+    kernel_shaped = re.compile(rf"\w+\[{e},{heads},{d}\]")
+    q_update = [r for r in ranked if kernel_shaped.search(r[2]) and "/jvp(" not in r[3]]
+    q_grad = [r for r in q_update if "transpose(jvp" in r[3] and "/qkv/q/" in r[3]]
+    out_update = [r for r in ranked if "transpose(jvp" in r[3] and "/out/" in r[3]
+                  and re.search(rf"f32\[{heads},{d},{e}\]", r[2])]
+    assert q_grad and len(out_update) == 1, ranked[:12]
+    assert any(r[2].lstrip("(").startswith("f32[") for r in q_update), q_update   # AdamW is among them
+    ratio = sum(r[0] for r in q_update) / out_update[0][0]
+    assert ratio <= 1.6, (ratio, q_update, out_update)
 
 
 def test_grouped_expert_products_compile_for_v5e(one_chip, monkeypatch):
