@@ -13,7 +13,9 @@ tile feeding the MXU, and the online-softmax recurrence
 accumulates the output in fp32 scratch. The backward pass is the standard
 two-kernel recomputation (dQ with KV innermost; dK/dV with Q innermost) from
 the saved logsumexp — no attention matrix is ever materialized in either
-direction.
+direction. A causal band (``window=``) has kernels of its own, with no grid
+axis over the keys and no scratch: a grid step holds all the keys its rows
+can see ("Windowed kernels" below).
 
 Sequence-sharded attention is handled by katib_tpu.ops.ring_attention (the
 ring schedule rotates K/V between devices); this kernel is the within-device
@@ -28,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Optional, Tuple
 
 import jax
@@ -71,56 +74,20 @@ def _use_kernel(interpret: Optional[bool]) -> bool:
 # kernel walks the group's query heads in its innermost grid axis and sums.
 #
 # Window: key ``s`` is visible to query ``t`` iff ``0 <= t - s < window``. The
-# innermost grid axis then runs over the blocks of the band only: its length is
-# the most blocks any outer block's band touches, step ``j`` is block
-# ``first(outer) + j``, and a step past the band's end is skipped (its block
-# index is clamped, so nothing is fetched for it). Blocks wholly outside the
-# band are never computed. With ``window=None`` and ``group == 1`` every index
-# map and kernel body below is the plain causal one.
+# windowed kernels (further down, under names of their own) have no grid axis
+# over the band: one grid step holds the whole band of its outer block, every
+# block of it an operand of its own, and computes only the sub-tiles the band
+# touches. With ``window=None`` every index map and kernel body up to there is
+# the plain causal one, over a group of query heads where ``group > 1``.
 
-def _first_kv_block(qi, window, block_q, block_k):
-    """First kv block that the band of q block ``qi`` touches."""
-    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
-
-
-def _band_kv_steps(t, window, block_q, block_k) -> int:
-    """Most kv blocks a q block's band touches: keys qi*bq - window + 1 .. qi*bq + bq - 1."""
-    return max(
-        (qi * block_q + block_q - 1) // block_k - max(qi * block_q - (window - 1), 0) // block_k + 1
-        for qi in range(t // block_q)
-    )
-
-
-def _first_q_block(ki, block_q, block_k):
-    """First q block that sees kv block ``ki`` (causal: the one holding its first key)."""
-    return (ki * block_k) // block_q
-
-
-def _band_q_steps(t, window, block_q, block_k) -> int:
-    """Most q blocks that see one kv block: queries ki*bk .. ki*bk + bk + window - 2."""
-    last = t // block_q - 1
-    return max(
-        min((ki * block_k + block_k + window - 2) // block_q, last) - (ki * block_k) // block_q + 1
-        for ki in range(t // block_k)
-    )
-
-
-def _mask_scores(s, q0, k0, causal, window):
+def _mask_scores(s, q0, k0, causal):
     """Scores of the block at rows ``q0``.., columns ``k0``.. with the keys a
     query may not see set to NEG_INF."""
-    if not causal and window is None:
+    if not causal:
         return s
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    hidden = k_pos > q_pos
-    if window is not None:
-        hidden = hidden | (q_pos - k_pos >= window)
-    return jnp.where(hidden, NEG_INF, s)
-
-
-def _kernel_name(base: str, window) -> str:
-    """Windowed calls carry their own names, so a trace tells them apart."""
-    return base if window is None else base.replace("flash_", "flash_window_", 1)
+    return jnp.where(k_pos > q_pos, NEG_INF, s)
 
 
 # ---------------------------------------------------------------------------
@@ -129,13 +96,11 @@ def _kernel_name(base: str, window) -> str:
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, causal: bool, sm_scale: float, block_q: int, block_k: int,
-                kv_steps: int, window: Optional[int] = None):
+                kv_steps: int):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    if window is not None:  # the grid's last axis walks the band's blocks
-        ki = _first_kv_block(qi, window, block_q, block_k) + ki
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -143,7 +108,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: skip blocks strictly above the diagonal (in a band: past its end).
+    # Causal: skip blocks strictly above the diagonal.
     run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
 
     @pl.when(run)
@@ -160,11 +125,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
             precision=prec,
         ) * sm_scale                                # [bq, bk] f32
-        s = _mask_scores(s, qi * block_q, ki * block_k, causal, window)
+        s = _mask_scores(s, qi * block_q, ki * block_k, causal)
 
-        # a row whose keys in this block are all hidden (a band's first block)
-        # accumulates p = 1 here; the block that holds its diagonal follows,
-        # and alpha = exp(NEG_INF - m) = 0 wipes that out
         m_prev = m_ref[:, 0:1]                      # [bq, 1]
         l_prev = l_ref[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -186,19 +148,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = m_ref[:, 0:1] + jnp.log(jnp.maximum(l, 1e-30))
 
 
-def _kv_index_maps(t, block_q, block_k, window, group):
-    """(kv_steps, index map of a k/v block) for the grids whose last axis
-    walks kv blocks (forward, dq): grid ``(b, q block, step)``."""
-    if window is None:
-        if group == 1:
-            return t // block_k, lambda b, i, j: (b, j, 0)
-        return t // block_k, lambda b, i, j: (b // group, j, 0)
-    last = t // block_k - 1
-
-    def kv_index(b, i, j):
-        return (b // group, jnp.minimum(_first_kv_block(i, window, block_q, block_k) + j, last), 0)
-
-    return _band_kv_steps(t, window, block_q, block_k), kv_index
+def _kv_index_map(group):
+    """Index map of a k/v block for the grids whose last axis walks kv blocks
+    (forward, dq): grid ``(b, q block, kv block)``."""
+    if group == 1:
+        return lambda b, i, j: (b, j, 0)
+    return lambda b, i, j: (b // group, j, 0)
 
 
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, group=1):
@@ -206,12 +161,14 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, gr
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if window is not None:
+        return _window_fwd(q, k, v, sm_scale, block_q, block_k, interpret, window, group)
     bh, t, d = q.shape
-    kv_steps, kv_index = _kv_index_maps(t, block_q, block_k, window, group)
+    kv_steps, kv_index = t // block_k, _kv_index_map(group)
     grid = (bh, t // block_q, kv_steps)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, kv_steps=kv_steps, window=window,
+        block_q=block_q, block_k=block_k, kv_steps=kv_steps,
     )
     return pl.pallas_call(
         kernel,
@@ -238,7 +195,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, gr
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window),
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -246,9 +203,9 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None, gr
 # Backward kernels (recompute from saved logsumexp)
 # ---------------------------------------------------------------------------
 
-def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, causal, sm_scale,
-                    block_q, block_k, window=None):
-    """Shared bwd block math: p [bq,bk] and ds [bq,bk] (pre-scaled, f32).
+def _recompute_p_ds(q, k, v, do, lse, delta, mask, sm_scale):
+    """Shared bwd block math: p [bq,bk] and ds [bq,bk] (pre-scaled, f32);
+    ``mask`` hides the scores a query may not see.
 
     Dots take the blocks in their native dtype (bf16 MXU rate) and accumulate
     f32; only the elementwise recurrence is f32.
@@ -258,7 +215,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, causal, sm_scale,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         precision=prec,
     ) * sm_scale
-    s = _mask_scores(s, qi * block_q, ki * block_k, causal, window)
+    s = mask(s)
     p = jnp.exp(s - lse)                            # lse [bq, 1] broadcasts
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
@@ -269,14 +226,11 @@ def _recompute_p_ds(q, k, v, do, lse, delta, qi, ki, causal, sm_scale,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, causal, sm_scale, block_q, block_k, kv_steps,
-                   window=None):
+                   dq_acc, *, causal, sm_scale, block_q, block_k, kv_steps):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    if window is not None:
-        ki = _first_kv_block(qi, window, block_q, block_k) + ki
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -291,8 +245,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         v = v_ref[0]
         do = do_ref[0]
         _, ds = _recompute_p_ds(
-            q, k, v, do, lse_ref[0], delta_ref[0], qi, ki, causal, sm_scale,
-            block_q, block_k, window,
+            q, k, v, do, lse_ref[0], delta_ref[0],
+            lambda s: _mask_scores(s, qi * block_q, ki * block_k, causal), sm_scale,
         )
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -306,17 +260,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, causal, sm_scale, block_q, block_k, q_steps,
-                    window=None, group=1, q_blocks=None):
+                    *, causal, sm_scale, block_q, block_k, q_steps, group=1):
     """One kv block of one KV head: the innermost grid axis walks the q
-    blocks that see it, of every query head of the group in turn."""
+    blocks, of every query head of the group in turn."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     step = pl.program_id(2)
     qi = step if group == 1 else step % q_steps
-    if window is not None:
-        qi = _first_q_block(ki, block_q, block_k) + qi
 
     @pl.when(step == 0)
     def _init():
@@ -324,8 +275,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-    if window is not None:  # not past the band's end, nor past the last block
-        run = run & (qi * block_q <= ki * block_k + block_k + window - 2) & (qi < q_blocks)
 
     @pl.when(run)
     def _step():
@@ -334,8 +283,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v = v_ref[0]
         do = do_ref[0]
         p, ds = _recompute_p_ds(
-            q, k, v, do, lse_ref[0], delta_ref[0], qi, ki, causal, sm_scale,
-            block_q, block_k, window,
+            q, k, v, do, lse_ref[0], delta_ref[0],
+            lambda s: _mask_scores(s, qi * block_q, ki * block_k, causal), sm_scale,
         )
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -363,15 +312,19 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret,
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BH, T, 1]
 
+    if window is not None:
+        return _window_bwd(q, k, v, do, lse, delta, sm_scale, block_q, block_k,
+                           interpret, window, group)
+
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    kv_steps, kv_index = _kv_index_maps(t, block_q, block_k, window, group)
-    kv_spec_dq = pl.BlockSpec((1, block_k, d), kv_index)
+    kv_steps = t // block_k
+    kv_spec_dq = pl.BlockSpec((1, block_k, d), _kv_index_map(group))
 
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, kv_steps=kv_steps, window=window,
+            block_q=block_q, block_k=block_k, kv_steps=kv_steps,
         ),
         grid=(bh, t // block_q, kv_steps),
         in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec],
@@ -382,26 +335,18 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", window),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid iterates q blocks innermost for a fixed kv block (of every
     # query head of the group: the sum over the group is this kernel's).
-    q_blocks = t // block_q
-    if window is None:
-        q_steps = q_blocks
-        if group == 1:
-            def q_index(b, i, j):
-                return (b, j, 0)
-        else:
-            def q_index(b, i, j):
-                return (b * group + j // q_steps, j % q_steps, 0)
-    else:
-        q_steps = _band_q_steps(t, window, block_q, block_k)
-
+    q_steps = t // block_q
+    if group == 1:
         def q_index(b, i, j):
-            qi = jnp.minimum(_first_q_block(i, block_q, block_k) + j % q_steps, q_blocks - 1)
-            return (b * group + j // q_steps, qi, 0)
+            return (b, j, 0)
+    else:
+        def q_index(b, i, j):
+            return (b * group + j // q_steps, j % q_steps, 0)
 
     q_spec2 = pl.BlockSpec((1, block_q, d), q_index)
     kv_spec2 = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
@@ -410,8 +355,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret,
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, q_steps=q_steps,
-            window=window, group=group, q_blocks=q_blocks,
+            block_q=block_q, block_k=block_k, q_steps=q_steps, group=group,
         ),
         grid=(bkv, t // block_k, group * q_steps),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
@@ -428,8 +372,356 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name=_kernel_name("flash_bwd_dkv", window),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
+# Windowed kernels: one grid step holds its block's whole band
+# ---------------------------------------------------------------------------
+#
+# Forward and dq: grid ``(b, q block)``. The kv blocks a q block's band can touch
+# are so many operands of the step (the same array under one BlockSpec each),
+# the last of them the block that holds the q block's last row; one that would
+# lie before the sequence is clamped to block 0 and hidden by position. dk/dv
+# the other way round: grid ``(b, kv block, query head of the group)``, the q, do,
+# lse and delta blocks that see the kv block as operands, the first of them the
+# block that holds the kv block's first key; one past the sequence's end is
+# clamped to the last block and hidden. Each block is fetched once a step.
+#
+# Inside a step the outer block is walked in chunks of WINDOW_CHUNK rows (keys,
+# for dk/dv), unrolled at trace time, each in one pass over its scores: the
+# forward keeps its running maximum, sum and output in values, from one kv block
+# to the next of a chunk, and writes each row once; no scratch, no first or
+# last step. Where the outer block is a whole number of inner blocks, the operands lie
+# at static offsets from a chunk, and a chunk takes static slices of them: the
+# aligned range its band touches and no more, cut where the band's two edges can
+# fall, so that only the edge pieces are masked (``_band_plan``). Where it is not
+# (unequal blocks that do not divide), a chunk takes every operand whole and
+# masks by position.
+
+# Rows (dk/dv: keys) of a block computed in one pass. At a window of 512 a chunk of 128 computes 640
+# columns for the 512 its rows see, one of 256 computes 768. Measured on a v5e at the sparse cell's
+# sliding layer (64 heads over 8, T 8192, heads of 128, window 512, bfloat16; ms a call forward /
+# dq / dk/dv, q and kv blocks alike, PERF.md PR 36): the stepping grid 4.76 / 2.83 / 3.47; chunks of
+# 128 in blocks of 512 2.00 / 1.88 / 2.56, of 1,024 1.75 / 1.70 / 2.34; chunks of 256 in blocks of
+# 512 1.99 / 1.89 / 3.16, of 1,024 1.76 / 1.74 / 3.10.
+WINDOW_CHUNK = 128
+
+
+def _band_kv_blocks(t, window, block_q, block_k) -> int:
+    """Most kv blocks a q block's band touches: keys qi*bq - window + 1 .. qi*bq + bq - 1."""
+    return max(
+        (qi * block_q + block_q - 1) // block_k - max(qi * block_q - (window - 1), 0) // block_k + 1
+        for qi in range(t // block_q)
+    )
+
+
+def _band_q_blocks(t, window, block_q, block_k) -> int:
+    """Most q blocks that see one kv block: queries ki*bk .. ki*bk + bk + window - 2."""
+    last = t // block_q - 1
+    return max(
+        min((ki * block_k + block_k + window - 2) // block_q, last) - (ki * block_k) // block_q + 1
+        for ki in range(t // block_k)
+    )
+
+
+def _band_plan(block_out, block_in, count, window, keys_outer):
+    """The static walk of an outer block over its ``count`` operands of
+    ``block_in``: for each chunk the pieces ``(operand, start, stop, gap0)`` to
+    compute, ``gap0`` the distance from the piece's first key back to its first
+    query. ``keys_outer``: the outer block holds keys and the operands the
+    queries that see them (dk/dv); else queries and their keys. None where the
+    operands' offsets from the block are not static."""
+    if block_out % block_in:
+        return None
+    chunk = math.gcd(block_out, WINDOW_CHUNK)
+    align = math.gcd(chunk, block_in)
+    base = 0 if keys_outer else block_out - count * block_in  # operand 0, from the outer block's start
+    plan = []
+    for a0 in range(0, block_out, chunk):
+        a1 = a0 + chunk
+        if keys_outer:  # queries a0 .. a1 + window - 2 see a key of the chunk, a1 - 1 .. a0 + window - 1 all
+            lo, hi, all_lo, all_hi = a0, a1 + window - 1, a1 - 1, a0 + window
+        else:           # keys a0 - window + 1 .. a1 - 1 are seen by a row of the chunk, a1 - window .. a0 by all
+            lo, hi, all_lo, all_hi = a0 - window + 1, a1, a1 - window, a0 + 1
+        lo, all_hi = (x // align * align for x in (lo, all_hi))
+        hi, all_lo = (-(-x // align) * align for x in (hi, all_lo))
+        lo, hi = max(lo, base), min(hi, base + count * block_in)
+        cuts = {lo, hi} | {base + j * block_in for j in range(count)}
+        if all_lo < all_hi:
+            cuts |= {all_lo, all_hi}
+        cuts = sorted(c for c in cuts if lo <= c <= hi)
+        pieces = []
+        for start, stop in zip(cuts, cuts[1:]):
+            j = (start - base) // block_in
+            at = start - base - j * block_in
+            pieces.append((j, at, at + stop - start, start - a0 if keys_outer else a0 - start))
+        plan.append(pieces)
+    return plan
+
+
+def _mask_band(s, gap0, window, outside=None):
+    """Scores whose entry ``[i, j]`` is of a key ``gap0 + i - j`` places behind
+    its query, NEG_INF where that is not within ``0 .. window - 1`` or where
+    ``outside`` (a row or a column of flags) says so. A static ``gap0`` drops
+    the compare that no entry of the tile can meet, and the tile comes back as
+    it is when none can."""
+    rows, cols = s.shape
+    static = isinstance(gap0, int)
+    ahead = not static or gap0 - (cols - 1) < 0
+    behind = not static or gap0 + rows - 1 >= window
+    if not (ahead or behind):
+        return s if outside is None else jnp.where(outside, NEG_INF, s)
+    gap = gap0 + (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                  - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    hidden = (gap < 0) | (gap >= window) if ahead and behind else gap < 0 if ahead else gap >= window
+    return jnp.where(hidden if outside is None else hidden | outside, NEG_INF, s)
+
+
+def _walk_band(body, plan, block_out, block_in, count, outer0, t, keys_outer):
+    """Run ``body(a0, chunk, pieces)`` for every chunk of the outer block that
+    starts at position ``outer0``; a piece is ``(operand, start, stop, gap0,
+    outside)``."""
+    from jax.experimental import pallas as pl
+
+    chunk = math.gcd(block_out, WINDOW_CHUNK)
+    # where operand 0 stands before it is clamped into the sequence: the block of the outer block's
+    # first key (dk/dv), or count - 1 blocks before the block of its last row
+    first0 = (outer0 // block_in if keys_outer
+              else (outer0 + block_out - 1) // block_in - (count - 1)) * block_in
+
+    def outside(j, lo, hi):
+        shape, axis = ((hi - lo, 1), 0) if keys_outer else ((1, hi - lo), 1)
+        pos = first0 + j * block_in + lo + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        return (pos < 0) | (pos >= t)
+
+    def walk(pieces_of):
+        for a0 in range(0, block_out, chunk):
+            body(a0, chunk, pieces_of(a0))
+
+    if plan is None:
+        def whole(a0):
+            gap = outer0 + a0 - first0
+            return [(j, 0, block_in, j * block_in - gap if keys_outer else gap - j * block_in,
+                     outside(j, 0, block_in)) for j in range(count)]
+
+        walk(whole)
+        return
+    clamped = (first0 < 0) | (first0 + count * block_in > t)
+    pl.when(clamped)(lambda: walk(
+        lambda a0: [(j, lo, hi, gap0, outside(j, lo, hi)) for j, lo, hi, gap0 in plan[a0 // chunk]]))
+    pl.when(jnp.logical_not(clamped))(lambda: walk(
+        lambda a0: [piece + (None,) for piece in plan[a0 // chunk]]))
+
+
+def _window_fwd_kernel(q_ref, *refs, sm_scale, block_q, block_k, window, count, plan, t):
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs, (o_ref, lse_ref) = refs[:count], refs[count:2 * count], refs[2 * count:]
+    q0 = pl.program_id(1) * block_q
+    prec = _dot_precision(q_ref.dtype)
+
+    def rows_in_one_pass(a0, chunk, pieces):
+        rows = slice(a0, a0 + chunk)
+        q = q_ref[0, rows, :]
+        scores = []
+        for j, lo, hi, gap0, outside in pieces:
+            s = jax.lax.dot_general(
+                q, k_refs[j][0, lo:hi, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            ) * sm_scale
+            scores.append(_mask_band(s, gap0, window, outside))
+        # The kv blocks in turn under a running maximum, held in values: a block's probabilities
+        # are rounded relative to the maximum up to and with that block, as the stepping grid's
+        # were (_window_kv_block says why that is kept). A row that sees nothing of a block takes
+        # p = 1 there until a later block's alpha = exp(NEG_INF - m) = 0 wipes it out; every row
+        # sees its own key, in the last block, so m ends as a score and l at least 1.
+        m = l = acc = None
+        for operand in sorted({piece[0] for piece in pieces}):
+            mine = [(s, piece) for s, piece in zip(scores, pieces) if piece[0] == operand]
+            m_new = functools.reduce(
+                jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s, _ in mine], *([] if m is None else [m]))
+            ps = [jnp.exp(s - m_new) for s, _ in mine]
+            l_new = functools.reduce(operator.add, [jnp.sum(p, axis=-1, keepdims=True) for p in ps])
+            acc_new = functools.reduce(operator.add, [
+                jax.lax.dot_general(
+                    p.astype(o_ref.dtype), v_refs[j][0, lo:hi, :], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=prec,
+                ) for p, (_, (j, lo, hi, _, _)) in zip(ps, mine)])
+            if m is not None:
+                alpha = jnp.exp(m - m_new)
+                l_new, acc_new = l * alpha + l_new, acc * alpha + acc_new
+            m, l, acc = m_new, l_new, acc_new
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)
+
+    _walk_band(rows_in_one_pass, plan, block_q, block_k, count, q0, t, False)
+
+
+def _window_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, *refs,
+                      sm_scale, block_q, block_k, window, count, plan, t):
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs, dq_ref = refs[:count], refs[count:2 * count], refs[2 * count]
+    q0 = pl.program_id(1) * block_q
+
+    def rows_in_one_pass(a0, chunk, pieces):
+        rows = slice(a0, a0 + chunk)
+        q, do, lse, delta = (ref[0, rows, :] for ref in (q_ref, do_ref, lse_ref, delta_ref))
+        parts = []
+        for j, lo, hi, gap0, outside in pieces:
+            k, v = k_refs[j][0, lo:hi, :], v_refs[j][0, lo:hi, :]
+            _, ds = _recompute_p_ds(
+                q, k, v, do, lse, delta,
+                functools.partial(_mask_band, gap0=gap0, window=window, outside=outside), sm_scale)
+            parts.append(jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_dot_precision(k.dtype),
+            ))
+        dq_ref[0, rows, :] = functools.reduce(operator.add, parts).astype(dq_ref.dtype)
+
+    _walk_band(rows_in_one_pass, plan, block_q, block_k, count, q0, t, False)
+
+
+def _window_dkv_kernel(k_ref, v_ref, *refs, sm_scale, block_q, block_k, window, count, plan, t, group):
+    """One kv block under one query head of its group; dk and dv are summed
+    over the group's heads in scratch and written at the last."""
+    from jax.experimental import pallas as pl
+
+    q_refs, do_refs, lse_refs, delta_refs = (refs[n * count:(n + 1) * count] for n in range(4))
+    dk_ref, dv_ref, dk_acc, dv_acc = refs[4 * count:]
+    k0 = pl.program_id(1) * block_k
+    head = pl.program_id(2)
+
+    @pl.when(head == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def keys_in_one_pass(a0, chunk, pieces):
+        keys = slice(a0, a0 + chunk)
+        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+        dks, dvs = [], []
+        for j, lo, hi, gap0, outside in pieces:
+            q, do, lse, delta = (
+                ref[j][0, lo:hi, :] for ref in (q_refs, do_refs, lse_refs, delta_refs))
+            p, ds = _recompute_p_ds(
+                q, k, v, do, lse, delta,
+                functools.partial(_mask_band, gap0=gap0, window=window, outside=outside), sm_scale)
+            dvs.append(jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_dot_precision(do.dtype),
+            ))
+            dks.append(jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_dot_precision(q.dtype),
+            ))
+        dk_acc[keys, :] += functools.reduce(operator.add, dks)
+        dv_acc[keys, :] += functools.reduce(operator.add, dvs)
+
+    _walk_band(keys_in_one_pass, plan, block_k, block_q, count, k0, t, True)
+
+    @pl.when(head == group - 1)
+    def _finish():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _band_kv_specs(t, d, block_q, block_k, window, group):
+    """(count, one BlockSpec a kv block of a q block's band) for grid ``(b, q block)``."""
+    from jax.experimental import pallas as pl
+
+    count = _band_kv_blocks(t, window, block_q, block_k)
+
+    def kv_index(b, i, *, j):
+        return (b // group, jnp.maximum((i * block_q + block_q - 1) // block_k - (count - 1) + j, 0), 0)
+
+    return count, [pl.BlockSpec((1, block_k, d), functools.partial(kv_index, j=j)) for j in range(count)]
+
+
+def _window_fwd(q, k, v, sm_scale, block_q, block_k, interpret, window, group):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t, d = q.shape
+    count, kv_specs = _band_kv_specs(t, d, block_q, block_k, window, group)
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _window_fwd_kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, window=window,
+            count=count, plan=_band_plan(block_q, block_k, count, window, False), t=t,
+        ),
+        grid=(bh, t // block_q),
+        in_specs=[q_spec] + kv_specs + kv_specs,
+        out_specs=[q_spec, pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="flash_window_fwd",
+    )(q, *[k] * count, *[v] * count)
+
+
+def _window_bwd(q, k, v, do, lse, delta, sm_scale, block_q, block_k, interpret, window, group):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t, d = q.shape
+    bkv = k.shape[0]
+    count, kv_specs = _band_kv_specs(t, d, block_q, block_k, window, group)
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
+    dq = pl.pallas_call(
+        functools.partial(
+            _window_dq_kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, window=window,
+            count=count, plan=_band_plan(block_q, block_k, count, window, False), t=t,
+        ),
+        grid=(bh, t // block_q),
+        in_specs=[q_spec, q_spec, row_spec, row_spec] + kv_specs + kv_specs,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="flash_window_bwd_dq",
+    )(q, do, lse, delta, *[k] * count, *[v] * count)
+
+    if block_q % block_k == 0:  # dk/dv's walk is static only over kv blocks of whole q blocks
+        block_k = block_q
+    count = _band_q_blocks(t, window, block_q, block_k)
+    last = t // block_q - 1
+
+    def q_index(b, i, g, *, j):
+        return (b * group + g, jnp.minimum(i * block_k // block_q + j, last), 0)
+
+    q_specs = [pl.BlockSpec((1, block_q, d), functools.partial(q_index, j=j)) for j in range(count)]
+    row_specs = [pl.BlockSpec((1, block_q, 1), functools.partial(q_index, j=j)) for j in range(count)]
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, g: (b, i, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _window_dkv_kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, window=window,
+            count=count, plan=_band_plan(block_k, block_q, count, window, True), t=t, group=group,
+        ),
+        grid=(bkv, t // block_k, group),
+        in_specs=[kv_spec, kv_spec] + q_specs + q_specs + row_specs + row_specs,
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((bkv, t, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, t, d), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="flash_window_bwd_dkv",
+    )(k, v, *[q] * count, *[do] * count, *[lse] * count, *[delta] * count)
     return dq, dk, dv
 
 
@@ -454,7 +746,8 @@ def _flash_bhtd_bwd(causal, sm_scale, block_q, block_k, interpret, window, group
     # (T=2048 d=64 causal), fwd+bwd with the forward's asymmetric bq=512
     # runs 10% SLOWER than bq=bk=1024 despite the faster forward — so bwd
     # blocks are chosen independently of the forward's (BWD_BLOCK_CAP). A
-    # windowed call keeps the forward's: they were cut to the band already.
+    # windowed call keeps the forward's: _window_block sized them for the
+    # dk/dv step, the widest of the three.
     t = q.shape[1]
     bwd_block = None if window is not None else _auto_block(t, BWD_BLOCK_CAP)
     bq = bwd_block or block_q
@@ -508,8 +801,42 @@ def _dense_grouped(q, k, v, causal, window, sm_scale):
     return o.reshape(b, t, h, d).astype(q.dtype)
 
 
-WINDOW_BLOCK_CAP = 512  # windowed calls, all three kernels: tiles no wider than
-                        # the band is deep, so most of a tile lies inside it
+WINDOW_BLOCK_CAP = 1024  # windowed calls, all three kernels: a chunk computes the same columns in any block,
+                         # and a block of 1,024 halves the grid steps of one of 512 (WINDOW_CHUNK's note)
+WINDOW_VMEM_BUDGET = 12 * 2**20  # for a step's operands, of the 16 MiB a kernel may scope on a v5e
+WINDOW_KV_BLOCK = 512  # forward and dq take the band in kv blocks of so many keys (_window_kv_block)
+
+
+def _window_block(t: int, window: int, d: int, itemsize: int) -> Optional[int]:
+    """The q block of a windowed call (and dk/dv's kv block): the largest
+    multiple of 128 that divides t, is no wider than WINDOW_BLOCK_CAP, and
+    lets the widest step fit WINDOW_VMEM_BUDGET. That step is dk/dv's: the
+    band's q, do, lse and delta blocks (a row statistic's block is padded to
+    128 lanes) beside k, v, dk and dv, all double-buffered, and two float32
+    accumulators. None where no block fits — at heads of 128 in bfloat16 a
+    window of some 3,900 keys — and the caller computes dense."""
+    for b in range(min(WINDOW_BLOCK_CAP, t) // 128 * 128, 127, -128):
+        if t % b:
+            continue
+        band = _band_q_blocks(t, window, b, b) * 2 * b * (d * itemsize + _LANES * 4)
+        if 2 * (band + 4 * b * d * itemsize) + 2 * b * d * 4 <= WINDOW_VMEM_BUDGET:
+            return b
+    return None
+
+
+def _window_kv_block(block: int) -> int:
+    """The kv block of a windowed call whose q block is ``block``: WINDOW_KV_BLOCK
+    where that divides it. The forward rounds a kv block's probabilities to the
+    operands' type relative to the running maximum up to that block, so the
+    block's width is part of the result. At 512 the forward's ``o`` and ``lse``
+    are the stepping kernels' in all but 2 of 10,000 elements, and the sparse
+    cell's loss after three steps at a rate of 1.13e-3, where the trial is on
+    the edge of diverging, stays 1.0e-2 from the float32 reference's; one
+    maximum over all a chunk's keys reads 1.8e-2 there and the running maximum
+    over blocks of 1,024 keys 2.05e-2, for the same time a call (PERF.md,
+    PR 36). dk/dv, whose walk is static only over kv blocks of whole q blocks,
+    takes kv blocks as wide as the q block (_window_bwd)."""
+    return WINDOW_KV_BLOCK if block % WINDOW_KV_BLOCK == 0 else block
 
 
 def flash_attention(
@@ -528,7 +855,14 @@ def flash_attention(
     ``k`` and ``v`` may carry fewer heads, [B, T, KV, D] with H a multiple of
     KV: query head ``j`` reads KV head ``j // (H // KV)``. ``window`` (with
     ``causal``) hides every key more than ``window - 1`` positions behind its
-    query; blocks outside the band are not computed.
+    query. A windowed call runs kernels of its own ("Windowed kernels" above):
+    a grid step holds a block's whole band and computes, a chunk of
+    WINDOW_CHUNK rows at a time, the aligned columns that chunk's band
+    touches — 640 for the 512 its rows see at a window of 512, where the
+    stepping grid this replaced computed 1,024. Its q blocks are
+    ``_window_block``'s, forward and backward, its kv blocks
+    ``_window_kv_block``'s; a band too wide for a step's VMEM (some 3,900
+    keys at bfloat16 heads of 128) is computed dense.
 
     Differentiable (custom VJP, recompute-based backward). Forward block
     sizes default to the largest dividing multiple of 128, asymmetric
@@ -550,10 +884,13 @@ def flash_attention(
             raise ValueError("a window is a causal band of at least one key")
         if window >= t:
             window = None  # the band is the causal half
-    q_cap, k_cap = (FWD_BLOCK_Q_CAP, FWD_BLOCK_K_CAP) if window is None else (
-        max(128, min(WINDOW_BLOCK_CAP, window)),) * 2
-    block_q = min(block_q, t) if block_q else (_auto_block(t, q_cap) or t + 1)
-    block_k = min(block_k, t) if block_k else (_auto_block(t, k_cap) or t + 1)
+    if window is None:
+        auto_q, auto_k = _auto_block(t, FWD_BLOCK_Q_CAP), _auto_block(t, FWD_BLOCK_K_CAP)
+    else:
+        auto_q = _window_block(t, window, d, q.dtype.itemsize)
+        auto_k = auto_q and _window_kv_block(auto_q)
+    block_q = min(block_q, t) if block_q else (auto_q or t + 1)
+    block_k = min(block_k, t) if block_k else (auto_k or t + 1)
 
     def dense_fallback():
         if group > 1 or window is not None:

@@ -1,7 +1,10 @@
 """The flash kernels with grouped KV heads and a window (Pallas interpret mode)
 against dense masked attention written out here: the forward pass and all three
 gradients, for groups of 1, 6 and 8 query heads a KV head, with no window, a
-window narrower than a block and one that spans blocks."""
+window narrower than a block, one as deep as a block (the sparse cell's ratio,
+where a chunk takes static slices of its band), one of two blocks and one that
+spans blocks; bfloat16 as the chip runs it; the first q block alone, whose band
+the start of the sequence cuts."""
 
 import math
 
@@ -35,39 +38,99 @@ def _inputs(group, kv_heads=2, t=256, d=64, seed=0):
     return q, k, v, do
 
 
-@pytest.mark.parametrize("window", [None, 48, 200])
-@pytest.mark.parametrize("group", [1, 6, 8])
-def test_kernels_agree_with_dense_masked_attention(group, window):
-    q, k, v, do = _inputs(group)
+# (forward, gradients): float32 as the file always held them; bfloat16 as read
+# off the parent's stepping kernels on these cases against _dense on the same
+# rounded inputs, 7.5e-3 and 2.8e-2 at most: the outputs' own rounding
+TOLERANCE = {jnp.float32: (2e-5, 5e-5), jnp.bfloat16: (1e-2, 3e-2)}
+
+
+def _agree_with_dense(group, window, blocks, dtype=jnp.float32, rows=None, kv_heads=2, t=256):
+    """Forward and the three gradients against _dense; ``rows``: of the first
+    so many rows alone (the others' cotangent is zero)."""
+    q, k, v, do = (x.astype(dtype) for x in _inputs(group, kv_heads=kv_heads, t=t))
+    if rows is not None:
+        do = do.at[:, rows:].set(0)
 
     def kernel(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, window=window, interpret=True,
-                                  block_q=64, block_k=64)
+                                  block_q=blocks[0], block_k=blocks[1])
+
+    def f32(x):
+        return x.astype(jnp.float32)
 
     o, pullback = jax.vjp(kernel, q, k, v)
-    o_ref, pullback_ref = jax.vjp(lambda q, k, v: _dense(q, k, v, window), q, k, v)
-    np.testing.assert_allclose(o, o_ref, atol=2e-5, rtol=2e-5)
-    for got, want, name in zip(pullback(do), pullback_ref(do), "qkv"):
-        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5, err_msg=f"d{name}")
+    o_ref, pullback_ref = jax.vjp(lambda q, k, v: _dense(q, k, v, window), f32(q), f32(k), f32(v))
+    fwd_tol, grad_tol = TOLERANCE[dtype]
+    np.testing.assert_allclose(f32(o)[:, :rows], o_ref[:, :rows], atol=fwd_tol, rtol=fwd_tol)
+    for got, want, name in zip(pullback(do), pullback_ref(f32(do)), "qkv"):
+        assert got.dtype == dtype
+        np.testing.assert_allclose(f32(got), want, atol=grad_tol, rtol=grad_tol, err_msg=f"d{name}")
 
 
-@pytest.mark.parametrize("blocks", [(128, 64), (64, 128)])
-def test_unequal_blocks_walk_the_same_band(blocks):
-    q, k, v, do = _inputs(6, kv_heads=1)
-    o, pullback = jax.vjp(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=True, window=100, interpret=True, block_q=blocks[0], block_k=blocks[1]), q, k, v)
-    o_ref, pullback_ref = jax.vjp(lambda q, k, v: _dense(q, k, v, 100), q, k, v)
-    np.testing.assert_allclose(o, o_ref, atol=2e-5, rtol=2e-5)
-    for got, want in zip(pullback(do), pullback_ref(do)):
-        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
+@pytest.mark.parametrize("group,window,dtype,rows", [
+    *[pytest.param(group, window, jnp.float32, None, id=f"{group}-{window}")
+      for window in (None, 48, 200) for group in (1, 6, 8)],
+    # the sparse cell's ratio, a window as deep as a block: blocks 1-3 take static slices
+    pytest.param(1, 64, jnp.float32, None, id="1-64-window-is-a-block"),
+    pytest.param(8, 64, jnp.float32, None, id="8-64-window-is-a-block"),
+    pytest.param(6, 128, jnp.float32, None, id="6-128-window-of-two-blocks"),
+    pytest.param(6, 16, jnp.float32, None, id="6-16-window-narrower-than-a-chunk"),
+    pytest.param(6, 64, jnp.bfloat16, None, id="6-64-bfloat16"),
+    pytest.param(1, 200, jnp.bfloat16, None, id="1-200-bfloat16"),
+    # the band cut by the start of the sequence
+    pytest.param(6, 64, jnp.float32, 64, id="6-64-first-q-block-alone"),
+    pytest.param(8, 200, jnp.float32, 64, id="8-200-first-q-block-alone"),
+])
+def test_kernels_agree_with_dense_masked_attention(group, window, dtype, rows):
+    _agree_with_dense(group, window, (64, 64), dtype, rows)
 
 
-def test_the_band_s_grid_is_as_long_as_the_band_not_as_the_sequence():
+@pytest.mark.parametrize("blocks,window,dtype,t", [
+    # q blocks of two kv blocks, as the chip runs it (1,024 over 512): forward and dq take three kv
+    # blocks in turn, dk/dv kv blocks as wide as the q block
+    pytest.param((128, 64), 100, jnp.float32, 256, id="blocks0"),
+    pytest.param((128, 64), 64, jnp.float32, 256, id="128x64-window-64"),
+    pytest.param((128, 64), 100, jnp.bfloat16, 256, id="128x64-bfloat16"),
+    # kv blocks of two q blocks: forward and dq take every operand whole, dk/dv static slices
+    pytest.param((64, 128), 100, jnp.float32, 256, id="blocks1"),
+    pytest.param((64, 128), 128, jnp.float32, 256, id="64x128-window-128"),
+    pytest.param((64, 128), 100, jnp.bfloat16, 256, id="64x128-bfloat16"),
+    # neither divides the other: all three take every operand whole
+    pytest.param((128, 192), 100, jnp.float32, 384, id="128x192-no-static-walk"),
+    pytest.param((256, 256), 48, jnp.float32, 256, id="one-block-holds-the-sequence"),
+])
+def test_unequal_blocks_walk_the_same_band(blocks, window, dtype, t):
+    _agree_with_dense(6, window, blocks, dtype, kv_heads=1, t=t)
+
+
+def _scores(t, window, block_q, block_k):
+    """(computed, visible) scores of the windowed forward: a q block whose band
+    reaches before the sequence computes what every other does."""
+    count = fa._band_kv_blocks(t, window, block_q, block_k)
+    plan = fa._band_plan(block_q, block_k, count, window, False)
+    chunk = block_q // len(plan)
+    computed = t // block_q * sum(chunk * (stop - start) for pieces in plan for _, start, stop, _ in pieces)
+    return computed, sum(min(row + 1, window) for row in range(t))
+
+
+def test_a_step_holds_the_band_and_computes_little_beyond_it():
     # T 8192, window 512, blocks of 512: two kv blocks a q block, two q blocks a kv block
-    assert fa._band_kv_steps(8192, 512, 512, 512) == 2
-    assert fa._band_q_steps(8192, 512, 512, 512) == 2
-    assert fa._band_kv_steps(8192, 512, 256, 256) == 3
-    assert fa._band_kv_steps(256, 48, 64, 64) == 2
+    assert fa._band_kv_blocks(8192, 512, 512, 512) == 2
+    assert fa._band_q_blocks(8192, 512, 512, 512) == 2
+    assert fa._band_kv_blocks(8192, 512, 256, 256) == 3
+    assert fa._band_kv_blocks(256, 48, 64, 64) == 2
+    # a chunk of 128 rows computes 640 columns for the 512 its rows see; the stepping grid computed 1,024
+    computed, visible = _scores(8192, 512, 512, 512)
+    assert 1.0 <= computed / visible <= 1.3
+    # every piece is a slice of one operand, and only pieces at the band's two edges are masked
+    for pieces in fa._band_plan(512, 512, 2, 512, False):
+        assert all(0 <= start < stop <= 512 and operand in (0, 1) for operand, start, stop, _ in pieces)
+        tiles = [jnp.zeros((128, stop - start)) for _, start, stop, _ in pieces]
+        masked = [fa._mask_band(tile, gap0, 512) is not tile for tile, (_, _, _, gap0) in zip(tiles, pieces)]
+        assert masked == [True] + [False] * (len(pieces) - 2) + [True], pieces
+    # q blocks of 64 under kv blocks of 128: forward and dq have no static walk, dk/dv has
+    assert fa._band_plan(64, 128, 2, 100, False) is None
+    assert fa._band_plan(128, 64, fa._band_q_blocks(256, 100, 64, 128), 100, True) is not None
 
 
 @pytest.mark.parametrize("group,window", [(1, None), (6, None), (8, 48)])

@@ -216,6 +216,25 @@ GROUPED = [
 ]
 
 
+def _kernel_grids(lowered_text):
+    """{kernel name: its grid} of every Pallas call in a lowered module: the
+    call's Mosaic body (MLIR bytecode, base64) names itself and carries the
+    grid as ``iteration_bounds``."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    grids = {}
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True  # the serialised dialect has a name of its own
+        for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)', lowered_text):
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+            name = re.search(r"module @([\w.]+)", asm).group(1)
+            bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>", asm).group(1)
+            grids[name] = tuple(int(n) for n in bounds.split(","))
+    return grids
+
+
 @pytest.mark.parametrize("heads,window", GROUPED)
 def test_grouped_and_windowed_flash_kernels_compile_for_v5e(one_chip, monkeypatch, heads, window):
     from katib_tpu.ops import flash_attention as fa
@@ -233,6 +252,12 @@ def test_grouped_and_windowed_flash_kernels_compile_for_v5e(one_chip, monkeypatc
         "flash_window_fwd", "flash_window_bwd_dq", "flash_window_bwd_dkv")
     for name in names:
         assert name in text
+    grids = _kernel_grids(text)
+    if window is None:  # the last axis walks the kv blocks (dk/dv: the q blocks of the group's six heads)
+        assert grids == {"flash_fwd": (48, 16, 8), "flash_bwd_dq": (48, 8, 8), "flash_bwd_dkv": (8, 8, 48)}
+    else:  # no axis over the band: a step holds it whole (dk/dv: one step a query head of the group)
+        assert grids == {"flash_window_fwd": (64, 8), "flash_window_bwd_dq": (64, 8),
+                         "flash_window_bwd_dkv": (8, 8, 8)}
 
 
 @pytest.mark.parametrize("heads,window", GROUPED)
