@@ -18,9 +18,8 @@ starts from, ``u_t = β_t (v_t - (Diag(α_t) S_{t-1})ᵀ k_t)`` gives
 
 with ``Q̃ = scale q e^{G}``, ``K̃ = k e^{G}`` and ``K_C = k e^{G_C - G}``. So
 ``U = U0 - W S_0`` with ``[W | U0] = (I + A)^{-1} Diag(β) [K̃ | V]``: all of it
-is computed for every chunk at once, in parallel (``_within_chunks``), and
-only ``U0 - W S``, ``Q̃ S + M U`` and the state's update walk the chunks in
-turn (the kernels).
+is computed for every chunk independently, in parallel, and only ``U0 - W S``,
+``Q̃ S + M U`` and the state's update walk the chunks in turn (the kernels).
 
 **Where a decay is exponentiated.** ``e^{G}``, ``e^{G_C - G}`` and the state's
 decay have exponents <= 0: they can underflow, as the true value does, and
@@ -38,8 +37,10 @@ Products take their operands in the inputs' dtype (bfloat16 on the chip, at
 the MXU's rate) and accumulate in float32; the state, the decays, ``U0`` and
 the triangular solve are float32.
 
-The kernels (Pallas, named for the device trace): ``kda_fwd`` walks the chunks
-of one head and writes ``o``; the backward recomputes ``_within_chunks`` under
+The kernels (Pallas, named for the device trace): ``kda_chunks`` computes the
+forward's parts, a block of chunks of one head a grid step, in VMEM;
+``kda_fwd`` walks the chunks of one head and writes ``o``; the backward
+computes the parts in XLA (``_within_chunks``, the kernel's reference) under
 ``jax.vjp``, walks the chunks again to write the state each chunk starts from
 (``kda_bwd_states``), walks them back (``kda_bwd_grads``) for the cotangents
 of ``Q̃, K_C, W, U0, M`` and the decay, and pulls those back through
@@ -111,6 +112,176 @@ def _within_chunks(q, k, v, g, beta, scale):
     w, u0 = solved[..., :dk], solved[..., dk:]
     return ((qf * decay).astype(dt), (kf * jnp.exp(last - cum)).astype(dt), w.astype(dt), u0,
             m.astype(dt), jnp.exp(last))
+
+
+# ---------------------------------------------------------------------------
+# Within a block of chunks (Pallas): the forward's parts, in VMEM
+# ---------------------------------------------------------------------------
+#
+# ``_within_chunks`` on one chunk's values, step for step: the same roundings
+# to the products' dtype, the same float32 decays, A and solve. What differs is
+# the order of float32 sums: the cumulative sum of g is taken by doubling, and
+# the solve is a product with the inverse of I + A.
+
+def _cumsum_rows(x):
+    """The cumulative sum of x [C, .] down its rows, by doubling."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    shift = 1
+    while shift < x.shape[0]:
+        x = x + jnp.where(row >= shift, pltpu.roll(x, shift, 0), 0.0)
+        shift *= 2
+    return x
+
+
+PACK = 128 // CHUNK  # chunks whose A and inverse sit side by side in the 128 lanes of a row
+
+
+def _side_by_side(x, y):
+    """x_j @ y_j for the chunks side by side in x, y [C, PACK * C]: one
+    product with the y_j on the diagonal of a [PACK * C, PACK * C] operand."""
+    c, width = x.shape
+    r = jax.lax.broadcasted_iota(jnp.int32, (width, width), 0)
+    l = jax.lax.broadcasted_iota(jnp.int32, (width, width), 1)
+    return _dot(x, jnp.where(r // c == l // c, jnp.concatenate([y] * (width // c), axis=0), 0.0))
+
+
+def _unit_lower_inverses(a):
+    """(I + a_j)^{-1} for the chunks side by side in ``a`` [C, PACK * C]
+    float32, each a_j zero on and above its diagonal. The diagonal blocks of
+    SUB are inverted as (I - N)(I + N²)(I + N⁴)(I + N⁸) (N is nilpotent of
+    index SUB); with ``F`` = their inverse times what lies below them,
+    nilpotent of index C / SUB, (I + a_j)^{-1} = (I + F)^{-1} times that
+    inverse, and (I + F)^{-1} is the same product over F's powers."""
+    c, width = a.shape
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, width), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (c, width), 1) % c
+    eye = jnp.where(t == s, 1.0, 0.0)
+
+    def neumann(n, index):  # (I + n)^{-1} for n nilpotent of index ``index``, a power of 2
+        inv, power = eye - n, n
+        for _ in range(int(math.log2(index)) - 1):
+            power = _side_by_side(power, power)
+            inv = inv + _side_by_side(inv, power)
+        return inv
+
+    within = t // SUB == s // SUB
+    inv = neumann(jnp.where(within, a, 0.0), SUB)
+    return _side_by_side(neumann(_side_by_side(inv, jnp.where(within, 0.0, a)), c // SUB), inv)
+
+
+def _chunks_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                   qt_ref, kc_ref, w_ref, u0_ref, m_ref, decay_ref, *, scale):
+    """A block of chunks of one head: ``*_ref`` [1, chunks * C, .] in (the
+    head's column of ``beta_ref`` [1, chunks * C, H] picked by the grid's
+    head); the parts out, [1, chunks, C, .]. PACK chunks a step, UNROLL
+    such steps a loop step."""
+    from jax.experimental import pallas as pl
+
+    c = CHUNK
+    dt = q_ref.dtype
+    head = pl.program_id(1)
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    tp = jax.lax.broadcasted_iota(jnp.int32, (c, PACK * c), 0)
+    sp = jax.lax.broadcasted_iota(jnp.int32, (c, PACK * c), 1)
+
+    def chunk(n):
+        """Chunk n's parts but W and U0, and what its solve takes."""
+        rows = pl.ds(pl.multiple_of(n * c, c), c)
+        kf = k_ref[0, rows, :].astype(jnp.float32)
+        qf = q_ref[0, rows, :].astype(jnp.float32) * scale
+        vf = v_ref[0, rows, :].astype(jnp.float32)
+        by_head = beta_ref[0, rows, :]
+        pick = jax.lax.broadcasted_iota(jnp.int32, by_head.shape, 1) == head
+        beta = jnp.sum(jnp.where(pick, by_head, 0.0), axis=1, keepdims=True)     # [C, 1]
+        cum = _cumsum_rows(g_ref[0, rows, :])                                    # G, <= 0
+        k_rows, k_cols, m_rows = [], [], []
+        for sub in range(c // SUB):
+            here = slice(sub * SUB, (sub + 1) * SUB)
+            first = cum[sub * SUB:sub * SUB + 1]                                 # G_r
+            row = jnp.exp(cum[here] - first)                                     # e^{G_t - G_r}
+            k_col = (kf * jnp.exp(jnp.minimum(first - cum, _CLIP))).astype(dt)   # k e^{G_r - G_s}
+            k_rows.append((kf[here] * row).astype(dt))
+            k_cols.append(k_col)
+            m_rows.append(_dot((qf[here] * row).astype(dt), k_col, (1, 1)))
+        decay = jnp.exp(cum)                                                     # e^{G_t}
+        last = cum[c - 1:c]                                                      # G_C
+        qt_ref[0, n] = (qf * decay).astype(dt)
+        kc_ref[0, n] = (kf * jnp.exp(last - cum)).astype(dt)
+        m_ref[0, n] = jnp.where(t >= s, jnp.concatenate(m_rows, axis=0), 0.0).astype(dt)
+        decay_ref[0, n] = jnp.exp(last)
+        return k_rows, k_cols, beta, kf * decay * beta, vf * beta
+
+    def pack(first):
+        """Chunks first .. first + PACK - 1: their A side by side, one inverse."""
+        got = [chunk(first + j) for j in range(PACK)]
+
+        def placed(x, j):  # x in the j-th of PACK places, zeros in the others
+            return jnp.concatenate([x if i == j else jnp.zeros_like(x) for i in range(PACK)], axis=0)
+
+        # A's rows of each sub-chunk for all PACK chunks at once: the chunks' rows side by
+        # side against their columns on the diagonal
+        a_rows = [_dot(jnp.concatenate([k_rows[sub] for k_rows, *_ in got], axis=1),
+                       jnp.concatenate([placed(k_cols[sub], j)
+                                        for j, (_, k_cols, *_) in enumerate(got)], axis=1), (1, 1))
+                  for sub in range(c // SUB)]
+        beta = sum(jnp.where(sp // c == j, b, 0.0) for j, (_, _, b, _, _) in enumerate(got))
+        inv = _unit_lower_inverses(jnp.where(tp > sp % c, jnp.concatenate(a_rows, axis=0), 0.0) * beta)
+        for j, (*_, rk, rv) in enumerate(got):
+            w_ref[0, first + j] = _dot(inv, placed(rk, j)).astype(dt)
+            u0_ref[0, first + j] = _dot(inv, placed(rv, j))
+
+    def body(i, carry):
+        for j in range(UNROLL):
+            pack((i * UNROLL + j) * PACK)
+        return carry
+
+    steps = qt_ref.shape[1] // PACK
+    jax.lax.fori_loop(0, steps // UNROLL, body, 0)
+    for j in range(steps - steps % UNROLL, steps):
+        pack(j * PACK)
+
+
+UNROLL = 2  # PACK chunks at a time, UNROLL times a loop step
+CHUNKS_A_STEP = 16  # chunks of one head a grid step of ``kda_chunks``, at most
+
+
+def _chunk_parts(q, k, v, g, beta, scale, interpret):
+    """What ``_parts`` gives, from the Pallas kernel ``kda_chunks``: q, k, v, g
+    [B, T, H, .] read head-major, as ``[B*H, T, .]`` (the layout a pass holds
+    them in, so no copy), and beta [B, T, H]; a block of chunks of one head a
+    grid step; the parts written as the walks read them, [B*H, NC, C, .]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    nc, c = t // CHUNK, CHUNK
+    per = max(n for n in range(PACK, min(nc, CHUNKS_A_STEP) + 1, PACK) if nc % n == 0)
+
+    def cols(width):  # a head's rows of [B * H, T, width]
+        return pl.BlockSpec((1, per * c, width), lambda i, j, n: (i * h + j, n, 0))
+
+    def parts(shape):
+        return pl.BlockSpec((1, per) + shape, lambda i, j, n: (i * h + j, n, 0, 0))
+
+    shapes = [(c, dk), (c, dk), (c, dk), (c, dv), (c, c), (1, dk)]
+    dtypes = [q.dtype] * 3 + [jnp.float32, q.dtype, jnp.float32]
+    return pl.pallas_call(
+        functools.partial(_chunks_kernel, scale=scale),
+        grid=(b, h, nc // per),
+        in_specs=[cols(dk), cols(dk), cols(dv), cols(dk),
+                  pl.BlockSpec((1, per * c, h), lambda i, j, n: (i, n, 0))],
+        out_specs=[parts(shape) for shape in shapes],
+        out_shape=[jax.ShapeDtypeStruct((b * h, nc) + shape, dtype)
+                   for shape, dtype in zip(shapes, dtypes)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=interpret,
+        name="kda_chunks",
+    )(*(jnp.moveaxis(x, 2, 1).reshape(b * h, t, -1) for x in (q, k, v, g)), beta)
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +452,7 @@ def _in_passes(fn, *xs):
 
 def _forward(q, k, v, g, beta, scale, interpret):
     b, t, h, _ = q.shape
-    o = _walk(_parts(q, k, v, g, beta, scale), q.dtype, interpret, states=False)
+    o = _walk(_chunk_parts(q, k, v, g, beta, scale, interpret), q.dtype, interpret, states=False)
     return jnp.moveaxis(o.reshape(b, h, t, -1), 1, 2)
 
 
@@ -310,15 +481,15 @@ def kda(q, k, v, g, beta, scale: Optional[float] = None):
     head, as the layer hands them), ``v`` [B, T, H, V], ``g`` = log α [B, T, H, K]
     (float32, each entry in ``(bound, 0)`` with ``bound * (SUB - 1)`` above
     -_CLIP) and ``beta`` [B, T, H] (float32); ``o`` [B, T, H, V] in ``q``'s dtype.
-    ``scale`` defaults to K^-1/2. A sequence that is no whole number of chunks
-    is padded at its end (a later position changes no earlier output). The
+    ``scale`` defaults to K^-1/2. A sequence that is no whole number of PACK
+    chunks is padded at its end (a later position changes no earlier output). The
     kernels compile on a TPU and run interpreted elsewhere."""
     from .flash_attention import _on_tpu
 
     t = q.shape[1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    pad = -t % CHUNK
+    pad = -t % (CHUNK * PACK)
     if pad:
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))

@@ -3,7 +3,8 @@ recurrence written out a position at a time: the output and the gradients of
 all five inputs, with the Pallas kernels interpreted (as they run off the
 TPU); gates drawn anywhere in (-5, 0) and at the -5 bound, β near 1; values as
 wide as the keys and twice as wide; a sequence that is no whole number of
-chunks; float32 and bfloat16."""
+chunks; float32 and bfloat16. The forward's chunk parts from the kernel
+``kda_chunks`` against the XLA ``_within_chunks`` the backward keeps."""
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,33 @@ def test_chunked_scan_is_the_recurrence(gate, dtype, dv):
         np.testing.assert_allclose(np.asarray(got, np.float32), ref, atol=tol * scale, err_msg=f"d{name}")
 
 
+# The chunk parts from ``kda_chunks`` against ``_within_chunks``, relative to
+# each part's largest entry. float32: the kernel sums g in another order, and a
+# decay's exponent is a difference of such sums, which reach 64 * 5 in size:
+# an ulp of those, 2^-24 * 320 = 1.9e-5, is a decay's rounding (read: 6.1e-6 at
+# most). bfloat16: one rounding of the parts' dtype, 2^-8 (read: 2.3e-3 at most)
+PARTS = {jnp.float32: 2e-5, jnp.bfloat16: 2.0 ** -8}
+
+
+@pytest.mark.parametrize("t", [256, 100], ids=["whole_chunks", "padded"])
+@pytest.mark.parametrize("gate", ["random", "bound"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("dv", [32, 64], ids=["v32", "v64"])
+def test_the_chunks_kernel_gives_the_parts(t, gate, dtype, dv):
+    q, k, v, g, beta, _ = _inputs(t=t, gate=gate, dv=dv)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    pad = -t % (K.CHUNK * K.PACK)  # as ``kda`` pads
+    q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
+    beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    got = K._chunk_parts(q, k, v, g, beta, 0.2, True)
+    want = K._parts(q, k, v, g, beta, 0.2)
+    for name, a, b in zip(("Q̃", "K_C", "W", "U0", "M", "decay"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, err_msg=name,
+                                   atol=PARTS[dtype] * float(np.max(np.abs(b))))
+
+
 def test_a_sequence_of_no_whole_chunks_is_padded_at_its_end():
     q, k, v, g, beta, do = _inputs(t=100)
     o = K.kda(q, k, v, g, beta)
@@ -79,7 +107,7 @@ def test_the_kernels_are_named_for_the_trace():
     q, k, v, g, beta, do = _inputs(t=128)
     text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(K.kda(*a) * do),
                                        argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta))
-    for name in ("kda_fwd", "kda_bwd_states", "kda_bwd_grads"):
+    for name in ("kda_chunks", "kda_fwd", "kda_bwd_states", "kda_bwd_grads"):
         assert name in text
 
 

@@ -369,22 +369,41 @@ def test_routed_layer_moves_its_rows_once_each_way_on_v5e(one_chip, monkeypatch)
 def test_kda_kernels_compile_for_v5e(one_chip, monkeypatch):
     """The chunked delta-rule scan with its gradient at the hybrid cell's shape
     ([1, 8192, 32 heads, 128], bfloat16; the decay a channel in float32): the
-    three walks named for the trace, each a Pallas call."""
+    forward's chunk parts and the three walks named for the trace, each a
+    Pallas call."""
     from katib_tpu.ops import flash_attention as fa
     from katib_tpu.ops.kda import kda
 
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    x = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
-    g = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32, sharding=one_chip)
-    beta = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32, sharding=one_chip)
 
     def loss(q, k, v, g, beta):
         return kda(q, k, v, g, beta).astype(jnp.float32).sum()
 
-    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), x, x, x, g, beta)
-    assert text.count("tpu_custom_call") == 3
-    for name in ("kda_fwd", "kda_bwd_states", "kda_bwd_grads"):
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), *_kda_inputs(one_chip))
+    assert text.count("tpu_custom_call") == 4
+    for name in ("kda_chunks", "kda_fwd", "kda_bwd_states", "kda_bwd_grads"):
         assert name in text
+
+
+def _kda_inputs(one_chip):
+    x = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32, sharding=one_chip)
+    return x, x, x, g, beta
+
+
+def test_kda_forward_leaves_its_chunk_parts_to_the_kernel(one_chip, monkeypatch):
+    """The forward alone, compiled: the parts come from ``kda_chunks``, so no
+    triangular solve is left to XLA and no float32 column decays
+    [., 4 sub-chunks, 64, 128] are written around it."""
+    from katib_tpu.ops import flash_attention as fa
+    from katib_tpu.ops.kda import kda
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    text = jax.jit(kda).lower(*_kda_inputs(one_chip)).compile().as_text()
+    assert "kda_chunks" in text and "kda_fwd" in text
+    assert "triangular" not in text
+    assert not re.findall(r"f32\[\d+,4,64,128\]", text)
 
 
 def test_latent_flash_kernels_compile_for_v5e(one_chip, monkeypatch):
