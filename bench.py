@@ -1,4 +1,4 @@
-"""CPU rehearsals of the control plane: 18 standalone scenarios.
+"""CPU rehearsals of the control plane: 17 standalone scenarios.
 
 Each scenario drives one plane of the package (observation store, tracing,
 step stats, telemetry, the analyzers, the compile service, fused
@@ -11,12 +11,12 @@ timed on this sandbox's shared CPU and is never a speed (ROADMAP aim 1).
 What the model programs cost on the chip is measured by ``benchmarks/``
 alone (``BENCHMARK.json``, ``PERF.md``).
 
-    python bench.py <scenario> [--smoke] [--distributed]
+    python bench.py <scenario> [--smoke]
 
 prints ONE JSON line ``{"metric": <scenario>, ...}``. ``--smoke`` trims the
 sizes to the tier-1 wiring run (``tests/test_bench_budget.py``,
-``scripts/check.sh``); ``--distributed`` is ``tracing_overhead``'s. With no
-or an unknown name the exit code is 2 and the names are listed.
+``scripts/check.sh``). With no or an unknown name the exit code is 2 and the
+names are listed.
 """
 
 import json
@@ -145,278 +145,12 @@ def _bench_obslog_fold_latency(smoke: bool = False):
         shutil.rmtree(root, ignore_errors=True)
 
 
-def _bench_tracing_overhead(smoke: bool = False, distributed: bool = False):
-    """Trial lifecycle tracing (katib_tpu/tracing.py): end-to-end trials/sec
-    of an in-process experiment with ``runtime.tracing`` on vs off. The
-    target is <3% overhead when on and ~0% when off (off IS the
-    KATIB_TPU_TRACING=0 path: every instrumentation site reduces to one
-    boolean check). Runs interleaved on/off passes and keeps each side's
-    best to shed scheduler noise on shared CI boxes. ``smoke`` trims the
-    trial count for the tier-1 wiring test (tests/test_bench_budget.py).
-    ``distributed`` (``--distributed``) switches to the 3-replica wire
-    measurement instead (ISSUE 19)."""
-    if distributed:
-        return _bench_tracing_overhead_distributed(smoke)
-    from katib_tpu.api.spec import (
-        AlgorithmSpec, ExperimentSpec, FeasibleSpace, ObjectiveSpec,
-        ObjectiveType, ParameterSpec, ParameterType, TrialTemplate,
-    )
-    from katib_tpu.config import KatibConfig
-    from katib_tpu.controller.experiment import ExperimentController
-
-    n_trials = 12 if smoke else int(os.environ.get("BENCH_TRACING_TRIALS", "64"))
-    reports = 20 if smoke else 100     # report() is the hottest traced site
-    work = 200 if smoke else 20000     # busy-work per step: an empty trial
-    # loop would measure thread-scheduling noise (±15% run-to-run on shared
-    # CI), not tracing — real trials compute between reports, and the <3%
-    # target is tracing cost relative to a realistically-busy trial
-
-    def trial_fn(assignments, ctx):
-        x = float(assignments.get("x", "0.5"))
-        for i in range(reports):
-            acc = 0
-            for j in range(work):
-                acc += j & 7
-            x = x * 0.999 + 1e-9 * acc
-            ctx.report(score=x)
-
-    counter = {"n": 0}
-
-    def run_once(tracing_on: bool) -> float:
-        counter["n"] += 1
-        cfg = KatibConfig()
-        cfg.runtime.tracing = tracing_on
-        cfg.runtime.obslog_buffered = False  # memory store either way
-        ctrl = ExperimentController(
-            root_dir=None, devices=list(range(8)), persist=False, config=cfg
-        )
-        name = f"tracing-bench-{counter['n']}"
-        spec = ExperimentSpec(
-            name=name,
-            parameters=[
-                ParameterSpec(
-                    "x", ParameterType.DOUBLE, FeasibleSpace(min="0.1", max="1.0")
-                )
-            ],
-            objective=ObjectiveSpec(
-                type=ObjectiveType.MAXIMIZE, objective_metric_name="score"
-            ),
-            algorithm=AlgorithmSpec("random"),
-            trial_template=TrialTemplate(function=trial_fn),
-            max_trial_count=n_trials,
-            parallel_trial_count=8,
-        )
-        try:
-            ctrl.create_experiment(spec)
-            t0 = time.perf_counter()
-            exp = ctrl.run(name, timeout=300)
-            dt = time.perf_counter() - t0
-            assert exp.status.trials_succeeded == n_trials, (
-                f"{exp.status.trials_succeeded}/{n_trials} succeeded"
-            )
-            if tracing_on:
-                trial = ctrl.state.list_trials(name)[0]
-                trace = ctrl.tracer.trial_trace(name, trial.name)
-                assert trace and trace["spans"], "tracing on but no spans recorded"
-            else:
-                assert not ctrl.tracer.enabled
-            return dt
-        finally:
-            ctrl.close()
-
-    run_once(False)  # warmup: thread/JIT-free path, but import + state costs
-    passes = 2 if smoke else 3
-    on_s, off_s = [], []
-    for _ in range(passes):
-        off_s.append(run_once(False))
-        on_s.append(run_once(True))
-    on, off = min(on_s), min(off_s)
-    overhead_pct = (on - off) / off * 100.0
-    return {
-        "trials": n_trials,
-        "reports_per_trial": reports,
-        "passes": passes,
-        "off_s": round(off, 4),
-        "on_s": round(on, 4),
-        "off_trials_per_s": round(n_trials / off, 1),
-        "on_trials_per_s": round(n_trials / on, 1),
-        "overhead_pct": round(overhead_pct, 2),
-        "target_pct": 3.0,
-        "within_target": overhead_pct < 3.0,
-        "smoke": smoke,
-    }
-
-
-def _bench_tracing_overhead_distributed(smoke: bool = False):
-    """Distributed tracing cost (ISSUE 19): the same cheap-experiment batch
-    driven through THREE real replica subprocesses over the wire, with the
-    whole distributed plane armed (KATIB_TPU_WIRE_TRACING=1 +
-    KATIB_TPU_TRACING=1: traceparent headers on every RPC, server-side rpc
-    spans, per-tenant SLO histograms, the durable wire span sink) vs both
-    knobs off. Target: <3% aggregate trials/sec cost. Uses the
-    control_plane_scaling harness shape — replica subprocesses, the
-    client-side placement router, subprocess trials reporting over the
-    wire — so the measured path IS the production wire path."""
-    import shutil
-    import tempfile
-
-    from katib_tpu.client.katib_client import ReplicaRouter
-
-    replicas = 3
-    n_exps = int(os.environ.get("BENCH_TRO_EXPERIMENTS", "3" if smoke else "9"))
-    n_trials = 2 if smoke else 4
-    epochs = 3 if smoke else 6
-    dwell = 0.02 if smoke else 0.05
-    parallel = 2 if smoke else 4
-    repo = os.path.dirname(os.path.abspath(__file__))
-
-    def spec_for(name):
-        step = 0.9 / max(n_trials - 1, 1)
-        return {
-            "name": name,
-            "parameters": [{
-                "name": "x", "parameterType": "double",
-                "feasibleSpace": {"min": "0.1", "max": "1.0", "step": repr(step)},
-            }],
-            "objective": {"type": "maximize", "objectiveMetricName": "score"},
-            "algorithm": {"algorithmName": "grid"},
-            "trialTemplate": {
-                "entryPoint": "cp_trial:run_trial",
-                "trialParameters": [{"name": "x", "reference": "x"}],
-            },
-            "maxTrialCount": n_trials,
-            "parallelTrialCount": parallel,
-            "resumePolicy": "FromVolume",
-        }
-
-    def is_done(status_doc):
-        if not status_doc:
-            return False
-        return any(
-            c.get("type") in ("Succeeded", "Failed") and c.get("status")
-            for c in status_doc.get("status", {}).get("conditions", [])
-        )
-
-    def run_once(wire_on: bool) -> float:
-        root = tempfile.mkdtemp(prefix="bench-trace-dist-")
-        with open(os.path.join(root, "cp_trial.py"), "w") as f:
-            f.write(_CP_TRIAL_MODULE.format(epochs=epochs, dwell=dwell))
-        env = dict(os.environ)
-        env.update({
-            "JAX_PLATFORMS": "cpu",
-            "PYTHONPATH": (
-                repo + os.pathsep + root + os.pathsep + env.get("PYTHONPATH", "")
-            ).rstrip(os.pathsep),
-            "KATIB_TPU_REPLICAS": str(replicas),
-            "KATIB_TPU_REPLICA_CAPACITY": str(n_exps + 4),
-            "KATIB_TPU_PLACEMENT_LEASE_SECONDS": "8",
-            "KATIB_TPU_TELEMETRY": "0",
-            "KATIB_TPU_COMPILE_SERVICE": "0",
-            "KATIB_TPU_OBSLOG_BUFFERED": "0",
-            "KATIB_TPU_TRACING": "1" if wire_on else "0",
-            "KATIB_TPU_WIRE_TRACING": "1" if wire_on else "0",
-        })
-        env.pop("KATIB_TPU_CHAOS", None)
-        procs, logs = [], []
-        deadline = time.time() + 420.0
-        try:
-            for i in range(replicas):
-                out = open(os.path.join(root, f"r{i}.log"), "w+")
-                logs.append(out)
-                procs.append(subprocess.Popen(
-                    [sys.executable, "-m", "katib_tpu.controller.replica",
-                     "--root", root, "--replica-id", f"r{i}", "--devices", "4"],
-                    env=env, stdout=out, stderr=out, text=True,
-                ))
-            router = ReplicaRouter(root)
-            while len(router.live_replicas()) < replicas:
-                if time.time() > deadline:
-                    raise TimeoutError(
-                        f"replicas never registered; see {root}/r*.log"
-                    )
-                time.sleep(0.2)
-            warm = []
-            for i in range(replicas):
-                w = dict(spec_for(f"trace-warm-{i}"))
-                w["maxTrialCount"] = 1
-                w["parallelTrialCount"] = 1
-                router.create_experiment(w)
-                warm.append(f"trace-warm-{i}")
-            while not all(is_done(router.experiment_status(w)) for w in warm):
-                if time.time() > deadline:
-                    raise TimeoutError("warmup experiments never completed")
-                time.sleep(0.2)
-            names = [f"trace-{i:02d}" for i in range(n_exps)]
-            t0 = time.time()
-            for name in names:
-                router.create_experiment(spec_for(name))
-            pending = set(names)
-            while pending:
-                if time.time() > deadline:
-                    raise TimeoutError(
-                        f"{len(pending)} experiment(s) never completed; "
-                        f"see {root}/r*.log"
-                    )
-                for name in list(pending):
-                    if is_done(router.experiment_status(name)):
-                        pending.discard(name)
-                time.sleep(0.15)
-            wall = time.time() - t0
-            if wire_on:
-                # the on side must actually have traced across the wire —
-                # a silently-dark plane would "win" the comparison
-                wdir = os.path.join(root, "traces", "wire")
-                assert os.path.isdir(wdir) and os.listdir(wdir), (
-                    "wire tracing on but no wire spans persisted under "
-                    f"{wdir}"
-                )
-            return wall
-        finally:
-            for proc in procs:
-                if proc.poll() is None:
-                    proc.terminate()
-            for proc in procs:
-                if proc.poll() is None:
-                    try:
-                        proc.wait(timeout=10)
-                    except subprocess.TimeoutExpired:
-                        proc.kill()
-            for out in logs:
-                out.close()
-            shutil.rmtree(root, ignore_errors=True)
-
-    passes = 1 if smoke else 2
-    on_s, off_s = [], []
-    for _ in range(passes):
-        off_s.append(run_once(False))
-        on_s.append(run_once(True))
-    on, off = min(on_s), min(off_s)
-    total = n_exps * n_trials
-    overhead_pct = (on - off) / off * 100.0
-    return {
-        "distributed": True,
-        "replicas": replicas,
-        "experiments": n_exps,
-        "trials": total,
-        "epochs": epochs,
-        "passes": passes,
-        "off_s": round(off, 3),
-        "on_s": round(on, 3),
-        "off_trials_per_s": round(total / off, 2),
-        "on_trials_per_s": round(total / on, 2),
-        "overhead_pct": round(overhead_pct, 2),
-        "target_pct": 3.0,
-        "within_target": overhead_pct < 3.0,
-        "smoke": smoke,
-    }
-
-
 def _bench_step_stats_overhead(smoke: bool = False):
     """Step-statistics plane cost (ISSUE 20): end-to-end packs/sec of a
     pack_size=8 in-process sweep with ``runtime.step_stats`` on vs off.
     Target <3% overhead when on (off IS the KATIB_TPU_STEP_STATS=0 path:
-    every consult is one ``is None`` check). Same interleaved-passes,
-    keep-each-side's-best shape as tracing_overhead. Also asserts the
+    every consult is one ``is None`` check). Runs interleaved on/off passes
+    and keeps each side's best to shed scheduler noise. Also asserts the
     knob-off run writes zero katib-tpu/perf/ rows and exports none of the
     step metric families, and runs one injected-straggler pass
     (KATIB_TPU_STEP_STATS_INJECT=straggle=...) that must fire exactly one
@@ -433,9 +167,9 @@ def _bench_step_stats_overhead(smoke: bool = False):
 
     pack_size = 8
     reports = 20 if smoke else 100     # report_population is the hot site
-    work = 200 if smoke else 20000     # busy-work per step (see
-    # tracing_overhead: an empty loop measures scheduler noise, not the
-    # plane; the <3% target is cost relative to a realistically-busy pack)
+    work = 200 if smoke else 20000     # busy-work per step: an empty loop
+    # measures scheduler noise, not the plane; the <3% target is cost
+    # relative to a realistically-busy pack
     lrs = [str(round(0.1 + 0.1 * i, 1)) for i in range(pack_size)]
 
     def pack_fn(assignments, ctx=None):
@@ -550,7 +284,7 @@ def _bench_telemetry_overhead(smoke: bool = False):
     boolean check). The on side runs the sampler at a 50ms interval — ~100x
     the production rate — so the measurement actually contains sampling
     work rather than an idle thread. Interleaved on/off passes, each side's
-    best kept, same noise-shedding shape as tracing_overhead. ``smoke``
+    best kept, to shed scheduler noise. ``smoke``
     trims the trial count for the tier-1 wiring test."""
     from katib_tpu.api.spec import (
         AlgorithmSpec, ExperimentSpec, FeasibleSpace, ObjectiveSpec,
@@ -1172,7 +906,6 @@ def _bench_suggestion_pipeline_latency(smoke: bool = False):
     )
     from katib_tpu.config import KatibConfig
     from katib_tpu.controller.experiment import ExperimentController
-    from katib_tpu.tracing import SPAN_DURATION_METRIC
 
     n_trials = 8 if smoke else 64
     candidates = 256 if smoke else 2048  # weight the inline compute
@@ -1235,9 +968,14 @@ def _bench_suggestion_pipeline_latency(smoke: bool = False):
             # integrity: zero duplicate or lost assignments
             assert len(names) == len(set(names)) == n_trials, (
                 len(names), len(set(names)))
-            key = (SPAN_DURATION_METRIC, (("stage", "suggestion"),))
-            hist = c.metrics._histograms.get(key)
-            mean_span = (hist.sum / hist.count) if hist and hist.count else 0.0
+            spans = [
+                s for t in names
+                for s in (c.tracer.trial_trace(name, t) or {}).get("spans", [])
+                if s["name"] == "suggestion" and s["end"] is not None
+            ]
+            mean_span = (
+                sum(s["end"] - s["start"] for s in spans) / len(spans) if spans else 0.0
+            )
             hits = sum(
                 v for (metric, _), v in c.metrics._counters.items()
                 if metric == "katib_suggestion_buffer_ready_total"
@@ -3271,7 +3009,6 @@ def _bench_ingest_throughput(smoke: bool = False):
 SCENARIOS = {
     "obslog_report_throughput": _bench_obslog_report_throughput,
     "obslog_fold_latency": _bench_obslog_fold_latency,
-    "tracing_overhead": _bench_tracing_overhead,
     "step_stats_overhead": _bench_step_stats_overhead,
     "telemetry_overhead": _bench_telemetry_overhead,
     "check_latency": _bench_check_latency,
@@ -3293,13 +3030,10 @@ SCENARIOS = {
 if __name__ == "__main__":
     if len(sys.argv) < 2 or sys.argv[1] not in SCENARIOS:
         print(
-            "usage: python bench.py <scenario> [--smoke] [--distributed]\n"
+            "usage: python bench.py <scenario> [--smoke]\n"
             "scenarios:\n  " + "\n  ".join(SCENARIOS),
             file=sys.stderr,
         )
         sys.exit(2)
-    kwargs = {"smoke": "--smoke" in sys.argv[2:]}
-    if "--distributed" in sys.argv[2:]:
-        kwargs["distributed"] = True  # tracing_overhead only (ISSUE 19)
-    result = SCENARIOS[sys.argv[1]](**kwargs)
+    result = SCENARIOS[sys.argv[1]](smoke="--smoke" in sys.argv[2:])
     print(json.dumps({"metric": sys.argv[1], **result}))

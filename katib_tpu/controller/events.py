@@ -123,8 +123,7 @@ class MetricsRegistry:
     katib_trial_failed_total, katib_trial_early_stopped_total, plus running
     gauges (prometheus_metrics.go). Histograms (no reference counterpart —
     its exporter is counters/gauges only) render the full
-    ``_bucket``/``_sum``/``_count`` exposition series; the tracing layer
-    feeds katib_span_duration_seconds{stage=...} through observe().
+    ``_bucket``/``_sum``/``_count`` exposition series, fed through observe().
     """
 
     # latency-shaped default buckets: 1ms .. 10min, roughly log-spaced
@@ -317,7 +316,6 @@ _HELP_CATALOG: Dict[str, str] = {
     "katib_obslog_flush_batch_rows": "Rows drained by buffered-store flushes.",
     "katib_obslog_flush_latency_seconds": "Latency of the last buffered-store flush.",
     "katib_obslog_buffered_rows": "Rows currently buffered in the write-behind store.",
-    "katib_span_duration_seconds": "Trial lifecycle stage durations from tracing spans, by stage.",
     # resource telemetry + health watchdog (katib_tpu/telemetry.py) — the
     # TrialStalled / TrialOOMRisk warning events pair with these counters
     # and show in GET /api/events?warning=1

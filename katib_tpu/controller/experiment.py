@@ -179,7 +179,6 @@ class ExperimentController:
 
         self.tracer = Tracer(
             enabled=rt.tracing,
-            metrics=self.metrics,
             ring_size=rt.trace_ring_spans,
             persist_dir=os.path.join(root_dir, "traces") if root_dir else None,
         )

@@ -34,6 +34,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops.flash_attention import flash_attention, sharded_flash_attention
 from ..ops.ring_attention import dense_attention, ring_attention
+from ..tracing import step_part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -339,51 +340,52 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        cfg = self.config
-        h, d = self.layer.num_heads or cfg.num_heads, cfg.head_dim
-        kv = cfg.num_kv_heads or h
-        if self.layer.attention not in ("full", "sliding"):
-            raise ValueError(f"no attention of the kind {self.layer.attention!r}")
-        window = cfg.window if self.layer.attention == "sliding" else None
-        if self.layer.attention == "sliding" and not window:
-            raise ValueError("a sliding layer needs TransformerConfig.window")
-        if kv == h:
-            q, k, v = QKVProjection(h, d, dtype=cfg.dtype, name="qkv")(x)
-        else:
-            q, k, v = GroupedQKVProjection(h, kv, d, dtype=cfg.dtype, name="qkv")(x)
-        rotary = cfg.rotary_of(self.layer.attention)
-        q = rotary_embed(q, positions, rotary.theta, rotary.fraction, rotary.yarn)
-        k = rotary_embed(k, positions, rotary.theta, rotary.fraction, rotary.yarn)
-        ring_over_mesh = self.mesh is not None and _mesh_axis_size(self.mesh, "seq") > 1
-        if (self.seq_axis is not None or ring_over_mesh) and (kv != h or window is not None):
-            raise NotImplementedError(
-                "ring attention over a sequence axis takes neither grouped KV heads nor a window")
-        if self.seq_axis is not None:
-            from ..ops.ring_attention import ring_attention_local
-
-            o = ring_attention_local(q, k, v, self.seq_axis, causal=cfg.causal)
-        elif self.mesh is not None:
-            # 'expert' is a batch axis here: outside the MoE layers it acts
-            # as pure data parallelism (see parallel.mesh.activation_batch_axes)
-            batch_axes = ("data", "fsdp", "expert")
-            if ring_over_mesh:
-                # cross-device sequence blocks: ring schedule over ppermute
-                o = ring_attention(
-                    q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes
-                )
+        with step_part("attention"):
+            cfg = self.config
+            h, d = self.layer.num_heads or cfg.num_heads, cfg.head_dim
+            kv = cfg.num_kv_heads or h
+            if self.layer.attention not in ("full", "sliding"):
+                raise ValueError(f"no attention of the kind {self.layer.attention!r}")
+            window = cfg.window if self.layer.attention == "sliding" else None
+            if self.layer.attention == "sliding" and not window:
+                raise ValueError("a sliding layer needs TransformerConfig.window")
+            if kv == h:
+                q, k, v = QKVProjection(h, d, dtype=cfg.dtype, name="qkv")(x)
             else:
-                # seq unsharded: fused Pallas flash kernel per local shard
-                o = sharded_flash_attention(
-                    q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes,
-                    **({} if window is None else {"window": window}),
-                )
-        else:
-            o = flash_attention(q, k, v, causal=cfg.causal, window=window)
-        if cfg.attention_gate:
-            o = _head_gate(x, h, o)
-        return nn.DenseGeneral(
-            cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out"
-        )(o)
+                q, k, v = GroupedQKVProjection(h, kv, d, dtype=cfg.dtype, name="qkv")(x)
+            rotary = cfg.rotary_of(self.layer.attention)
+            q = rotary_embed(q, positions, rotary.theta, rotary.fraction, rotary.yarn)
+            k = rotary_embed(k, positions, rotary.theta, rotary.fraction, rotary.yarn)
+            ring_over_mesh = self.mesh is not None and _mesh_axis_size(self.mesh, "seq") > 1
+            if (self.seq_axis is not None or ring_over_mesh) and (kv != h or window is not None):
+                raise NotImplementedError(
+                    "ring attention over a sequence axis takes neither grouped KV heads nor a window")
+            if self.seq_axis is not None:
+                from ..ops.ring_attention import ring_attention_local
+
+                o = ring_attention_local(q, k, v, self.seq_axis, causal=cfg.causal)
+            elif self.mesh is not None:
+                # 'expert' is a batch axis here: outside the MoE layers it acts
+                # as pure data parallelism (see parallel.mesh.activation_batch_axes)
+                batch_axes = ("data", "fsdp", "expert")
+                if ring_over_mesh:
+                    # cross-device sequence blocks: ring schedule over ppermute
+                    o = ring_attention(
+                        q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes
+                    )
+                else:
+                    # seq unsharded: fused Pallas flash kernel per local shard
+                    o = sharded_flash_attention(
+                        q, k, v, self.mesh, causal=cfg.causal, batch_axes=batch_axes,
+                        **({} if window is None else {"window": window}),
+                    )
+            else:
+                o = flash_attention(q, k, v, causal=cfg.causal, window=window)
+            if cfg.attention_gate:
+                o = _head_gate(x, h, o)
+            return nn.DenseGeneral(
+                cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out"
+            )(o)
 
 
 def _deinterleave(x):
@@ -403,33 +405,34 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        cfg, lat = self.config, self.config.latent
-        h, eps = cfg.num_heads, cfg.rms_eps
-        b, t, _ = x.shape
-        q = HeadProjection(h, lat.nope_dim + lat.rope_dim, dtype=cfg.dtype, name="q")(x)
-        latent = nn.Dense(lat.kv_rank + lat.rope_dim, use_bias=False, dtype=cfg.dtype, name="dkv")(x)
-        c = RMSNorm(eps, name="kv_norm")(latent[..., :lat.kv_rank]).astype(cfg.dtype)
-        k_rope = jnp.broadcast_to(latent[:, :, None, lat.kv_rank:], (b, t, h, lat.rope_dim))
-        kv = nn.DenseGeneral((h, lat.nope_dim + lat.v_dim), use_bias=False, dtype=cfg.dtype,
-                             name="ukv")(c)
-        k = jnp.concatenate([kv[..., :lat.nope_dim], k_rope.astype(kv.dtype)], axis=-1)
-        v = kv[..., lat.nope_dim:]
-        if lat.qk_norm:
-            q = RMSNorm(eps, name="q_norm")(q).astype(cfg.dtype)
-            k = RMSNorm(eps, name="k_norm")(k).astype(cfg.dtype)
+        with step_part("attention"):
+            cfg, lat = self.config, self.config.latent
+            h, eps = cfg.num_heads, cfg.rms_eps
+            b, t, _ = x.shape
+            q = HeadProjection(h, lat.nope_dim + lat.rope_dim, dtype=cfg.dtype, name="q")(x)
+            latent = nn.Dense(lat.kv_rank + lat.rope_dim, use_bias=False, dtype=cfg.dtype, name="dkv")(x)
+            c = RMSNorm(eps, name="kv_norm")(latent[..., :lat.kv_rank]).astype(cfg.dtype)
+            k_rope = jnp.broadcast_to(latent[:, :, None, lat.kv_rank:], (b, t, h, lat.rope_dim))
+            kv = nn.DenseGeneral((h, lat.nope_dim + lat.v_dim), use_bias=False, dtype=cfg.dtype,
+                                 name="ukv")(c)
+            k = jnp.concatenate([kv[..., :lat.nope_dim], k_rope.astype(kv.dtype)], axis=-1)
+            v = kv[..., lat.nope_dim:]
+            if lat.qk_norm:
+                q = RMSNorm(eps, name="q_norm")(q).astype(cfg.dtype)
+                k = RMSNorm(eps, name="k_norm")(k).astype(cfg.dtype)
 
-        def rotate(x):
-            rope = x[..., lat.nope_dim:]
-            if lat.interleaved:
-                rope = _deinterleave(rope)
-            return jnp.concatenate(
-                [x[..., :lat.nope_dim], rotary_embed(rope, positions, lat.theta)], axis=-1)
+            def rotate(x):
+                rope = x[..., lat.nope_dim:]
+                if lat.interleaved:
+                    rope = _deinterleave(rope)
+                return jnp.concatenate(
+                    [x[..., :lat.nope_dim], rotary_embed(rope, positions, lat.theta)], axis=-1)
 
-        o = flash_attention(rotate(q), rotate(k), v, causal=cfg.causal)
-        if cfg.attention_gate:
-            o = _head_gate(x, h, o)
-        return nn.DenseGeneral(
-            cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out")(o)
+            o = flash_attention(rotate(q), rotate(k), v, causal=cfg.causal)
+            if cfg.attention_gate:
+                o = _head_gate(x, h, o)
+            return nn.DenseGeneral(
+                cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out")(o)
 
 
 class DeltaAttention(nn.Module):
@@ -443,36 +446,37 @@ class DeltaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.kda import kda
+        with step_part("linear_attention"):
+            from ..ops.kda import kda
 
-        cfg, lin = self.config, self.config.linear
-        h, d = lin.num_heads, lin.head_dim
-        b, t, _ = x.shape
-        conv_init = nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2))
+            cfg, lin = self.config, self.config.linear
+            h, d = lin.num_heads, lin.head_dim
+            b, t, _ = x.shape
+            conv_init = nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2))
 
-        def short_conv(name, y):  # causal: position t sees t - conv + 1 .. t
-            w = self.param(f"{name}_conv", conv_init, (lin.conv, h, d), jnp.float32).astype(y.dtype)
-            padded = jnp.pad(y, ((0, 0), (lin.conv - 1, 0), (0, 0), (0, 0)))
-            return nn.silu(sum(w[j] * padded[:, j:j + t] for j in range(lin.conv)))
+            def short_conv(name, y):  # causal: position t sees t - conv + 1 .. t
+                w = self.param(f"{name}_conv", conv_init, (lin.conv, h, d), jnp.float32).astype(y.dtype)
+                padded = jnp.pad(y, ((0, 0), (lin.conv - 1, 0), (0, 0), (0, 0)))
+                return nn.silu(sum(w[j] * padded[:, j:j + t] for j in range(lin.conv)))
 
-        def l2_normed(y):
-            y32 = y.astype(jnp.float32)
-            return (y32 * jax.lax.rsqrt(jnp.sum(y32 * y32, axis=-1, keepdims=True) + 1e-6)).astype(y.dtype)
+            def l2_normed(y):
+                y32 = y.astype(jnp.float32)
+                return (y32 * jax.lax.rsqrt(jnp.sum(y32 * y32, axis=-1, keepdims=True) + 1e-6)).astype(y.dtype)
 
-        q, k, v = (short_conv(name, HeadProjection(h, d, dtype=cfg.dtype, name=name)(x))
-                   for name in ("q", "k", "v"))
-        a_log = self.param("a_log", nn.initializers.zeros, (h,), jnp.float32)
-        dt_bias = self.param("dt_bias", nn.initializers.zeros, (h, d), jnp.float32)
-        f = HeadProjection(h, d, dtype=cfg.dtype, name="f")(x).astype(jnp.float32)
-        g = lin.lower_bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None] * (f + dt_bias))
-        beta = jax.nn.sigmoid(
-            nn.Dense(h, use_bias=False, dtype=jnp.float32, name="beta")(x.astype(jnp.float32)))
-        o = kda(l2_normed(q), l2_normed(k), v, g, beta)
-        o = RMSNorm(cfg.rms_eps, name="o_norm")(o.reshape(b, t, h * d)).reshape(b, t, h, d)
-        if cfg.attention_gate:
-            o = _head_gate(x, h, o)
-        return nn.DenseGeneral(
-            cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out")(o)
+            q, k, v = (short_conv(name, HeadProjection(h, d, dtype=cfg.dtype, name=name)(x))
+                       for name in ("q", "k", "v"))
+            a_log = self.param("a_log", nn.initializers.zeros, (h,), jnp.float32)
+            dt_bias = self.param("dt_bias", nn.initializers.zeros, (h, d), jnp.float32)
+            f = HeadProjection(h, d, dtype=cfg.dtype, name="f")(x).astype(jnp.float32)
+            g = lin.lower_bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None] * (f + dt_bias))
+            beta = jax.nn.sigmoid(
+                nn.Dense(h, use_bias=False, dtype=jnp.float32, name="beta")(x.astype(jnp.float32)))
+            o = kda(l2_normed(q), l2_normed(k), v, g, beta)
+            o = RMSNorm(cfg.rms_eps, name="o_norm")(o.reshape(b, t, h * d)).reshape(b, t, h, d)
+            if cfg.attention_gate:
+                o = _head_gate(x, h, o)
+            return nn.DenseGeneral(
+                cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="out")(o)
 
 
 def _mesh_axis_size(mesh, axis: str) -> int:
@@ -487,13 +491,14 @@ class MLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.config
-        hidden = self.hidden or cfg.mlp_hidden or cfg.embed_dim * cfg.mlp_ratio
-        up = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="up")(x)
-        gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="gate")(x)
-        return nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype, name="down")(
-            nn.silu(gate) * up
-        )
+        with step_part("mlp"):
+            cfg = self.config
+            hidden = self.hidden or cfg.mlp_hidden or cfg.embed_dim * cfg.mlp_ratio
+            up = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="up")(x)
+            gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="gate")(x)
+            return nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype, name="down")(
+                nn.silu(gate) * up
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -704,22 +709,24 @@ class RoutedExperts(nn.Module):
         bias = None
         if routed.bias_rate:  # moved by the step's rule (parallel/train.py), no gradient
             bias = self.param("bias", nn.initializers.zeros, (routed.router_width,), jnp.float32)
-        logits = nn.Dense(
-            routed.router_width, use_bias=False, dtype=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST, name="router",
-        )(tokens.astype(jnp.float32))
-        scores = jax.nn.sigmoid(logits)
-        weights, local, chosen = route(
-            scores, routed, None if bias is None else jax.lax.stop_gradient(bias))
-        if routed.bias_rate or routed.balance_weight:
-            self._router_state(scores, chosen, b, t)
-        plan = dispatch_plan(local, held, TILE, spare=CHUNK_TILES - 1)
-        self.sow("intermediates", "routing", {
-            "landed": jnp.sum(plan["landed"]).astype(jnp.float32),
-            "load_max": jnp.max(plan["load"]).astype(jnp.float32),
-            "load_mean": jnp.mean(plan["load"].astype(jnp.float32)),
-            "chosen": chosen,
-        })
+        with step_part("router"):
+            logits = nn.Dense(
+                routed.router_width, use_bias=False, dtype=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST, name="router",
+            )(tokens.astype(jnp.float32))
+            scores = jax.nn.sigmoid(logits)
+            weights, local, chosen = route(
+                scores, routed, None if bias is None else jax.lax.stop_gradient(bias))
+            if routed.bias_rate or routed.balance_weight:
+                self._router_state(scores, chosen, b, t)
+        with step_part("plan"):
+            plan = dispatch_plan(local, held, TILE, spare=CHUNK_TILES - 1)
+            self.sow("intermediates", "routing", {
+                "landed": jnp.sum(plan["landed"]).astype(jnp.float32),
+                "load_max": jnp.max(plan["load"]).astype(jnp.float32),
+                "load_mean": jnp.mean(plan["load"].astype(jnp.float32)),
+                "chosen": chosen,
+            })
 
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
         w_gate = self.param("gate", init, (held, e, f), jnp.float32)
@@ -728,15 +735,18 @@ class RoutedExperts(nn.Module):
 
         @jax.checkpoint  # a layer's buffers are for N k rows: kept for one layer at a time
         def experts(tokens, weights, w_gate, w_up, w_down):
-            tokens = tokens.astype(cfg.dtype)
             source, dest, landed = plan["source"], plan["dest"], plan["landed"]
-            filled = source < n * k
-            rows = gather_rows(tokens, source // k, filled, dest, landed)
-            products = functools.partial(
-                grouped_matmul, tile_group=plan["tile_group"], num_tiles=plan["num_tiles"])
-            hidden = nn.silu(products(rows, w_gate)) * products(rows, w_up)
-            out = products(hidden, w_down)
-            return combine_rows(out, weights, dest, landed, source, filled)
+            with step_part("dispatch"):
+                tokens = tokens.astype(cfg.dtype)
+                filled = source < n * k
+                rows = gather_rows(tokens, source // k, filled, dest, landed)
+            with step_part("products"):
+                products = functools.partial(
+                    grouped_matmul, tile_group=plan["tile_group"], num_tiles=plan["num_tiles"])
+                hidden = nn.silu(products(rows, w_gate)) * products(rows, w_up)
+                out = products(hidden, w_down)
+            with step_part("combine"):
+                return combine_rows(out, weights, dest, landed, source, filled)
 
         y = experts(tokens, weights, w_gate, w_up, w_down).reshape(b, t, e)
         if routed.shared_hidden:
@@ -1028,24 +1038,22 @@ class Block(nn.Module):
         eps = self.config.rms_eps
         if self.layer.attention in ("linear", "latent"):
             return self._hybrid(x, positions)
-        x = _pin_residual(
-            x + Attention(self.config, self.mesh, self.seq_axis, self.layer, name="attn")(
-                RMSNorm(eps, name="ln1")(x), positions
-            ),
-            self.mesh,
-        )
-        if self.layer.mlp == "routed":
-            x = x + RoutedExperts(self.config, name="experts")(RMSNorm(eps, name="ln2")(x))
-        elif self.layer.mlp != "dense":
+        if self.layer.mlp not in ("routed", "dense"):
             raise ValueError(f"no MLP of the kind {self.layer.mlp!r}")
+        with step_part("norm_residual"):
+            h = RMSNorm(eps, name="ln1")(x)
+        h = Attention(self.config, self.mesh, self.seq_axis, self.layer, name="attn")(h, positions)
+        with step_part("norm_residual"):
+            x = _pin_residual(x + h, self.mesh)
+            h = RMSNorm(eps, name="ln2")(x)
+        if self.layer.mlp == "routed":
+            h = RoutedExperts(self.config, name="experts")(h)
         elif self.config.num_experts > 0:
-            x = x + MoE(
-                self.config, self.mesh, self.expert_axis, self.expert_axis_size,
-                name="moe",
-            )(RMSNorm(eps, name="ln2")(x))
+            h = MoE(self.config, self.mesh, self.expert_axis, self.expert_axis_size, name="moe")(h)
         else:
-            x = x + MLP(self.config, name="mlp")(RMSNorm(eps, name="ln2")(x))
-        return _pin_residual(x, self.mesh)
+            h = MLP(self.config, name="mlp")(h)
+        with step_part("norm_residual"):
+            return _pin_residual(x + h, self.mesh)
 
     def _hybrid(self, x, positions):
         """A linear or latent layer. Its two halves, each with its norm, are
@@ -1056,17 +1064,23 @@ class Block(nn.Module):
 
         def attend(block, x):
             kind = DeltaAttention if block.layer.attention == "linear" else LatentAttention
-            h = RMSNorm(eps, name="ln1")(x)
+            with step_part("norm_residual"):
+                h = RMSNorm(eps, name="ln1")(x)
             return kind(block.config, name="attn")(h, *(() if kind is DeltaAttention else (positions,)))
 
         def feed(block, x):
-            h = RMSNorm(eps, name="ln2")(x)
+            with step_part("norm_residual"):
+                h = RMSNorm(eps, name="ln2")(x)
             if block.layer.mlp == "routed":
                 return RoutedExperts(block.config, name="experts")(h)
             return MLP(block.config, name="mlp")(h)
 
-        x = x + nn.remat(attend)(self, x)
-        return x + nn.remat(feed)(self, x)
+        h = nn.remat(attend)(self, x)
+        with step_part("norm_residual"):
+            x = x + h
+        h = nn.remat(feed)(self, x)
+        with step_part("norm_residual"):
+            return x + h
 
 
 class TransformerLM(nn.Module):
@@ -1082,34 +1096,36 @@ class TransformerLM(nn.Module):
         emb = self.param(
             "embed", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.embed_dim), jnp.float32
         )
-        if self.mesh is not None:
-            # ZeRO idiom: the table is STORED sharded over 'fsdp'
-            # (param_sharding_rules) but COMPUTED replicated — one cheap
-            # [V, E] all-gather here instead of the involuntary full
-            # rematerialization the partitioner otherwise emits for the
-            # token-gather forward (and its scatter-add transpose), whose
-            # activations can't transition from embed-dim-sharded to
-            # batch-sharded efficiently.
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        with step_part("embed"):
+            if self.mesh is not None:
+                # ZeRO idiom: the table is STORED sharded over 'fsdp'
+                # (param_sharding_rules) but COMPUTED replicated — one cheap
+                # [V, E] all-gather here instead of the involuntary full
+                # rematerialization the partitioner otherwise emits for the
+                # token-gather forward (and its scatter-add transpose), whose
+                # activations can't transition from embed-dim-sharded to
+                # batch-sharded efficiently.
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-            emb = jax.lax.with_sharding_constraint(
-                emb, NamedSharding(self.mesh, P(None, None))
-            )
-        x = _pin_residual(emb[tokens].astype(cfg.dtype), self.mesh)
+                emb = jax.lax.with_sharding_constraint(
+                    emb, NamedSharding(self.mesh, P(None, None))
+                )
+            x = _pin_residual(emb[tokens].astype(cfg.dtype), self.mesh)
         for i in range(cfg.num_layers):
             x = Block(cfg, self.mesh, layer=cfg.layer(i), name=f"block{i}")(x, positions)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
-        # the output head, tied to the embedding or its own [V, E] — the
-        # largest matmul in the model: bf16 operands at native MXU rate, f32
-        # accumulation for the softmax/loss
-        head = emb if cfg.tied_head else self.param(
-            "head", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.embed_dim), jnp.float32
-        )
-        logits = jnp.einsum(
-            "bte,ve->btv", x.astype(cfg.dtype), head.astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        return logits
+        with step_part("norm_residual"):
+            x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        with step_part("head_loss"):
+            # the output head, tied to the embedding or its own [V, E] — the
+            # largest matmul in the model: bf16 operands at native MXU rate, f32
+            # accumulation for the softmax/loss
+            head = emb if cfg.tied_head else self.param(
+                "head", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.embed_dim), jnp.float32
+            )
+            return jnp.einsum(
+                "bte,ve->btv", x.astype(cfg.dtype), head.astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            )
 
 
 def abstract_lm_program(assignments: Dict[str, str]):

@@ -36,6 +36,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..tracing import step_part
+
 NEG_INF = -1e30
 _LANES = 128  # TPU lane width; scratch vectors are padded to this
 
@@ -880,50 +882,51 @@ def flash_attention(
     tiling cannot cover (T < 2 MXU rows or not a multiple of 128) fall back
     to dense attention — semantics are identical.
     """
-    from .ring_attention import dense_attention
+    with step_part("flash"):
+        from .ring_attention import dense_attention
 
-    b, t, h, d = q.shape
-    kv_heads, dv = k.shape[2], v.shape[-1]
-    if h % kv_heads or v.shape[2] != kv_heads:
-        raise ValueError(f"{h} query heads cannot share {kv_heads} key/value heads")
-    group = h // kv_heads
-    if window is not None:
-        if not causal or window < 1:
-            raise ValueError("a window is a causal band of at least one key")
-        if window >= t:
-            window = None  # the band is the causal half
-        elif dv != d:
-            raise ValueError("a windowed call takes one head size for q, k and v")
-    if window is None:
-        auto_q, auto_k = _auto_block(t, FWD_BLOCK_Q_CAP), _auto_block(t, FWD_BLOCK_K_CAP)
-    else:
-        auto_q = _window_block(t, window, d, q.dtype.itemsize)
-        auto_k = auto_q and _window_kv_block(auto_q)
-    block_q = min(block_q, t) if block_q else (auto_q or t + 1)
-    block_k = min(block_k, t) if block_k else (auto_k or t + 1)
+        b, t, h, d = q.shape
+        kv_heads, dv = k.shape[2], v.shape[-1]
+        if h % kv_heads or v.shape[2] != kv_heads:
+            raise ValueError(f"{h} query heads cannot share {kv_heads} key/value heads")
+        group = h // kv_heads
+        if window is not None:
+            if not causal or window < 1:
+                raise ValueError("a window is a causal band of at least one key")
+            if window >= t:
+                window = None  # the band is the causal half
+            elif dv != d:
+                raise ValueError("a windowed call takes one head size for q, k and v")
+        if window is None:
+            auto_q, auto_k = _auto_block(t, FWD_BLOCK_Q_CAP), _auto_block(t, FWD_BLOCK_K_CAP)
+        else:
+            auto_q = _window_block(t, window, d, q.dtype.itemsize)
+            auto_k = auto_q and _window_kv_block(auto_q)
+        block_q = min(block_q, t) if block_q else (auto_q or t + 1)
+        block_k = min(block_k, t) if block_k else (auto_k or t + 1)
 
-    def dense_fallback():
-        if group > 1 or window is not None or dv != d:
-            return _dense_grouped(q, k, v, causal, window,
-                                  1.0 / math.sqrt(d) if sm_scale is None else sm_scale)
-        # dense_attention hard-codes 1/sqrt(d); fold a custom sm_scale into q
-        # so fallback results match the kernel on every platform
-        qs = q if sm_scale is None else q * (sm_scale * math.sqrt(d))
-        return dense_attention(qs, k, v, causal=causal)
+        def dense_fallback():
+            if group > 1 or window is not None or dv != d:
+                return _dense_grouped(q, k, v, causal, window,
+                                      1.0 / math.sqrt(d) if sm_scale is None else sm_scale)
+            # dense_attention hard-codes 1/sqrt(d); fold a custom sm_scale into q
+            # so fallback results match the kernel on every platform
+            qs = q if sm_scale is None else q * (sm_scale * math.sqrt(d))
+            return dense_attention(qs, k, v, causal=causal)
 
-    if t % block_q or t % block_k or t < 16 or not _use_kernel(interpret):
-        return dense_fallback()
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
+        if t % block_q or t % block_k or t < 16 or not _use_kernel(interpret):
+            return dense_fallback()
+        if sm_scale is None:
+            sm_scale = 1.0 / math.sqrt(d)
 
-    def to_bhtd(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, x.shape[3])
+        def to_bhtd(x):
+            return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, x.shape[3])
 
-    o = _flash_bhtd(
-        to_bhtd(q), to_bhtd(k), to_bhtd(v),
-        causal, float(sm_scale), block_q, block_k, bool(interpret), window, group,
-    )
-    return o.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
+        o = _flash_bhtd(
+            to_bhtd(q), to_bhtd(k), to_bhtd(v),
+            causal, float(sm_scale), block_q, block_k, bool(interpret), window, group,
+        )
+        return o.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
 
 def flash_attention_with_lse(

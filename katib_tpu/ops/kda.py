@@ -57,6 +57,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..tracing import step_part
+
 CHUNK = 64   # positions a chunk; the state is carried from one to the next
 SUB = 16     # positions a decay is exponentiated across, at most
 _CLIP = 80.0  # the largest exponent taken: e^80 is a float32, a hidden column's is clipped to it
@@ -452,16 +454,23 @@ def _in_passes(fn, *xs):
 
 def _forward(q, k, v, g, beta, scale, interpret):
     b, t, h, _ = q.shape
-    o = _walk(_chunk_parts(q, k, v, g, beta, scale, interpret), q.dtype, interpret, states=False)
+    with step_part("kda_parts"):
+        parts = _chunk_parts(q, k, v, g, beta, scale, interpret)
+    with step_part("kda_walks"):
+        o = _walk(parts, q.dtype, interpret, states=False)
     return jnp.moveaxis(o.reshape(b, h, t, -1), 1, 2)
 
 
 def _backward(q, k, v, g, beta, do, scale, interpret):
     b, t, h, _ = do.shape
-    parts, pullback = jax.vjp(functools.partial(_parts, scale=scale), q, k, v, g, beta)
+    with step_part("kda_parts"):
+        parts, pullback = jax.vjp(functools.partial(_parts, scale=scale), q, k, v, g, beta)
     do = jnp.moveaxis(do, 2, 1).reshape(b * h, t // CHUNK, CHUNK, -1)
-    states = _walk(parts, do.dtype, interpret, states=True)
-    return pullback(_walk_back(parts, states, do, interpret))
+    with step_part("kda_walks"):
+        states = _walk(parts, do.dtype, interpret, states=True)
+        d_parts = _walk_back(parts, states, do, interpret)
+    with step_part("kda_parts"):
+        return pullback(d_parts)
 
 
 def _kda_fwd(q, k, v, g, beta, scale, interpret):

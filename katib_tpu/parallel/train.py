@@ -21,6 +21,7 @@ from ..models.transformer import (
     TransformerLM,
     param_sharding_rules,
 )
+from ..tracing import enter_annotation, leave_annotation, step_part
 
 
 def lm_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
@@ -150,7 +151,8 @@ def make_lm_train_step(
                 logits, mutated = model.apply(
                     {"params": p}, tokens, positions, mutable=["intermediates"]
                 )
-                return lm_loss(logits, targets) + collect_moe_aux(mutated), ()
+                with step_part("head_loss"):
+                    return lm_loss(logits, targets) + collect_moe_aux(mutated), ()
             if routed:
                 from ..models.transformer import collect_router_state, collect_routing
 
@@ -158,22 +160,31 @@ def make_lm_train_step(
                     {"params": p}, tokens, positions, mutable=["intermediates"]
                 )
                 if not adjusted:  # nothing is added to the loss for these layers
-                    return lm_loss(logits, targets), (collect_routing(mutated),)
+                    with step_part("head_loss"):
+                        loss = lm_loss(logits, targets)
+                    with step_part("router"):
+                        return loss, (collect_routing(mutated),)
                 # a balance loss is added, the expert loads are kept for the bias
-                balance, loads = collect_router_state(mutated)
-                return lm_loss(logits, targets) + balance, (collect_routing(mutated), loads)
+                with step_part("router"):
+                    balance, loads = collect_router_state(mutated)
+                with step_part("head_loss"):
+                    loss = lm_loss(logits, targets) + balance
+                with step_part("router"):
+                    return loss, (collect_routing(mutated), loads)
             logits = model.apply({"params": p}, tokens, positions)
-            return lm_loss(logits, targets), ()
+            with step_part("head_loss"):
+                return lm_loss(logits, targets), ()
 
         (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        if adjusted:
-            from ..models.transformer import move_router_bias
+        with step_part("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            if adjusted:
+                from ..models.transformer import move_router_bias
 
-            routing, loads = counters
-            params, span = move_router_bias(params, loads, config.routed.bias_rate)
-            counters = (dict(routing, bias_span=span) if biased else routing,)
+                routing, loads = counters
+                params, span = move_router_bias(params, loads, config.routed.bias_rate)
+                counters = (dict(routing, bias_span=span) if biased else routing,)
         return (params, opt_state, loss) + counters
 
     jitted_step = jax.jit(step, donate_argnums=(0, 1))
@@ -272,10 +283,18 @@ def run_lm_trial(assignments: Dict[str, str], ctx=None) -> None:
     # the synthetic batch is constant across steps: stage it once
     with stage("stage_batch"):
         tokens, targets, positions = put_batch(data[:, :-1], data[:, 1:])
+    traced = getattr(ctx, "tracer", None) is not None
+
     def report(loss, counters):
-        value = float(loss)  # the one wait for the device
-        for routing in counters:  # of the step whose loss this is: ready with it
-            ctx.count(**{name: float(v) for name, v in routing.items()})
+        # a profiler annotation only: the device idles from this step's end to the next dispatch
+        note = enter_annotation("fetch") if traced else None
+        try:
+            value = float(loss)  # the one wait for the device
+            fetched = [{name: float(v) for name, v in routing.items()} for routing in counters]
+        finally:
+            leave_annotation(note)
+        for routing in fetched:  # of the step whose loss this is: ready with it
+            ctx.count(**routing)
         ctx.report(loss=value)
 
     with prof_cm:

@@ -18,8 +18,9 @@ per-stage timing; this module supplies it:
 - W3C-traceparent-style context (``00-<trace>-<span>-01``) propagated to
   subprocess trials via ``KATIB_TPU_TRACEPARENT`` and rejoined on the
   ``report_metrics`` env binding and the ReportObservationLog RPC;
-- span ends feed the ``katib_span_duration_seconds{stage=...}`` histogram in
-  the MetricsRegistry (controller/events.py);
+- the train step's parts (``STEP_PARTS``): named scopes the model code opens
+  where the work is, so a device trace's operations say which part they are
+  (``part_of``);
 - exports: span-tree text rendering (``katib-tpu trace``), Chrome/Perfetto
   ``trace_event`` JSON (``GET .../trace?format=perfetto``, openable in
   ui.perfetto.dev alongside the xplane dumps), and per-trial JSON
@@ -49,8 +50,6 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 ENV_TRACING = "KATIB_TPU_TRACING"
 ENV_TRACEPARENT = "KATIB_TPU_TRACEPARENT"
 ENV_WIRE_TRACING = "KATIB_TPU_WIRE_TRACING"
-
-SPAN_DURATION_METRIC = "katib_span_duration_seconds"
 
 _TRACEPARENT_RE = re.compile(r"^00-([0-9a-f]{32})-([0-9a-f]{16})-[0-9a-f]{2}$")
 
@@ -201,12 +200,10 @@ class Tracer:
     def __init__(
         self,
         enabled: bool = True,
-        metrics=None,
         ring_size: int = 4096,
         persist_dir: Optional[str] = None,
     ):
         self.enabled = enabled
-        self.metrics = metrics
         self.ring_size = ring_size
         self.persist_dir = persist_dir
         self._lock = threading.Lock()
@@ -291,11 +288,6 @@ class Tracer:
         if attrs:
             span.attrs.update(attrs)
         span.end = time.time() if end is None else end
-        if self.metrics is not None:
-            try:
-                self.metrics.observe(SPAN_DURATION_METRIC, span.duration, stage=span.name)
-            except Exception:
-                pass  # a histogram bug must never unwind the traced path
         sink = self.wire_sink
         if sink is not None:
             sink.record(span, getattr(span, "_wire_experiment", ""))
@@ -581,6 +573,62 @@ def enter_annotation(name: str):
 def leave_annotation(note) -> None:
     if note is not None:
         note.__exit__(None, None, None)
+
+
+# -- the train step's parts, as the device trace names them --------------------
+#
+# The model code opens each part with ``step_part`` where that work is, so the
+# ``op_name`` of every operation the step compiles to carries it: through
+# ``jax.checkpoint``, a ``custom_vjp``'s backward and the transpose, where the
+# path reads ``transpose(jvp(<part>))`` or holds ``transpose(`` further out.
+# Scopes are trace-time names only: the compiled step's instructions are
+# those of a step without them.
+
+STEP_PARTS = (
+    "embed",           # the token embedding's lookup
+    "attention",       # full, sliding and latent attention: projections, rotary, head gate
+    "flash",           # the flash kernels with their layout changes and lse / delta copies
+    "linear_attention",  # a delta-attention layer's projections, conv, gates, output norm
+    "kda_parts",       # the delta rule's chunk parts: kda_chunks forward, _parts and its pullback backward
+    "kda_walks",       # the delta rule's walks over the chunks (_walk, _walk_back)
+    "mlp",             # a dense SwiGLU, the shared expert's included
+    "router",          # a routed layer's router product, choice of experts and router state
+    "plan",            # where a routed layer's rows go (dispatch_plan)
+    "dispatch",        # the rows gathered into the experts' buffer, and that gather's gradient
+    "products",        # the grouped expert products and the SwiGLU between them
+    "combine",         # the experts' rows weighted back onto the tokens, and its gradient
+    "norm_residual",   # a block's norms and residual adds, the final norm
+    "head_loss",       # the output head's product and the loss
+    "optimizer",       # AdamW's update, its application and the router bias's rule
+)
+
+
+def step_part(name: str):
+    """``jax.named_scope(name)`` for a name of STEP_PARTS; any other name raises."""
+    if name not in STEP_PARTS:
+        raise ValueError(f"{name!r} is not one of tracing.STEP_PARTS")
+    import jax
+
+    return jax.named_scope(name)
+
+
+_TRANSFORMED = re.compile(r"^(?:jvp|transpose)\((.*)\)$")
+
+
+def part_of(op_name: str) -> Tuple[str, bool]:
+    """(the innermost part of STEP_PARTS that ``op_name``'s path names,
+    whether it is the backward pass), ``"unnamed"`` where it names none. A
+    component names a part bare or inside ``jvp(…)`` / ``transpose(…)``;
+    any ``transpose(`` in the path is the backward pass."""
+    part = "unnamed"
+    for component in op_name.split("/"):
+        inner = _TRANSFORMED.match(component)
+        while inner:
+            component = inner.group(1)
+            inner = _TRANSFORMED.match(component)
+        if component in STEP_PARTS:
+            part = component
+    return part, "transpose(" in op_name
 
 
 class StageSpan:

@@ -74,9 +74,6 @@ JAX_PLATFORMS=cpu BENCH_CP_REPLICAS=3 KATIB_TPU_REPLICAS=3 KATIB_TPU_TENANCY=1 \
     KATIB_TPU_SLO_OBJECTIVES="default=0.000001" \
     python bench.py control_plane_scaling --smoke
 
-echo
-echo "== distributed tracing-overhead smoke (3 replica subprocesses, wire tracing off vs on) =="
-JAX_PLATFORMS=cpu python bench.py tracing_overhead --smoke --distributed
 
 echo
 echo "== step-stats smoke (per-step timing plane off vs on, injected gang straggler) =="

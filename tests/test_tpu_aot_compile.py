@@ -200,7 +200,7 @@ def test_qkv_weight_gradient_is_no_windowed_convolution_on_v5e(one_chip, monkeyp
     ]
     assert windowed == []
     ranked = entry_ops_by_estimated_cycles(text)
-    qkv_grad = [r for r in ranked if "transpose(jvp" in r[3] and "attn/qkv" in r[3]]
+    qkv_grad = [r for r in ranked if "transpose(jvp" in r[3] and "attn/attention/qkv" in r[3]]
     mlp_grad = [r for r in ranked if "transpose(jvp" in r[3] and "/mlp/" in r[3]]
     assert qkv_grad and mlp_grad, ranked[:10]
     # the MLP's weight gradients do 1.3 times the arithmetic of q/k/v's
