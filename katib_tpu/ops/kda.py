@@ -35,17 +35,30 @@ positive sign: that is what the bounded ("safe") gate is for.
 
 Products take their operands in the inputs' dtype (bfloat16 on the chip, at
 the MXU's rate) and accumulate in float32; the state, the decays, ``U0`` and
-the triangular solve are float32.
+the triangular solve (a product with (I + A)^{-1}) are float32.
+
+**The backward.** The walk back gives the cotangents of ``Q̃, K_C, W, U0, M``
+and the state's decay; they are pulled back to q, k, v, g and β chunk by
+chunk, by hand. The solve's: with ``dR = (I + A)^{-ᵀ} [dW | dU0]``, the
+right-hand side ``Diag(β) [K̃ | V]`` takes ``dR`` and ``A`` takes
+``-dR [W | U0]ᵀ`` below its diagonal (the inverse is read, no second solve).
+``A`` and ``M`` go back to k, q, β and the decays through the same split of
+``e^{G_t - G_s}`` as the forward, a hidden column's clipped exponent passing
+no gradient; ``Q̃``'s, ``K_C``'s, ``K̃``'s and the state's decay add their
+terms to ``dG`` (``G_C``'s summed over the chunk), and ``dg`` is ``dG``
+summed from the chunk's end back. Every term of ``dG`` is float32, and no
+cotangent is rounded to the inputs' dtype before it is summed.
 
 The kernels (Pallas, named for the device trace): ``kda_chunks`` computes the
-forward's parts, a block of chunks of one head a grid step, in VMEM;
-``kda_fwd`` walks the chunks of one head and writes ``o``; the backward
-computes the parts in XLA (``_within_chunks``, the kernel's reference) under
-``jax.vjp``, walks the chunks again to write the state each chunk starts from
-(``kda_bwd_states``), walks them back (``kda_bwd_grads``) for the cotangents
-of ``Q̃, K_C, W, U0, M`` and the decay, and pulls those back through
-``_within_chunks``. Off the TPU the same kernels run interpreted. Both
-directions take the heads HEADS_A_PASS at a time, one pass after another.
+parts, a block of chunks of one head a grid step, in VMEM (in the backward's
+call it also writes the inverses); ``kda_fwd`` walks the chunks of one head
+and writes ``o``; the backward walks the chunks again to write the state each
+chunk starts from (``kda_bwd_states``), walks them back (``kda_bwd_grads``),
+and ``kda_chunks_bwd`` pulls the parts' cotangents back, a block of chunks of
+one head a grid step, as ``kda_chunks`` reads and writes them. ``_within_chunks``
+is the parts in XLA, the reference both chunk kernels are tested against. Off
+the TPU the same kernels run interpreted. Both directions take the heads
+HEADS_A_PASS at a time, one pass after another.
 """
 
 from __future__ import annotations
@@ -125,14 +138,17 @@ def _within_chunks(q, k, v, g, beta, scale):
 # the order of float32 sums: the cumulative sum of g is taken by doubling, and
 # the solve is a product with the inverse of I + A.
 
-def _cumsum_rows(x):
-    """The cumulative sum of x [C, .] down its rows, by doubling."""
+def _cumsum_rows(x, reverse=False):
+    """The cumulative sum of x [C, .] down its rows (up them, ``reverse``), by doubling."""
     from jax.experimental.pallas import tpu as pltpu
 
     row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    shift = 1
-    while shift < x.shape[0]:
-        x = x + jnp.where(row >= shift, pltpu.roll(x, shift, 0), 0.0)
+    n, shift = x.shape[0], 1
+    while shift < n:
+        if reverse:  # x[t + shift] rolled to t
+            x = x + jnp.where(row < n - shift, pltpu.roll(x, n - shift, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= shift, pltpu.roll(x, shift, 0), 0.0)
         shift *= 2
     return x
 
@@ -173,12 +189,20 @@ def _unit_lower_inverses(a):
     return _side_by_side(neumann(_side_by_side(inv, jnp.where(within, 0.0, a)), c // SUB), inv)
 
 
+def _head_beta(beta_ref, rows, head):
+    """Head ``head``'s column of ``beta_ref`` [1, chunks * C, H] at ``rows``: [C, 1]."""
+    by_head = beta_ref[0, rows, :]
+    pick = jax.lax.broadcasted_iota(jnp.int32, by_head.shape, 1) == head
+    return jnp.sum(jnp.where(pick, by_head, 0.0), axis=1, keepdims=True)
+
+
 def _chunks_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
-                   qt_ref, kc_ref, w_ref, u0_ref, m_ref, decay_ref, *, scale):
+                   qt_ref, kc_ref, w_ref, u0_ref, m_ref, decay_ref, *inv_ref, scale):
     """A block of chunks of one head: ``*_ref`` [1, chunks * C, .] in (the
     head's column of ``beta_ref`` [1, chunks * C, H] picked by the grid's
-    head); the parts out, [1, chunks, C, .]. PACK chunks a step, UNROLL
-    such steps a loop step."""
+    head); the parts out, [1, chunks, C, .], and where ``inv_ref`` is given
+    the inverses (I + A)^{-1}, [1, chunks / PACK, C, PACK * C], PACK chunks'
+    side by side. PACK chunks a step, UNROLL such steps a loop step."""
     from jax.experimental import pallas as pl
 
     c = CHUNK
@@ -195,9 +219,7 @@ def _chunks_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
         kf = k_ref[0, rows, :].astype(jnp.float32)
         qf = q_ref[0, rows, :].astype(jnp.float32) * scale
         vf = v_ref[0, rows, :].astype(jnp.float32)
-        by_head = beta_ref[0, rows, :]
-        pick = jax.lax.broadcasted_iota(jnp.int32, by_head.shape, 1) == head
-        beta = jnp.sum(jnp.where(pick, by_head, 0.0), axis=1, keepdims=True)     # [C, 1]
+        beta = _head_beta(beta_ref, rows, head)                                  # [C, 1]
         cum = _cumsum_rows(g_ref[0, rows, :])                                    # G, <= 0
         k_rows, k_cols, m_rows = [], [], []
         for sub in range(c // SUB):
@@ -216,8 +238,9 @@ def _chunks_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
         decay_ref[0, n] = jnp.exp(last)
         return k_rows, k_cols, beta, kf * decay * beta, vf * beta
 
-    def pack(first):
-        """Chunks first .. first + PACK - 1: their A side by side, one inverse."""
+    def pack(p):
+        """Chunks p * PACK .. p * PACK + PACK - 1: their A side by side, one inverse."""
+        first = p * PACK
         got = [chunk(first + j) for j in range(PACK)]
 
         def placed(x, j):  # x in the j-th of PACK places, zeros in the others
@@ -234,56 +257,225 @@ def _chunks_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
         for j, (*_, rk, rv) in enumerate(got):
             w_ref[0, first + j] = _dot(inv, placed(rk, j)).astype(dt)
             u0_ref[0, first + j] = _dot(inv, placed(rv, j))
+        if inv_ref:
+            inv_ref[0][0, p] = inv
 
+    _in_packs(pack, qt_ref.shape[1] // PACK)
+
+
+def _in_packs(pack, steps):
+    """``pack(p)`` for p in 0 .. steps - 1, UNROLL of them a loop step."""
     def body(i, carry):
         for j in range(UNROLL):
-            pack((i * UNROLL + j) * PACK)
+            pack(i * UNROLL + j)
         return carry
 
-    steps = qt_ref.shape[1] // PACK
     jax.lax.fori_loop(0, steps // UNROLL, body, 0)
-    for j in range(steps - steps % UNROLL, steps):
-        pack(j * PACK)
+    for p in range(steps - steps % UNROLL, steps):
+        pack(p)
 
 
 UNROLL = 2  # PACK chunks at a time, UNROLL times a loop step
 CHUNKS_A_STEP = 16  # chunks of one head a grid step of ``kda_chunks``, at most
 
 
-def _chunk_parts(q, k, v, g, beta, scale, interpret):
+class _Blocks:
+    """The block specs ``kda_chunks`` and ``kda_chunks_bwd`` share: a block of
+    ``per`` chunks of one head a grid step (b, h, n)."""
+
+    def __init__(self, b, t, h):
+        from jax.experimental import pallas as pl
+
+        self.pl, self.b, self.t, self.h = pl, b, t, h
+        self.nc = nc = t // CHUNK
+        self.per = max(n for n in range(PACK, min(nc, CHUNKS_A_STEP) + 1, PACK) if nc % n == 0)
+        self.grid = (b, h, nc // self.per)
+
+    def cols(self, width):  # a head's rows of [B * H, T, width]
+        return self.pl.BlockSpec((1, self.per * CHUNK, width), lambda i, j, n: (i * self.h + j, n, 0))
+
+    def beta(self):  # the rows of beta [B, T, H], every head
+        return self.pl.BlockSpec((1, self.per * CHUNK, self.h), lambda i, j, n: (i, n, 0))
+
+    def parts(self, shape, per=None):  # a head's block of [B * H, ., *shape]: its chunks, or ``per`` items
+        return self.pl.BlockSpec((1, per or self.per) + shape,
+                                 lambda i, j, n: (i * self.h + j, n) + (0,) * len(shape))
+
+    def row(self):  # a head's positions of [B * H, 1, T]
+        return self.pl.BlockSpec((1, 1, self.per * CHUNK), lambda i, j, n: (i * self.h + j, 0, n))
+
+    def head_major(self, x):
+        """[B, T, H, .] -> [B * H, T, .]"""
+        return jnp.moveaxis(x, 2, 1).reshape(self.b * self.h, self.t, -1)
+
+    def call(self, kernel, in_specs, out_specs, out_shape, interpret, name):
+        from jax.experimental.pallas import tpu as pltpu
+
+        return self.pl.pallas_call(
+            kernel, grid=self.grid, in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=interpret, name=name)
+
+
+def _chunk_parts(q, k, v, g, beta, scale, interpret, inverse=False):
     """What ``_parts`` gives, from the Pallas kernel ``kda_chunks``: q, k, v, g
     [B, T, H, .] read head-major, as ``[B*H, T, .]`` (the layout a pass holds
     them in, so no copy), and beta [B, T, H]; a block of chunks of one head a
-    grid step; the parts written as the walks read them, [B*H, NC, C, .]."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    grid step; the parts written as the walks read them, [B*H, NC, C, .].
+    With ``inverse`` (the backward's call) also the chunks' (I + A)^{-1},
+    float32 [B*H, NC / PACK, C, PACK * C], PACK chunks' side by side."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
-    nc, c = t // CHUNK, CHUNK
-    per = max(n for n in range(PACK, min(nc, CHUNKS_A_STEP) + 1, PACK) if nc % n == 0)
-
-    def cols(width):  # a head's rows of [B * H, T, width]
-        return pl.BlockSpec((1, per * c, width), lambda i, j, n: (i * h + j, n, 0))
-
-    def parts(shape):
-        return pl.BlockSpec((1, per) + shape, lambda i, j, n: (i * h + j, n, 0, 0))
-
+    blocks, c = _Blocks(b, t, h), CHUNK
     shapes = [(c, dk), (c, dk), (c, dk), (c, dv), (c, c), (1, dk)]
     dtypes = [q.dtype] * 3 + [jnp.float32, q.dtype, jnp.float32]
-    return pl.pallas_call(
+    out_specs = [blocks.parts(shape) for shape in shapes]
+    out_shape = [jax.ShapeDtypeStruct((b * h, blocks.nc) + shape, dtype)
+                 for shape, dtype in zip(shapes, dtypes)]
+    if inverse:
+        out_specs.append(blocks.parts((c, PACK * c), blocks.per // PACK))
+        out_shape.append(jax.ShapeDtypeStruct((b * h, blocks.nc // PACK, c, PACK * c), jnp.float32))
+    return blocks.call(
         functools.partial(_chunks_kernel, scale=scale),
-        grid=(b, h, nc // per),
-        in_specs=[cols(dk), cols(dk), cols(dv), cols(dk),
-                  pl.BlockSpec((1, per * c, h), lambda i, j, n: (i, n, 0))],
-        out_specs=[parts(shape) for shape in shapes],
-        out_shape=[jax.ShapeDtypeStruct((b * h, nc) + shape, dtype)
-                   for shape, dtype in zip(shapes, dtypes)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-        name="kda_chunks",
-    )(*(jnp.moveaxis(x, 2, 1).reshape(b * h, t, -1) for x in (q, k, v, g)), beta)
+        [blocks.cols(dk), blocks.cols(dk), blocks.cols(dv), blocks.cols(dk), blocks.beta()],
+        out_specs, out_shape, interpret, "kda_chunks",
+    )(*(blocks.head_major(x) for x in (q, k, v, g)), beta)
+
+
+# ---------------------------------------------------------------------------
+# The parts pulled back, a block of chunks (Pallas), in VMEM
+# ---------------------------------------------------------------------------
+#
+# ``_within_chunks``' pullback, written out: given the cotangents of Q̃, K_C,
+# W, U0, M and the decay (the walk's), those of q, k, v, g and β. Products take
+# their operands in the inputs' dtype and accumulate in float32, as the
+# forward's; the products with (I + A)^{-1}, the decays and every term of the
+# gate's gradient are float32, and no cotangent is rounded to the inputs' dtype
+# before it is summed.
+
+def _chunks_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, w_ref, u0_ref,
+                       dqt_ref, dkc_ref, dw_ref, du0_ref, dm_ref, ddecay_ref,
+                       dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *, scale):
+    """A block of chunks of one head, laid out as ``_chunks_kernel`` reads and
+    writes them; dq, dk, dv, dg out as q is read, dβ [1, 1, chunks * C]."""
+    from jax.experimental import pallas as pl
+
+    c = CHUNK
+    dt = q_ref.dtype
+    head = pl.program_id(1)
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    tp = jax.lax.broadcasted_iota(jnp.int32, (c, PACK * c), 0)
+    sp = jax.lax.broadcasted_iota(jnp.int32, (c, PACK * c), 1)
+
+    def chunk(n, dr_k, dr_v):
+        """Chunk n's cotangents, given dR = (I + A)^{-T} [dW | dU0] = [dr_k | dr_v],
+        that of the solve's right-hand side Diag(β) [K̃ | V]; β's as a column."""
+        rows = pl.ds(pl.multiple_of(n * c, c), c)
+        kf = k_ref[0, rows, :].astype(jnp.float32)
+        qf = q_ref[0, rows, :].astype(jnp.float32) * scale
+        vf = v_ref[0, rows, :].astype(jnp.float32)
+        beta = _head_beta(beta_ref, rows, head)                                  # [C, 1]
+        cum = _cumsum_rows(g_ref[0, rows, :])                                    # G
+        decay = jnp.exp(cum)                                                     # e^{G_t}
+        last = cum[c - 1:c]                                                      # G_C
+        # the solve: dA = -dR [W | U0]ᵀ below the diagonal
+        da = -(_dot(dr_k.astype(dt), w_ref[0, n], (1, 1))
+               + _dot(dr_v.astype(dt), u0_ref[0, n].astype(dt), (1, 1)))
+        da = jnp.where(t > s, da, 0.0)
+        # its right-hand side, Diag(β) [k e^{G} | v]
+        kt = kf * decay
+        dk = dr_k * decay * beta
+        dg = dr_k * kt * beta
+        dv = dr_v * beta
+        dbeta = jnp.sum(dr_k * kt, axis=1, keepdims=True) + jnp.sum(dr_v * vf, axis=1, keepdims=True)
+        # Q̃ = scale q e^{G}
+        dqt = dqt_ref[0, n].astype(jnp.float32)
+        dq = dqt * decay
+        dg = dg + dqt * qf * decay
+        # K_C = k e^{G_C - G}, and the state's decay e^{G_C}
+        to_last = jnp.exp(last - cum)
+        dkc = dkc_ref[0, n].astype(jnp.float32)
+        dk = dk + dkc * to_last
+        kc_term = dkc * kf * to_last
+        dg = dg - kc_term
+        at_last = jnp.sum(kc_term, axis=0, keepdims=True) + ddecay_ref[0, n] * jnp.exp(last)
+        # A and M, a sub-chunk's rows at a time against every column, split at its first row r
+        da_raw = da * beta                                                       # A = β A_raw
+        dm = jnp.where(t >= s, dm_ref[0, n].astype(jnp.float32), 0.0)
+        by_rows = []
+        for sub in range(c // SUB):
+            here = slice(sub * SUB, (sub + 1) * SUB)
+            first = cum[sub * SUB:sub * SUB + 1]                                 # G_r
+            row = jnp.exp(cum[here] - first)                                     # e^{G_t - G_r}
+            exponent = first - cum
+            col = jnp.exp(jnp.minimum(exponent, _CLIP))                          # e^{G_r - G_s}
+            k_col = kf * col
+            k_row, q_row = kf[here] * row, qf[here] * row
+            rows_b = jnp.concatenate([k_row, q_row], axis=0).astype(dt)          # [2 SUB, K]
+            k_col_b = k_col.astype(dt)
+            a_raw = _dot(rows_b[:SUB], k_col_b, (1, 1))                          # [SUB, C]
+            lhs = jnp.concatenate([da_raw[here], dm[here]], axis=0).astype(dt)   # [2 SUB, C]
+            d_rows = _dot(lhs, k_col_b)                                          # [2 SUB, K]
+            d_col = _dot(lhs, rows_b, (0, 0))                                    # [C, K]
+            dk = dk + d_col * col
+            # a hidden column's exponent is clipped: no gradient goes through it
+            dg = dg - jnp.where(exponent < _CLIP, d_col * k_col, 0.0)
+            by_rows.append((d_rows[:SUB] * row, d_rows[SUB:] * row,
+                            d_rows[:SUB] * k_row + d_rows[SUB:] * q_row,
+                            jnp.sum(da[here] * a_raw, axis=1, keepdims=True)))
+        dk_rows, dq_rows, dg_rows, dbeta_rows = (jnp.concatenate(x, axis=0) for x in zip(*by_rows))
+        t_k = jax.lax.broadcasted_iota(jnp.int32, dg.shape, 0)
+        dg = dg + dg_rows + jnp.where(t_k == c - 1, at_last, 0.0)
+        dq_ref[0, rows, :] = ((dq + dq_rows) * scale).astype(dq_ref.dtype)
+        dk_ref[0, rows, :] = (dk + dk_rows).astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+        dg_ref[0, rows, :] = _cumsum_rows(dg, reverse=True)                      # G = cumsum(g)
+        return dbeta + dbeta_rows
+
+    def pack(p):
+        """Chunks p * PACK .. p * PACK + PACK - 1: dR for all of them in one
+        product with their inverses on the diagonal; dβ as one row."""
+        first = p * PACK
+        inv = inv_ref[0, p]                                                      # [C, PACK * C]
+        diagonal = jnp.concatenate([jnp.where(sp // c == j, inv, 0.0) for j in range(PACK)], axis=0)
+        dr_k, dr_v = (_dot(diagonal, jnp.concatenate([ref[0, first + j].astype(jnp.float32)
+                                                      for j in range(PACK)], axis=0), (0, 0))
+                      for ref in (dw_ref, du0_ref))
+        dbetas = [chunk(first + j, dr_k[j * c:(j + 1) * c], dr_v[j * c:(j + 1) * c])
+                  for j in range(PACK)]
+        # chunk j's column along its lanes, then each lane's own row: a row [1, PACK * C]
+        spread = sum(jnp.where(sp // c == j, d, 0.0) for j, d in enumerate(dbetas))
+        dbeta_ref[0, :, pl.ds(pl.multiple_of(first * c, PACK * c), PACK * c)] = jnp.sum(
+            jnp.where(tp == sp % c, spread, 0.0), axis=0, keepdims=True)
+
+    _in_packs(pack, dqt_ref.shape[1] // PACK)
+
+
+def _chunk_grads(q, k, v, g, beta, inverse, w, u0, d_parts, scale, interpret):
+    """The cotangents of q, k, v, g [B, T, H, .] and beta [B, T, H] from those
+    of the parts (``d_parts``, as ``kda_bwd_grads`` writes them), through the
+    Pallas kernel ``kda_chunks_bwd``: a block of chunks of one head a grid
+    step, read as ``kda_chunks`` reads and writes them; ``inverse``, ``w``,
+    ``u0``: what ``kda_chunks`` wrote in the backward's call."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    blocks, c = _Blocks(b, t, h), CHUNK
+    shapes = [(c, dk), (c, dk), (c, dk), (c, dv), (c, c), (1, dk)]
+    dq, dk_, dv_, dg, dbeta = blocks.call(
+        functools.partial(_chunks_bwd_kernel, scale=scale),
+        [blocks.cols(dk), blocks.cols(dk), blocks.cols(dv), blocks.cols(dk), blocks.beta(),
+         blocks.parts((c, PACK * c), blocks.per // PACK), blocks.parts((c, dk)), blocks.parts((c, dv))]
+        + [blocks.parts(shape) for shape in shapes],
+        [blocks.cols(dk), blocks.cols(dk), blocks.cols(dv), blocks.cols(dk), blocks.row()],
+        [jax.ShapeDtypeStruct((b * h, t, width), dtype) for width, dtype in
+         ((dk, q.dtype), (dk, k.dtype), (dv, v.dtype), (dk, jnp.float32))]
+        + [jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32)],
+        interpret, "kda_chunks_bwd",
+    )(*(blocks.head_major(x) for x in (q, k, v, g)), beta, inverse, w, u0, *d_parts)
+    return tuple(jnp.moveaxis(x.reshape(b, h, t, -1), 1, 2) for x in (dq, dk_, dv_, dg)) + (
+        jnp.moveaxis(dbeta.reshape(b, h, t), 1, 2),)
 
 
 # ---------------------------------------------------------------------------
@@ -464,13 +656,13 @@ def _forward(q, k, v, g, beta, scale, interpret):
 def _backward(q, k, v, g, beta, do, scale, interpret):
     b, t, h, _ = do.shape
     with step_part("kda_parts"):
-        parts, pullback = jax.vjp(functools.partial(_parts, scale=scale), q, k, v, g, beta)
+        *parts, inverse = _chunk_parts(q, k, v, g, beta, scale, interpret, inverse=True)
     do = jnp.moveaxis(do, 2, 1).reshape(b * h, t // CHUNK, CHUNK, -1)
     with step_part("kda_walks"):
         states = _walk(parts, do.dtype, interpret, states=True)
         d_parts = _walk_back(parts, states, do, interpret)
     with step_part("kda_parts"):
-        return pullback(d_parts)
+        return _chunk_grads(q, k, v, g, beta, inverse, parts[2], parts[3], d_parts, scale, interpret)
 
 
 def _kda_fwd(q, k, v, g, beta, scale, interpret):

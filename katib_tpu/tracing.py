@@ -589,7 +589,7 @@ STEP_PARTS = (
     "attention",       # full, sliding and latent attention: projections, rotary, head gate
     "flash",           # the flash kernels with their layout changes and lse / delta copies
     "linear_attention",  # a delta-attention layer's projections, conv, gates, output norm
-    "kda_parts",       # the delta rule's chunk parts: kda_chunks forward, _parts and its pullback backward
+    "kda_parts",       # the delta rule's chunk parts: kda_chunks both ways, kda_chunks_bwd their pullback
     "kda_walks",       # the delta rule's walks over the chunks (_walk, _walk_back)
     "mlp",             # a dense SwiGLU, the shared expert's included
     "router",          # a routed layer's router product, choice of experts and router state

@@ -3,8 +3,11 @@ recurrence written out a position at a time: the output and the gradients of
 all five inputs, with the Pallas kernels interpreted (as they run off the
 TPU); gates drawn anywhere in (-5, 0) and at the -5 bound, β near 1; values as
 wide as the keys and twice as wide; a sequence that is no whole number of
-chunks; float32 and bfloat16. The forward's chunk parts from the kernel
-``kda_chunks`` against the XLA ``_within_chunks`` the backward keeps."""
+chunks; float32 and bfloat16. The chunk parts from the kernel ``kda_chunks``,
+and their pullback from the kernel ``kda_chunks_bwd``, against the XLA
+``_within_chunks`` and its ``jax.vjp``."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +99,39 @@ def test_the_chunks_kernel_gives_the_parts(t, gate, dtype, dv):
                                    atol=PARTS[dtype] * float(np.max(np.abs(b))))
 
 
+# The pullback from ``kda_chunks_bwd`` against ``jax.vjp`` of ``_within_chunks``,
+# each input's cotangent relative to its largest reference entry (dg: as GATE
+# holds it). float32: a decay's rounding (above) enters through a row's and a
+# column's decay, read at 2.0e-5 at most (dk). bfloat16: the reference rounds
+# the cotangents of its bfloat16 operands (2^-8 each) where the kernel rounds
+# dA and dR as product operands, read at 6.0e-3 at most (dq at the bound)
+PULLBACK = {jnp.float32: 6e-5, jnp.bfloat16: 2.0 ** -6}
+
+
+@pytest.mark.parametrize("t", [256, 1152], ids=["one_grid_step", "three_grid_steps"])
+@pytest.mark.parametrize("gate", ["random", "bound"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("dv", [32, 64], ids=["v32", "v64"])
+def test_the_chunks_bwd_kernel_pulls_the_parts_back(t, gate, dtype, dv):
+    q, k, v, g, beta, _ = _inputs(t=t, gate=gate, dv=dv)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    parts, pullback = jax.vjp(functools.partial(K._parts, scale=0.2), q, k, v, g, beta)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(parts))
+    d_parts = tuple(jax.random.normal(key, p.shape).astype(p.dtype) for key, p in zip(keys, parts))
+    refs = pullback(d_parts)
+    *got_parts, inverse = K._chunk_parts(q, k, v, g, beta, 0.2, True, inverse=True)
+    got = K._chunk_grads(q, k, v, g, beta, inverse, got_parts[2], got_parts[3], d_parts, 0.2, True)
+    for a, ref, name in zip(got, refs, ("q", "k", "v", "g", "beta")):
+        assert a.shape == ref.shape and a.dtype == ref.dtype, name
+        ref = np.asarray(ref, np.float32)
+        scale = float(np.max(np.abs(ref)))
+        if name == "g":
+            scale, tol = max(scale, float(jnp.max(jnp.abs(refs[1].astype(jnp.float32))))), GATE[dtype]
+        else:
+            tol = PULLBACK[dtype]
+        np.testing.assert_allclose(np.asarray(a, np.float32), ref, atol=tol * scale, err_msg=f"d{name}")
+
+
 def test_a_sequence_of_no_whole_chunks_is_padded_at_its_end():
     q, k, v, g, beta, do = _inputs(t=100)
     o = K.kda(q, k, v, g, beta)
@@ -107,8 +143,9 @@ def test_the_kernels_are_named_for_the_trace():
     q, k, v, g, beta, do = _inputs(t=128)
     text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(K.kda(*a) * do),
                                        argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta))
-    for name in ("kda_chunks", "kda_fwd", "kda_bwd_states", "kda_bwd_grads"):
+    for name in ("kda_chunks", "kda_fwd", "kda_bwd_states", "kda_bwd_grads", "kda_chunks_bwd"):
         assert name in text
+    assert "triangular_solve" not in text  # the pullback reads the inverse kda_chunks wrote
 
 
 def test_no_decay_is_exponentiated_across_more_than_a_sub_chunk():

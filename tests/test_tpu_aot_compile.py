@@ -369,8 +369,9 @@ def test_routed_layer_moves_its_rows_once_each_way_on_v5e(one_chip, monkeypatch)
 def test_kda_kernels_compile_for_v5e(one_chip, monkeypatch):
     """The chunked delta-rule scan with its gradient at the hybrid cell's shape
     ([1, 8192, 32 heads, 128], bfloat16; the decay a channel in float32): the
-    forward's chunk parts and the three walks named for the trace, each a
-    Pallas call."""
+    chunk parts (forward and backward), the three walks and the parts'
+    pullback named for the trace, each a Pallas call; no triangular solve is
+    left to XLA."""
     from katib_tpu.ops import flash_attention as fa
     from katib_tpu.ops.kda import kda
 
@@ -380,9 +381,10 @@ def test_kda_kernels_compile_for_v5e(one_chip, monkeypatch):
         return kda(q, k, v, g, beta).astype(jnp.float32).sum()
 
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), *_kda_inputs(one_chip))
-    assert text.count("tpu_custom_call") == 4
-    for name in ("kda_chunks", "kda_fwd", "kda_bwd_states", "kda_bwd_grads"):
+    assert text.count("tpu_custom_call") == 6
+    for name in ("kda_chunks", "kda_fwd", "kda_bwd_states", "kda_bwd_grads", "kda_chunks_bwd"):
         assert name in text
+    assert "triangular" not in text
 
 
 def _kda_inputs(one_chip):
